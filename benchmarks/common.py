@@ -3,9 +3,27 @@ CSV emission (``name,us_per_call,derived``)."""
 
 from __future__ import annotations
 
+import os
 import time
 
 import numpy as np
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at a fixed path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and JAX reads it itself;
+    otherwise the cache lives in ``<checkout>/.jax_cache``.  The path is
+    part of the cache key, so it never depends on a pid, a temp name or
+    the time.  Entry points call this; importing the library does not.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(checkout, ".jax_cache")
+        )
 
 
 def sift_like(n: int, dim: int = 128, seed: int = 0, n_clusters: int = 64):

@@ -27,7 +27,7 @@ from . import (
     fig_recovery,
     kernels_micro,
 )
-from .common import emit
+from .common import emit, use_compile_cache
 
 MODULES = [
     ("fig6", fig6_mixed_workload),
@@ -99,6 +99,7 @@ def main() -> None:
     if unknown:  # a typo'd tag must not pass as an empty (green) run
         print(f"# unknown benchmark tags: {sorted(unknown)}", file=sys.stderr)
         sys.exit(2)
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     all_rows: list[tuple[str, float, str]] = []

@@ -423,6 +423,8 @@ class Proxy:
                     )
                     continue
                 except RuntimeError:
+                    if node.alive:
+                        raise  # an error on a live node is not a failover
                     failed = True
             if failed:
                 # Mid-request failover: the node died between planning and

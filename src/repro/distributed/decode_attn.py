@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.layers import repeat_kv
-from .compat import shard_map
 
 
 def _partial_attention(q, k, v, valid):
@@ -91,7 +90,7 @@ def make_gqa_flash_decode(mesh: Mesh, seq_axis: str = "model",
             out = (o_tot / jnp.maximum(l_tot, 1e-30).transpose(0, 2, 1)[..., None])
             return out.astype(q.dtype), kc, vc
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(
@@ -107,7 +106,7 @@ def make_gqa_flash_decode(mesh: Mesh, seq_axis: str = "model",
                 P(b_axis, seq_axis, None, None),
                 P(b_axis, seq_axis, None, None),
             ),
-            check=False,
+            check_vma=False,
         )(q, k_new, v_new, k_cache, v_cache, pos)
 
     return impl
@@ -159,7 +158,7 @@ def make_mla_flash_decode(mesh: Mesh, seq_axis: str = "model",
             ctx_out = ctx_tot / jnp.maximum(l_tot, 1e-30).transpose(0, 2, 1)[..., None]
             return ctx_out.astype(q_c.dtype), cc
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(
@@ -173,7 +172,7 @@ def make_mla_flash_decode(mesh: Mesh, seq_axis: str = "model",
                 P(b_axis, None, None, None),
                 P(b_axis, seq_axis, None),
             ),
-            check=False,
+            check_vma=False,
         )(q_c, q_rope, payload_new, c_cache, pos)
 
     return impl

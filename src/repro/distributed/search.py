@@ -20,23 +20,23 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .compat import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def _local_topk(queries, base_shard, k, metric, row_offset, valid=None):
     q = queries.astype(jnp.float32)
     x = base_shard.astype(jnp.float32)
+    # full f32 on the MXU, as in the l2_topk kernel this is checked against
+    qx = jnp.dot(q, x.T, precision=jax.lax.Precision.HIGHEST)
     if metric == "l2":
         scores = (
             jnp.sum(q * q, axis=1, keepdims=True)
-            - 2.0 * q @ x.T
+            - 2.0 * qx
             + jnp.sum(x * x, axis=1)[None, :]
         )
         scores = -scores  # top_k takes max
     else:
-        scores = q @ x.T
+        scores = qx
     if valid is not None:
         scores = jnp.where(valid[None, :] > 0, scores, -jnp.inf)
     vals, idx = jax.lax.top_k(scores, k)
@@ -72,12 +72,12 @@ def make_distributed_search(mesh: Mesh, k: int, metric: str = "l2"):
             out_i = jnp.take_along_axis(cand_i, sel, axis=1)
             return out_v, out_i
 
-        out = shard_map(
+        out = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(None, None), P(axes, None), P(axes)),
             out_specs=(P(), P()),
-            check=False,
+            check_vma=False,
         )(queries, base, valid)
         vals, idx = out
         if metric == "l2":
@@ -89,7 +89,9 @@ def make_distributed_search(mesh: Mesh, k: int, metric: str = "l2"):
 
 def distributed_search_host(queries, base, k, metric="l2", mesh=None):
     """Convenience wrapper: shards base over available devices and runs."""
-    mesh = mesh or jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = mesh or jax.make_mesh(
+        (jax.device_count(),), ("data",), axis_types=(AxisType.Auto,)
+    )
     n = base.shape[0]
     n_dev = mesh.devices.size
     pad = (-n) % n_dev

@@ -44,7 +44,8 @@ def _assign_kernel(
     x = x_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
     xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [TN, TC]
     xn = jnp.sum(x * x, axis=1, keepdims=True)
     cn = jnp.sum(c * c, axis=1)[None, :]
@@ -69,7 +70,8 @@ def kmeans_assign_pallas(
     centroids: jnp.ndarray,  # [C, D] padded to TC (pad rows = +inf-ish far away)
     tn: int = DEFAULT_TN,
     tc: int = DEFAULT_TC,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     n, d = x.shape
     c, _ = centroids.shape

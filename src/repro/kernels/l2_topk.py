@@ -55,9 +55,11 @@ def _scan_kernel(
 
     q = q_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
-    # MXU contraction with f32 accumulation.
+    # Full-f32 MXU contraction: a single bf16 pass would swamp the small
+    # distances left after the |q|^2 - 2q.x + |x|^2 cancellation.
     qx = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [TQ, TN]
     if metric == "l2":
         qn = jnp.sum(q * q, axis=1, keepdims=True)
@@ -96,7 +98,8 @@ def l2_topk_pallas(
     metric: str = "l2",
     tq: int = DEFAULT_TQ,
     tn: int = DEFAULT_TN,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     nq, d = queries.shape
     n, _ = base.shape

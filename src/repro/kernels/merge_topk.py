@@ -10,10 +10,14 @@ each pk the minimum key (L2 distance, negated IP similarity) wins.
 The body is the K-step min/argmin selection loop from ``topk_util`` with
 one extension: after emitting a winner, EVERY candidate carrying the
 same pk is masked out with a vectorized compare against the picked pk —
-the per-row dedup the host merge used to run as a Python loop.  The
-candidate pool [TQ, M] stays VMEM-resident across the whole loop (M is
-n_partials * k, a few thousand lanes at most); the grid tiles queries
-only.
+the per-row dedup the host merge used to run as a Python loop.  The grid
+tiles queries and pool columns: each [TQ, TM] pool tile is merged into a
+running [TQ, K] top-k kept in VMEM scratch, so VMEM use does not grow
+with the pool (a whole [TQ, M] pool of 4 segments x nprobe=32 x k=100
+overran it).  Folding tile by tile gives the same answer as one pass:
+a pk dropped from the running top-k has K distinct better pks ahead of
+it, and the running entries come from earlier columns, so ties still
+break by column order.
 """
 
 from __future__ import annotations
@@ -23,28 +27,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .topk_util import BIG_F32, NEG_I32
 
 DEFAULT_TQ = 128
+DEFAULT_TM = 512
 
 
 def _merge_kernel(
-    s_ref,  # [TQ, M] pooled candidate scores
-    p_ref,  # [TQ, M] int32 pks, -1 = empty slot
+    s_ref,  # [TQ, TM] pooled candidate scores
+    p_ref,  # [TQ, TM] int32 pks, -1 = empty slot
     out_v_ref,  # [TQ, K]
     out_p_ref,  # [TQ, K]
+    acc_v,  # scratch [TQ, K] f32 running keys (min-semantics)
+    acc_p,  # scratch [TQ, K] i32 running pks
     *,
     k: int,
     metric: str,
+    n_m_tiles: int,
 ):
+    jm = pl.program_id(1)
+
+    @pl.when(jm == 0)
+    def _init():
+        acc_v[...] = jnp.full_like(acc_v[...], BIG_F32)
+        acc_p[...] = jnp.full_like(acc_p[...], NEG_I32)
+
     s = s_ref[...].astype(jnp.float32)
     p = p_ref[...]
     key = s if metric == "l2" else -s
     ok = (p >= 0) & (key < BIG_F32) & (key > -BIG_F32) & ~jnp.isnan(key)
     key = jnp.where(ok, key, BIG_F32)
+    # running entries first: they hold the earlier columns
+    key = jnp.concatenate([acc_v[...], key], axis=1)
+    p = jnp.concatenate([acc_p[...], p], axis=1)
     tq, m = key.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (tq, m), 1)
+    out_col = jax.lax.broadcasted_iota(jnp.int32, (tq, k), 1)
 
     def body(j, carry):
         cand, ov, op = carry
@@ -55,10 +75,9 @@ def _merge_kernel(
         picked_pk = jnp.sum(jnp.where(picked_oh, p, 0), axis=1)
         have = row_min < BIG_F32
         picked_pk = jnp.where(have, picked_pk, NEG_I32)
-        ov = jax.lax.dynamic_update_slice(
-            ov, jnp.where(have, row_min, BIG_F32)[:, None], (0, j)
-        )
-        op = jax.lax.dynamic_update_slice(op, picked_pk[:, None], (0, j))
+        slot = out_col == j  # masked write: Mosaic cannot store at column j
+        ov = jnp.where(slot, jnp.where(have, row_min, BIG_F32)[:, None], ov)
+        op = jnp.where(slot, picked_pk[:, None], op)
         # pk-dedup: retire every occurrence of the picked pk, not just the
         # winning slot (keep-best-occurrence)
         kill = (p == picked_pk[:, None]) & have[:, None]
@@ -67,38 +86,53 @@ def _merge_kernel(
     out_v = jnp.full((tq, k), BIG_F32, jnp.float32)
     out_p = jnp.full((tq, k), NEG_I32, jnp.int32)
     _, out_v, out_p = jax.lax.fori_loop(0, k, body, (key, out_v, out_p))
-    if metric == "ip":
-        out_v = -out_v  # back to similarity scale (empty slots -> -BIG)
-    out_v_ref[...] = out_v
-    out_p_ref[...] = out_p
+    acc_v[...] = out_v
+    acc_p[...] = out_p
+
+    @pl.when(jm == n_m_tiles - 1)
+    def _emit():
+        v = acc_v[...]
+        # back to similarity scale for IP (empty slots -> -BIG)
+        out_v_ref[...] = v if metric == "l2" else -v
+        out_p_ref[...] = acc_p[...]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "metric", "tq", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("k", "metric", "tq", "tm", "interpret")
+)
 def merge_topk_pallas(
-    scores: jnp.ndarray,  # [NQ, M] padded to TQ multiple, M lane-aligned
+    scores: jnp.ndarray,  # [NQ, M] padded to TQ multiple, M to a TM multiple
     pks: jnp.ndarray,  # [NQ, M] int32
     k: int,
     metric: str = "l2",
     tq: int = DEFAULT_TQ,
-    interpret: bool = True,
+    tm: int = DEFAULT_TM,
+    *,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     nq, m = scores.shape
-    assert nq % tq == 0, (nq, tq)
-    kernel = functools.partial(_merge_kernel, k=k, metric=metric)
+    tm = min(tm, m)
+    assert nq % tq == 0 and m % tm == 0, (nq, m, tq, tm)
+    n_m_tiles = m // tm
+    kernel = functools.partial(_merge_kernel, k=k, metric=metric, n_m_tiles=n_m_tiles)
     out_v, out_p = pl.pallas_call(
         kernel,
-        grid=(nq // tq,),
+        grid=(nq // tq, n_m_tiles),
         in_specs=[
-            pl.BlockSpec((tq, m), lambda i: (i, 0)),
-            pl.BlockSpec((tq, m), lambda i: (i, 0)),
+            pl.BlockSpec((tq, tm), lambda i, j: (i, j)),
+            pl.BlockSpec((tq, tm), lambda i, j: (i, j)),
         ],
         out_specs=[
-            pl.BlockSpec((tq, k), lambda i: (i, 0)),
-            pl.BlockSpec((tq, k), lambda i: (i, 0)),
+            pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nq, k), jnp.float32),
             jax.ShapeDtypeStruct((nq, k), jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((tq, k), jnp.float32),
+            pltpu.VMEM((tq, k), jnp.int32),
         ],
         interpret=interpret,
     )(scores, pks.astype(jnp.int32))

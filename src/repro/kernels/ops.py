@@ -1,10 +1,9 @@
 """Public jit'd entry points for the kernel layer.
 
-Dispatch policy: on TPU backends the Pallas kernels run compiled; elsewhere
-(this CPU container) the pure-jnp oracles in ``ref.py`` execute by default
-for speed, while the Pallas bodies are validated under ``interpret=True`` in
-the test suite.  Set ``REPRO_FORCE_PALLAS=1`` to force interpret-mode Pallas
-everywhere (slow, but exercises the real kernels end to end).
+Dispatch policy: on a TPU backend the Pallas kernels run compiled; on any
+other backend the vectorized numpy host paths below run, and the Pallas
+bodies are validated against the ``ref.py`` oracles in interpret mode by
+the test suite.  The platform is the only selector.
 
 All wrappers here accept un-padded shapes and handle the 128-alignment the
 kernels require (pad rows, mask padding as invalid, strip outputs).
@@ -12,7 +11,6 @@ kernels require (pad rows, mask padding as invalid, strip outputs).
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from functools import lru_cache
 
@@ -56,10 +54,6 @@ __all__ = [
 
 @lru_cache(maxsize=1)
 def use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS") == "1":
-        return True
-    if os.environ.get("REPRO_FORCE_REF") == "1":
-        return False
     return jax.default_backend() == "tpu"
 
 
@@ -330,11 +324,12 @@ def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.
         if pad_m:
             sp = jnp.pad(sp, ((0, 0), (0, pad_m)), constant_values=np.float32(fill))
             pp = jnp.pad(pp, ((0, 0), (0, pad_m)), constant_values=-1)
+        tm = next(t for t in (512, 256, 128) if (m + pad_m) % t == 0)
         sp = _pad_rows(sp, tq)
         pp = _pad_rows(pp, tq, fill=-1)
         k_eff = min(k, m)
         vals, opk = merge_topk_pallas(
-            sp, pp, k_eff, metric=metric, tq=tq, interpret=_interpret()
+            sp, pp, k_eff, metric=metric, tq=tq, tm=tm, interpret=_interpret()
         )
         vals = np.asarray(vals[:nq], np.float32)
         opk = np.asarray(opk[:nq], np.int64)

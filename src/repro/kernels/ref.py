@@ -1,8 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel.
 
 These are the semantic ground truth: tests sweep shapes/dtypes and assert
-``assert_allclose(kernel(...), ref(...))``.  They are also the fallback
-execution path on platforms without Pallas support.
+``assert_allclose(kernel(...), ref(...))``.
 """
 
 from __future__ import annotations

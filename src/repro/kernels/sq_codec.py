@@ -48,7 +48,7 @@ def _decode_kernel(c_ref, vmin_ref, vmax_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def sq_encode_pallas(
     x: jnp.ndarray, vmin: jnp.ndarray, vmax: jnp.ndarray,
-    tn: int = DEFAULT_TN, interpret: bool = True,
+    tn: int = DEFAULT_TN, *, interpret: bool,
 ) -> jnp.ndarray:
     n, d = x.shape
     assert n % tn == 0
@@ -69,7 +69,7 @@ def sq_encode_pallas(
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def sq_decode_pallas(
     codes: jnp.ndarray, vmin: jnp.ndarray, vmax: jnp.ndarray,
-    tn: int = DEFAULT_TN, interpret: bool = True,
+    tn: int = DEFAULT_TN, *, interpret: bool,
 ) -> jnp.ndarray:
     n, d = codes.shape
     assert n % tn == 0
@@ -116,7 +116,8 @@ def _sq_scan_kernel(
     x = c_ref[...].astype(jnp.float32) * scale + vmin  # fused dequant in VMEM
 
     qx = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     if metric == "l2":
         qn = jnp.sum(q * q, axis=1, keepdims=True)
@@ -152,7 +153,8 @@ def sq_l2_topk_pallas(
     metric: str = "l2",
     tq: int = DEFAULT_TQ,
     tn: int = DEFAULT_TN,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     nq, d = queries.shape
     n = codes.shape[0]

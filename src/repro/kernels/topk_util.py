@@ -17,12 +17,6 @@ NEG_I32 = -1
 BIG_F32 = 3.0e38
 
 
-def _row_onehot(col_idx: jnp.ndarray, width: int) -> jnp.ndarray:
-    """[TQ] int32 -> one-hot [TQ, width] float32 (Mosaic-safe gather substitute)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (col_idx.shape[0], width), 1)
-    return (iota == col_idx[:, None]).astype(jnp.float32)
-
-
 def select_topk_small(
     vals: jnp.ndarray, idx: jnp.ndarray, k: int
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -30,20 +24,27 @@ def select_topk_small(
 
     Pure min/argmin selection loop: K iterations, each picks the row-wise
     minimum, emits it, and masks it out with a one-hot.  Ascending output.
+    Every step is a full-tile masked write (Mosaic cannot store at a
+    loop-carried column), and the picked index is an int32 masked sum, so
+    it is exact for any int32 row index.
     """
     tq, m = vals.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, m), 1)
+    out_col = jax.lax.broadcasted_iota(jnp.int32, (tq, k), 1)
+    idx = idx.astype(jnp.int32)
     out_v = jnp.full((tq, k), BIG_F32, dtype=jnp.float32)
     out_i = jnp.full((tq, k), NEG_I32, dtype=jnp.int32)
 
     def body(j, carry):
         cv, ov, oi = carry
-        row_min = jnp.min(cv, axis=1)  # [TQ]
+        row_min = jnp.min(cv, axis=1, keepdims=True)  # [TQ, 1]
         row_arg = jnp.argmin(cv, axis=1).astype(jnp.int32)  # [TQ]
-        oh = _row_onehot(row_arg, m)  # [TQ, M]
-        picked_idx = jnp.sum(oh * idx.astype(jnp.float32), axis=1).astype(jnp.int32)
-        ov = jax.lax.dynamic_update_slice(ov, row_min[:, None], (0, j))
-        oi = jax.lax.dynamic_update_slice(oi, picked_idx[:, None], (0, j))
-        cv = jnp.where(oh > 0, BIG_F32, cv)
+        oh = col == row_arg[:, None]  # [TQ, M] one-hot
+        picked_idx = jnp.sum(jnp.where(oh, idx, 0), axis=1, keepdims=True)
+        slot = out_col == j
+        ov = jnp.where(slot, row_min, ov)
+        oi = jnp.where(slot, picked_idx, oi)
+        cv = jnp.where(oh, BIG_F32, cv)
         return cv, ov, oi
 
     _, out_v, out_i = jax.lax.fori_loop(

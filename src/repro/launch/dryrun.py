@@ -34,7 +34,6 @@ import traceback
 import jax
 
 from ..configs import ARCHS, SHAPES, cells, get_arch, skipped_cells
-from ..distributed.compat import cost_analysis_dict
 from .mesh import make_production_mesh
 from .steps import build_cell
 
@@ -122,7 +121,7 @@ def _compile_cell(cfg, shape, mesh, kw):
 
 
 def _cost_of(compiled) -> dict[str, float]:
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -206,7 +205,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str | None = 
         cost_method = cost["method"]
         coll_breakdown = cost["coll_breakdown"]
     else:
-        raw = cost_analysis_dict(compiled)
+        raw = compiled.cost_analysis()
         cost = {
             "flops": float(raw.get("flops", 0.0)),
             "bytes": float(raw.get("bytes accessed", 0.0)),

@@ -8,18 +8,21 @@ dry-run forces 512 host devices while tests/benches must see 1.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips for the multi-pod pass."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 4):
     """Small mesh over forced host devices — used by multi-device tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def mesh_axis_size(mesh, name: str) -> int:

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from ..distributed.act_sharding import constrain
-from ..distributed.compat import shard_map
 from .config import ModelConfig
 from .layers import PARAM_DTYPE
 
@@ -186,7 +185,7 @@ def _moe_block_shard_map(cfg: ModelConfig, p: dict, x: jnp.ndarray, pol) -> jnp.
         out_partial = _moe_local_compute(cfg, x_l, router, w_gate, w_up, w_down, e0)
         return jax.lax.psum(out_partial, "model")
 
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -197,7 +196,7 @@ def _moe_block_shard_map(cfg: ModelConfig, p: dict, x: jnp.ndarray, pol) -> jnp.
             P("model", None, None),
         ),
         out_specs=P(b_axis, None, None),
-        check=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.moe_num_shared:

@@ -27,10 +27,11 @@ def test_distributed_search_matches_bruteforce():
     run_subprocess("""
     import numpy as np, jax
     from repro.distributed.search import distributed_search_host
+    from repro.launch.mesh import make_host_mesh
     rng = np.random.default_rng(0)
     base = rng.standard_normal((999, 24)).astype(np.float32)   # uneven => pad path
     q = rng.standard_normal((4, 24)).astype(np.float32)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(2, 4)
     vals, idx = distributed_search_host(q, base, 10, "l2", mesh)
     d2 = np.sum(q**2,1,keepdims=True) - 2*q@base.T + np.sum(base**2,1)
     gt = np.argsort(d2,axis=1)[:, :10]
@@ -46,9 +47,10 @@ def test_flash_decode_matches_dense():
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.models.model import dense_gqa_decode_attn
+    from repro.launch.mesh import make_host_mesh
     from repro.distributed.decode_attn import make_gqa_flash_decode
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(2, 4)
     B, S, H, KVH, hd = 4, 32, 8, 2, 16
     rng = np.random.default_rng(0)
     q = rng.standard_normal((B, 1, H, hd)).astype(np.float32)
@@ -80,9 +82,10 @@ def test_mla_flash_decode_matches_dense():
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.models.model import dense_mla_decode_attn
+    from repro.launch.mesh import make_host_mesh
     from repro.distributed.decode_attn import make_mla_flash_decode
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(2, 4)
     B, S, H, r, rope = 4, 32, 6, 16, 8
     rng = np.random.default_rng(0)
     q_c = rng.standard_normal((B, 1, H, r)).astype(np.float32)
@@ -119,8 +122,9 @@ def test_small_mesh_train_step_executes():
     from repro.launch.steps import build_train_cell
     from repro.models.config import ShapeConfig
     from repro.train.optimizer import init_opt_state
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_host_mesh(2, 2)
     cfg = ARCHS["yi-9b"].reduced(num_heads=4, num_kv_heads=2, d_model=64,
                                  head_dim=16, d_ff=128, vocab_size=256)
     shape = ShapeConfig("tiny_train", seq_len=32, global_batch=4, kind="train")
@@ -154,8 +158,9 @@ def test_small_mesh_moe_shard_map_matches_dense():
     from repro.configs import ARCHS
     from repro.models.moe import init_moe_params, moe_block
     from repro.distributed import act_sharding
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_host_mesh(2, 2)
     cfg = ARCHS["qwen3-moe-30b-a3b"].reduced()
     p = init_moe_params(cfg, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model), jnp.float32)
@@ -177,8 +182,7 @@ def test_dryrun_search_compiles_at_scale():
     from repro.distributed.search import dryrun_search
     mesh = make_production_mesh()
     compiled = dryrun_search(mesh, n_rows=256*4096, dim=128, nq=64, k=50)
-    from repro.distributed.compat import cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     assert cost.get("flops", 0) > 0
     print("OK", cost.get("flops"))
     """, devices=256, timeout=560)

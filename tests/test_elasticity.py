@@ -142,6 +142,29 @@ def test_kill_node_mid_search_bit_for_bit(rng):
         assert not p.under_replicated  # healed back to rf=2 on survivors
 
 
+def test_scan_error_on_live_node_is_raised_not_failed_over(rng):
+    """A scan that raises on a node that is still alive (a device or
+    compile error) reaches the caller; it is not mistaken for a node death,
+    so placement stays as it was."""
+    system = ManuSystem(ManuConfig(num_query_nodes=2, seal_rows=200))
+    coll = system.create_collection("c", dim=8)
+    ingest(coll, rng, 600, 8, batches=3)
+    coll.flush()
+    before = system.cluster_state().live_node_ids
+    node_id = next(
+        n for n, st in system.query_coord.nodes.items() if st.segments
+    )
+
+    def device_error(request):
+        raise RuntimeError("RESOURCE_EXHAUSTED: scoped vmem")
+
+    system.query_nodes[node_id].search_request = device_error
+    q = rng.standard_normal((1, 8)).astype(np.float32)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        coll.search(q, limit=5, staleness_ms=0.0)
+    assert system.cluster_state().live_node_ids == before
+
+
 def test_node_join_heals_under_replication(rng):
     """Under-replicated (1 node, rf=2) -> node join -> the reconciler heals
     every segment back to full replication."""

@@ -1,12 +1,8 @@
 """Per-kernel validation: Pallas (interpret mode) vs the pure-jnp oracles,
 swept over shapes and dtypes."""
 
-import os
-
 import numpy as np
 import pytest
-
-os.environ.setdefault("REPRO_FORCE_PALLAS", "0")
 
 import jax.numpy as jnp
 

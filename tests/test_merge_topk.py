@@ -86,6 +86,26 @@ def test_merge_topk_pallas_interpret_matches_ref(metric):
     np.testing.assert_array_equal(np.asarray(want_p), np.where(bad, -1, got_p))
 
 
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+@pytest.mark.parametrize("k", [5, 40])
+def test_merge_topk_pallas_column_tiles_match_ref(metric, k):
+    """Folding the pool tile by tile into a running top-k gives the
+    one-pass answer: duplicates span tiles and ties span tile borders."""
+    rng = np.random.default_rng(11)
+    nq, m = 8, 512
+    s, p = random_pool(rng, nq, m, metric, pk_range=60)
+    want_s, want_p = ref.merge_topk_ref(jnp.asarray(s), jnp.asarray(p), k, metric)
+    got_v, got_p = merge_topk_pallas(
+        jnp.asarray(s), jnp.asarray(p, np.int32), k, metric=metric, tq=8, tm=128,
+        interpret=True,
+    )
+    got_v, got_p = np.asarray(got_v), np.asarray(got_p, np.int64)
+    bad = np.abs(got_v) >= 1e38
+    fill = np.inf if metric == "l2" else -np.inf
+    np.testing.assert_array_equal(np.asarray(want_s), np.where(bad, fill, got_v))
+    np.testing.assert_array_equal(np.asarray(want_p), np.where(bad, -1, got_p))
+
+
 def test_merge_topk_empty_and_padding():
     s = np.zeros((3, 0), np.float32)
     p = np.zeros((3, 0), np.int64)

@@ -89,10 +89,11 @@ def test_train_resume_is_seamless():
 
 def test_microbatch_grads_match_full_batch():
     """Gradient accumulation over N microbatches == one full-batch step."""
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import build_train_cell
     from repro.models.config import ShapeConfig
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     cfg = ARCHS["yi-9b"].reduced(num_layers=2, d_model=32, num_heads=2,
                                  num_kv_heads=2, head_dim=8, d_ff=64, vocab_size=64)
     shape = ShapeConfig("t", seq_len=16, global_batch=4, kind="train")
