@@ -13,9 +13,10 @@ def use_compile_cache() -> None:
     """Keep JAX's persistent compilation cache at a fixed path.
 
     ``JAX_COMPILATION_CACHE_DIR``, when set, wins and JAX reads it itself;
-    otherwise the cache lives in ``<checkout>/.jax_cache``.  The path is
-    part of the cache key, so it never depends on a pid, a temp name or
-    the time.  Entry points call this; importing the library does not.
+    otherwise the cache lives in ``<checkout>/.jax_cache``.  The path never
+    depends on a pid, a temp name or the time, so a later run finds what an
+    earlier one cached.  Entry points call this; importing the library does
+    not.
     """
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         import jax
