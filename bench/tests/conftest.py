@@ -1,0 +1,8 @@
+"""CPU tests of the benchmark harness: ``python -m pytest bench/tests``."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, BENCH)
+sys.path.insert(0, os.path.join(os.path.dirname(BENCH), "src"))
