@@ -1471,6 +1471,11 @@ class ManuSystem:
 
     # ------------------------------------------------------------- threads
     def start_threads(self) -> None:
+        # Each pump round and reconcile is a profiler annotation
+        # (``manu.pump``, ``manu.reconcile``), so a device trace shows
+        # when the background threads ran.
+        from jax.profiler import TraceAnnotation
+
         self._stop.clear()
         # The pump thread owns node stepping; the proxy's failover waits
         # must sleep instead of stepping nodes themselves.
@@ -1478,7 +1483,8 @@ class ManuSystem:
 
         def pump_loop():
             while not self._stop.is_set():
-                self.pump()
+                with TraceAnnotation("manu.pump"):
+                    self.pump()
                 time.sleep(self.config.pump_sleep_s)
 
         def watchdog_loop():
@@ -1490,7 +1496,8 @@ class ManuSystem:
                 now = time.time()
                 if now - last_reconcile >= self.config.reconcile_interval_s:
                     last_reconcile = now
-                    self.query_coord.reconciler.reconcile()
+                    with TraceAnnotation("manu.reconcile"):
+                        self.query_coord.reconciler.reconcile()
                 time.sleep(0.05)
 
         for fn in (pump_loop, watchdog_loop):

@@ -19,6 +19,7 @@ re-dispatches the failed units to surviving replicas mid-request.
 
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
 from collections import OrderedDict
@@ -45,7 +46,7 @@ from .request import (
     vector_column_of,
 )
 from .segment import DEFAULT_PARTITION
-from .telemetry import MetricsRegistry, TraceContext
+from .telemetry import UNTRACED, MetricsRegistry, TraceContext
 from .timestamp import TSO, INFINITE_STALENESS
 
 
@@ -181,7 +182,7 @@ class Proxy:
         self.metrics.inc("proxy_mutations_total", labels={"op": request.op})
         self.metrics.observe("proxy_mutation_latency_us", elapsed_us)
         if trace_ctx is not None:
-            res.trace = trace_ctx.finish(elapsed_us)
+            res.trace = trace_ctx.finish()
         return res
 
     def mutate_batch(
@@ -368,19 +369,23 @@ class Proxy:
             res = None
             failed = node is None or not node.alive
             if not failed:
-                if wait_fn is not None:
-                    scope = wait_scopes.get(node_id, None)
-                    if scope is None:
-                        wait_fn(node, guarantee)
-                    elif scope:
-                        if wait_scoped is None:
-                            wait_scoped = _accepts_channel_scope(wait_fn)
-                        if wait_scoped:
-                            wait_fn(node, guarantee, scope)
-                        else:  # legacy wait_fn: conservative full wait
-                            wait_fn(node, guarantee)
-                    # empty scope: every channel this node serves is already
-                    # covered by a routed pick — zero-wait path, no call
+                scope = wait_scopes.get(node_id, None)
+                # An empty scope: every channel this node serves is already
+                # covered by a routed pick — zero-wait path, no call.
+                if wait_fn is not None and (scope is None or scope):
+                    if scope and wait_scoped is None:
+                        wait_scoped = _accepts_channel_scope(wait_fn)
+                    if trace_ctx is None:
+                        _consistency_wait(wait_fn, node, guarantee, scope, wait_scoped)
+                    else:
+                        wspan = trace_ctx.span(
+                            "consistency_wait", node_id=node_id,
+                            detail="full" if scope is None else ",".join(scope),
+                        )
+                        with trace_ctx.timed(wspan):
+                            _consistency_wait(
+                                wait_fn, node, guarantee, scope, wait_scoped
+                            )
                 try:
                     if hedge_timeout_s is not None:
                         res = _run_with_timeout(
@@ -456,14 +461,50 @@ class Proxy:
         waited_ms = (time.perf_counter() - t0) * 1e3
         target_nodes = [qn for qn in self.query_nodes.values() if qn.alive]
 
+        merge_timer = UNTRACED
+        if trace_ctx is not None:
+            merge_timer = trace_ctx.timed(
+                trace_ctx.span("merge_topk", node_id=self.proxy_id)
+            )
+        with merge_timer:
+            out_s, out_p = self._reduce(request, metric, partials)
+        fields = None
+        if request.output_fields:
+            hydrate_span = None
+            if trace_ctx is not None:
+                hydrate_span = trace_ctx.span("fetch_fields", node_id=self.proxy_id)
+            if hydrate_span is not None:
+                with trace_ctx.timed(hydrate_span):
+                    fields = self._hydrate(
+                        target_nodes, info, out_p, request.output_fields,
+                        guarantee.query_ts, trace=(trace_ctx, hydrate_span),
+                    )
+            else:
+                fields = self._hydrate(
+                    target_nodes, info, out_p, request.output_fields,
+                    guarantee.query_ts,
+                )
+        self.metrics.inc("proxy_searches_total")
+        self.metrics.observe("proxy_search_latency_us", waited_ms * 1e3)
+        # The root spans the proxy's whole time, the reduce and the
+        # hydration after ``waited_ms`` included.
+        trace = trace_ctx.finish() if trace_ctx is not None else None
+        return SearchResult(
+            out_s, out_p, guarantee.query_ts, waited_ms, fields, trace
+        )
+
+    @staticmethod
+    def _reduce(
+        request: SearchRequest, metric: Metric, partials
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """The global reduce: per sub-request, ``merge_topk`` over every
+        node's candidates (pk-dedup) and the range cut; hybrid requests
+        then fuse the per-field lists with the request's ranker."""
         nq = request.nq
         kk = request.k
+        n_fields = len(request.anns)
         metric_str = "l2" if metric is Metric.L2 else "ip"
         fill = np.inf if metric is Metric.L2 else -np.inf
-        merge_span = None
-        if trace_ctx is not None:
-            merge_span = trace_ctx.span("merge_topk", node_id=self.proxy_id)
-            merge_t0 = trace_ctx.perf_counter()
         merged: list[tuple[np.ndarray, np.ndarray]] = []
         for f in range(n_fields):
             if not partials[f]:
@@ -491,43 +532,18 @@ class Proxy:
                     out_f[0], out_f[1], metric_str, radius, range_filter
                 )
             merged.append(out_f)
-        if request.is_hybrid:
-            # Hybrid fusion over the per-field GLOBAL lists (RRF ranks are
-            # only meaningful after the global reduce, hence proxy-side).
-            out_s, out_p = ops.hybrid_fuse(
-                [m[0] for m in merged],
-                [m[1] for m in merged],
-                kk,
-                metrics=[metric.value] * n_fields,
-                weights=[a.weight for a in request.anns],
-                kind=request.ranker.kind,
-                rrf_k=request.ranker.rrf_k,
-            )
-        else:
-            out_s, out_p = merged[0]
-        if merge_span is not None:
-            merge_span.duration_us = (trace_ctx.perf_counter() - merge_t0) * 1e6
-        fields = None
-        if request.output_fields:
-            hydrate_span = None
-            if trace_ctx is not None:
-                hydrate_span = trace_ctx.span("fetch_fields", node_id=self.proxy_id)
-            if hydrate_span is not None:
-                with trace_ctx.timed(hydrate_span):
-                    fields = self._hydrate(
-                        target_nodes, info, out_p, request.output_fields,
-                        guarantee.query_ts, trace=(trace_ctx, hydrate_span),
-                    )
-            else:
-                fields = self._hydrate(
-                    target_nodes, info, out_p, request.output_fields,
-                    guarantee.query_ts,
-                )
-        self.metrics.inc("proxy_searches_total")
-        self.metrics.observe("proxy_search_latency_us", waited_ms * 1e3)
-        trace = trace_ctx.finish(waited_ms * 1e3) if trace_ctx is not None else None
-        return SearchResult(
-            out_s, out_p, guarantee.query_ts, waited_ms, fields, trace
+        if not request.is_hybrid:
+            return merged[0]
+        # Hybrid fusion over the per-field GLOBAL lists (RRF ranks are
+        # only meaningful after the global reduce, hence proxy-side).
+        return ops.hybrid_fuse(
+            [m[0] for m in merged],
+            [m[1] for m in merged],
+            kk,
+            metrics=[metric.value] * n_fields,
+            weights=[a.weight for a in request.anns],
+            kind=request.ranker.kind,
+            rrf_k=request.ranker.rrf_k,
         )
 
     # ------------------------------------------------- replica-aware dispatch
@@ -939,12 +955,25 @@ def _accepts_channel_scope(wait_fn) -> bool:
     return len(positional) >= 3
 
 
+def _consistency_wait(wait_fn, node, guarantee, scope, scoped: bool | None) -> None:
+    """One node's consistency wait: on the channels of ``scope`` where
+    ``wait_fn`` takes a scope, else (scope None, or a legacy two-argument
+    ``wait_fn``) the conservative full wait."""
+    if scope is not None and scoped:
+        wait_fn(node, guarantee, scope)
+    else:
+        wait_fn(node, guarantee)
+
+
 def _run_with_timeout(fn, timeout_s: float):
-    """Run fn in a worker thread; None on timeout (hedged-request helper)."""
+    """Run fn in a worker thread; None on timeout (hedged-request helper).
+    The worker runs in a copy of the caller's context, so a traced
+    request's open span stays the parent of the spans it records."""
     result: list = []
+    context = contextvars.copy_context()
 
     def target():
-        result.append(fn())
+        result.append(context.run(fn))
 
     t = threading.Thread(target=target, daemon=True)
     t.start()

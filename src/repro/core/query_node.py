@@ -33,7 +33,7 @@ from .log import EntryType, LogBroker, LogEntry, Subscription, shard_of_channel
 from .object_store import ObjectStore
 from .request import PRIMARY_VECTOR_COLUMN, AnnsQuery, NodeSearchRequest
 from .segment import DEFAULT_PARTITION, Segment, add_tombstone, flatten_tombstones
-from .telemetry import MetricsRegistry
+from .telemetry import UNTRACED, MetricsRegistry
 
 TEMP_INDEX_SLICE_ROWS = 2_048  # scaled-down default of the paper's 10k
 
@@ -913,7 +913,18 @@ class QueryNode:
         pool_s: list[np.ndarray] = []
         pool_p: list[np.ndarray] = []
 
-        def record_class(cls: str, units, t0: float) -> None:
+        def open_class(cls: str, units):
+            """The class's ``scan_<cls>`` span timer, open while the class
+            runs so that kernel spans nest under it (a no-op untraced)."""
+            if trace is None:
+                return UNTRACED
+            ctx, parent = trace
+            return ctx.timed(ctx.span(
+                f"scan_{cls}", parent=parent, node_id=self.node_id,
+                segment_ids=sorted({u.segment_id for u in units}),
+            ))
+
+        def record_class(cls: str, units, t0: float, span) -> None:
             elapsed_us = (_t.perf_counter() - t0) * 1e6
             rows = int(sum(int(u.mask.sum()) for u in units))
             self.metrics.observe(
@@ -922,13 +933,7 @@ class QueryNode:
             self.metrics.inc(
                 "query_node_rows_scanned_total", rows, labels={"class": cls}
             )
-            if trace is not None:
-                ctx, parent = trace
-                span = ctx.span(
-                    f"scan_{cls}", parent=parent, node_id=self.node_id,
-                    segment_ids=sorted({u.segment_id for u in units}),
-                )
-                span.duration_us = elapsed_us
+            if span is not None:
                 span.rows_scanned = rows
 
         # Index-backed units group by spec: all co-located segments sharing
@@ -940,19 +945,20 @@ class QueryNode:
         for unit in plan.indexed + plan.growing_slice:
             index_groups.setdefault(unit.index.batch_spec(), []).append(unit)
         for units in index_groups.values():
-            t0 = _t.perf_counter()
-            s, i, splits = type(units[0].index).search_batched(
-                [u.index for u in units],
-                queries,
-                k,
-                valids=[u.mask for u in units],
-            )
-            for j, unit in enumerate(units):
-                blk = slice(splits[j], splits[j + 1])
-                pool_s.append(s[:, blk])
-                pool_p.append(_map_pks(i[:, blk], unit.pks))
             cls = "indexed" if id(units[0]) in indexed_ids else "growing_slice"
-            record_class(cls, units, t0)
+            t0 = _t.perf_counter()
+            with open_class(cls, units) as span:
+                s, i, splits = type(units[0].index).search_batched(
+                    [u.index for u in units],
+                    queries,
+                    k,
+                    valids=[u.mask for u in units],
+                )
+                for j, unit in enumerate(units):
+                    blk = slice(splits[j], splits[j + 1])
+                    pool_s.append(s[:, blk])
+                    pool_p.append(_map_pks(i[:, blk], unit.pks))
+            record_class(cls, units, t0, span)
         # Brute classes run as one fused scan per class: a single shared
         # distance contraction, per-segment top-k extracted from it.
         # Cosine scans normalize both sides: the planner handed us the
@@ -966,18 +972,19 @@ class QueryNode:
             if not units:
                 continue
             t0 = _t.perf_counter()
-            s, i = ops.topk_scan_segmented(
-                q_brute,
-                [u.vectors for u in units],
-                k,
-                metric=metric_str,
-                valids=[u.mask for u in units],
-            )
-            for j, unit in enumerate(units):
-                blk = slice(j * k, (j + 1) * k)
-                pool_s.append(s[:, blk])
-                pool_p.append(_map_pks(i[:, blk], unit.pks))
-            record_class(cls, units, t0)
+            with open_class(cls, units) as span:
+                s, i = ops.topk_scan_segmented(
+                    q_brute,
+                    [u.vectors for u in units],
+                    k,
+                    metric=metric_str,
+                    valids=[u.mask for u in units],
+                )
+                for j, unit in enumerate(units):
+                    blk = slice(j * k, (j + 1) * k)
+                    pool_s.append(s[:, blk])
+                    pool_p.append(_map_pks(i[:, blk], unit.pks))
+            record_class(cls, units, t0, span)
         # Post-filter classes scan with VISIBILITY-only valids at the
         # inflated class width k' = k + max(k_extra): each unit's k_extra is
         # its worst-case interloper count (visible rows failing the filter),
@@ -989,52 +996,55 @@ class QueryNode:
                 post_groups.setdefault(unit.index.batch_spec(), []).append(unit)
             for units in post_groups.values():
                 t0 = _t.perf_counter()
+                with open_class("post_indexed", units) as span:
+                    k_class = k + max(u.k_extra for u in units)
+                    s, i, splits = type(units[0].index).search_batched(
+                        [u.index for u in units],
+                        queries,
+                        k_class,
+                        valids=[u.mask for u in units],
+                    )
+                    for j, unit in enumerate(units):
+                        blk = slice(splits[j], splits[j + 1])
+                        cs, ci = ops.post_filter_cut(
+                            s[:, blk], i[:, blk], unit.post_mask, metric=metric_str
+                        )
+                        pool_s.append(cs)
+                        pool_p.append(_map_pks(ci, unit.pks))
+                record_class("post_indexed", units, t0, span)
+        if plan.post_brute:
+            units = plan.post_brute
+            t0 = _t.perf_counter()
+            with open_class("post_brute", units) as span:
                 k_class = k + max(u.k_extra for u in units)
-                s, i, splits = type(units[0].index).search_batched(
-                    [u.index for u in units],
-                    queries,
+                s, i = ops.topk_scan_segmented(
+                    q_brute,
+                    [u.vectors for u in units],
                     k_class,
+                    metric=metric_str,
                     valids=[u.mask for u in units],
                 )
                 for j, unit in enumerate(units):
-                    blk = slice(splits[j], splits[j + 1])
+                    blk = slice(j * k_class, (j + 1) * k_class)
                     cs, ci = ops.post_filter_cut(
                         s[:, blk], i[:, blk], unit.post_mask, metric=metric_str
                     )
                     pool_s.append(cs)
                     pool_p.append(_map_pks(ci, unit.pks))
-                record_class("post_indexed", units, t0)
-        if plan.post_brute:
-            units = plan.post_brute
-            t0 = _t.perf_counter()
-            k_class = k + max(u.k_extra for u in units)
-            s, i = ops.topk_scan_segmented(
-                q_brute,
-                [u.vectors for u in units],
-                k_class,
-                metric=metric_str,
-                valids=[u.mask for u in units],
-            )
-            for j, unit in enumerate(units):
-                blk = slice(j * k_class, (j + 1) * k_class)
-                cs, ci = ops.post_filter_cut(
-                    s[:, blk], i[:, blk], unit.post_mask, metric=metric_str
-                )
-                pool_s.append(cs)
-                pool_p.append(_map_pks(ci, unit.pks))
-            record_class("post_brute", units, t0)
+            record_class("post_brute", units, t0, span)
         # Brute-filtered units already gathered their surviving rows: each
         # scans its own tiny vector block unfused (the gathers are ragged,
         # so a shared contraction buys nothing at these sizes).
         if plan.brute_filtered:
             t0 = _t.perf_counter()
-            for unit in plan.brute_filtered:
-                s, i = ops.topk_scan(
-                    q_brute, unit.vectors, k, metric=metric_str
-                )
-                pool_s.append(s)
-                pool_p.append(_map_pks(i, unit.pks))
-            record_class("brute_filtered", plan.brute_filtered, t0)
+            with open_class("brute_filtered", plan.brute_filtered) as span:
+                for unit in plan.brute_filtered:
+                    s, i = ops.topk_scan(
+                        q_brute, unit.vectors, k, metric=metric_str
+                    )
+                    pool_s.append(s)
+                    pool_p.append(_map_pks(i, unit.pks))
+            record_class("brute_filtered", plan.brute_filtered, t0, span)
         return pool_s, pool_p
 
     def search_request(
@@ -1072,14 +1082,6 @@ class QueryNode:
             self.metrics.observe(
                 "query_node_search_latency_us",
                 (_t.perf_counter() - t0) * 1e6,
-                labels={"node": self.node_id},
-            )
-            self.metrics.set_gauge(
-                "node_searches_primary", self.searches_primary,
-                labels={"node": self.node_id},
-            )
-            self.metrics.set_gauge(
-                "node_searches_hedged", self.searches_hedged,
                 labels={"node": self.node_id},
             )
 

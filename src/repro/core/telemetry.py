@@ -14,9 +14,14 @@ Three cooperating primitives, all process-local and allocation-light:
     Per-request span trees.  A context is allocated at the proxy only
     when ``SearchRequest(trace=True)`` — every hot-path call site guards
     with ``if trace is not None`` so the disabled cost is one branch.
-    Durations use an injectable ``perf_counter``; tracing carries node
-    ids, segment ids, and rows scanned so chaos tests can assert the
-    tree bit-for-bit matches what was executed.
+    Start times and durations use an injectable ``perf_counter``; tracing
+    carries node ids, segment ids, rows scanned and host-to-device bytes
+    so chaos tests can assert the tree bit-for-bit matches what was
+    executed.  A span opened with ``TraceContext.timed`` is the ambient
+    parent (``open_span()``) of the code it wraps, so the kernel layer
+    hangs its spans under it without a trace parameter, and it is a
+    ``jax.profiler.TraceAnnotation`` named ``manu.<span>`` on the
+    profiler's clock.
 
 ``EventLog``
     Bounded ring of typed control-plane events (node death, CAS
@@ -27,8 +32,10 @@ Three cooperating primitives, all process-local and allocation-light:
 
 from __future__ import annotations
 
+import contextvars
 import math
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -39,6 +46,8 @@ __all__ = [
     "TraceContext",
     "Span",
     "RequestTrace",
+    "open_span",
+    "UNTRACED",
     "Event",
     "EventLog",
 ]
@@ -245,8 +254,10 @@ class Span:
     name: str
     node_id: str | None = None
     segment_ids: tuple[int, ...] = ()
+    start_us: float = 0.0  # perf_counter at entry, in microseconds
     duration_us: float = 0.0
     rows_scanned: int = 0
+    bytes_h2d: int = 0  # host bytes copied to the device
     detail: str = ""
     children: list["Span"] = field(default_factory=list)
 
@@ -255,8 +266,10 @@ class Span:
             "name": self.name,
             "node_id": self.node_id,
             "segment_ids": [int(s) for s in self.segment_ids],
+            "start_us": float(self.start_us),
             "duration_us": float(self.duration_us),
             "rows_scanned": int(self.rows_scanned),
+            "bytes_h2d": int(self.bytes_h2d),
             "detail": self.detail,
             "children": [c.to_dict() for c in self.children],
         }
@@ -299,6 +312,8 @@ class RequestTrace:
                 bits.append(f"segments={list(span.segment_ids)}")
             if span.rows_scanned:
                 bits.append(f"rows={span.rows_scanned}")
+            if span.bytes_h2d:
+                bits.append(f"h2d={span.bytes_h2d}B")
             bits.append(f"{span.duration_us:.0f}us")
             if span.detail:
                 bits.append(span.detail)
@@ -310,12 +325,33 @@ class RequestTrace:
         return "\n".join(lines)
 
 
+# The (TraceContext, Span) that ``TraceContext.timed`` has open in this
+# thread or task; None outside traced requests.
+_OPEN_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "manu_open_span", default=None
+)
+
+
+# What an untraced request enters where a traced one enters a span timer:
+# one shared, reusable no-op, so the untraced path allocates nothing.
+UNTRACED = nullcontext()
+
+
+def open_span():
+    """``(TraceContext, Span)`` of the innermost span open here, or None.
+
+    The one lookup an untraced request pays where code below the query
+    node (the kernel layer) asks whether to record spans."""
+    return _OPEN_SPAN.get()
+
+
 class TraceContext:
     """Mutable trace builder threaded through one request.
 
     Hot paths hold ``trace: TraceContext | None`` and guard every use
     with ``if trace is not None`` — no object is allocated when tracing
     is off.  ``perf_counter`` is injectable for deterministic tests.
+    The root span starts when the context is made.
     """
 
     _next_id = 0
@@ -326,7 +362,7 @@ class TraceContext:
         TraceContext._next_id += 1
         self.request_id = TraceContext._next_id
         self.kind = kind
-        self.root = Span(name=kind)
+        self.root = Span(name=kind, start_us=perf_counter() * 1e6)
         self.perf_counter = perf_counter
 
     def span(
@@ -341,33 +377,50 @@ class TraceContext:
             name=name,
             node_id=node_id,
             segment_ids=tuple(int(x) for x in segment_ids),
+            start_us=self.perf_counter() * 1e6,  # ``timed`` restamps it
             detail=detail,
         )
         (parent if parent is not None else self.root).children.append(s)
         return s
 
     def timed(self, span: Span):
-        """Context manager stamping ``duration_us`` on exit."""
-        return _SpanTimer(span, self.perf_counter)
+        """Context manager stamping ``start_us`` on entry and
+        ``duration_us`` on exit.  While it is open, ``span`` is the
+        ambient parent (``open_span()``) and a profiler annotation
+        ``manu.<name>``."""
+        return _SpanTimer(self, span)
 
-    def finish(self, duration_us: float) -> RequestTrace:
+    def finish(self, duration_us: float | None = None) -> RequestTrace:
+        """Close the root; by default it lasts from the context's making
+        until now."""
+        if duration_us is None:
+            duration_us = self.perf_counter() * 1e6 - self.root.start_us
         self.root.duration_us = duration_us
         return RequestTrace(request_id=self.request_id, kind=self.kind, root=self.root)
 
 
 class _SpanTimer:
-    __slots__ = ("span", "perf_counter", "t0")
+    __slots__ = ("ctx", "span", "t0", "token", "annotation")
 
-    def __init__(self, span: Span, perf_counter) -> None:
+    def __init__(self, ctx: TraceContext, span: Span) -> None:
+        self.ctx = ctx
         self.span = span
-        self.perf_counter = perf_counter
 
     def __enter__(self) -> Span:
-        self.t0 = self.perf_counter()
+        # jax is imported here, on the first traced span, and not before.
+        from jax.profiler import TraceAnnotation
+
+        self.annotation = TraceAnnotation("manu." + self.span.name)
+        self.annotation.__enter__()
+        self.token = _OPEN_SPAN.set((self.ctx, self.span))
+        self.t0 = self.ctx.perf_counter()
+        self.span.start_us = self.t0 * 1e6
         return self.span
 
     def __exit__(self, *exc) -> None:
-        self.span.duration_us = (self.perf_counter() - self.t0) * 1e6
+        self.span.duration_us = (self.ctx.perf_counter() - self.t0) * 1e6
+        _OPEN_SPAN.reset(self.token)
+        self.annotation.__exit__(*exc)
 
 
 # --------------------------------------------------------------------------

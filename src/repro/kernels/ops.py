@@ -7,10 +7,14 @@ the test suite.  The platform is the only selector.
 
 All wrappers here accept un-padded shapes and handle the 128-alignment the
 kernels require (pad rows, mask padding as invalid, strip outputs).
+
+Under a traced request every kernel call records a ``kernel_<name>`` span
+(``_KernelCall``) below the span open in the caller.
 """
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import lru_cache
 
@@ -18,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.telemetry import open_span
 from . import ref
 from .kmeans_assign import kmeans_assign_pallas
 from .l2_topk import l2_topk_pallas
@@ -82,6 +87,84 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+class _KernelCall:
+    """The ``kernel_<name>`` span of one kernel call in a traced request.
+
+    Two children split it: ``h2d`` runs from the first host input sent
+    (``put``) until every input is on the device (``block_until_ready``,
+    so that the copy is timed by itself) and counts the bytes sent;
+    ``result_wait`` runs from the kernel's return until its outputs are
+    numpy arrays.  The rest is the eager pads, the slicing and the launch.
+    """
+
+    __slots__ = ("ctx", "span", "timer", "sent")
+
+    def __init__(self, ctx, parent, name: str) -> None:
+        self.ctx = ctx
+        self.span = ctx.span("kernel_" + name, parent=parent)
+        self.timer = ctx.timed(self.span)
+        self.sent: list = []
+
+    def __enter__(self) -> "_KernelCall":
+        self.timer.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.timer.__exit__(*exc)
+
+    def put(self, x, dtype=None):
+        """``x`` on the device; a host array counts its bytes as sent
+        (after the dtype conversion), a device array counts none."""
+        out = jnp.asarray(x, dtype)
+        if not isinstance(x, jax.Array):
+            self.sent.append(out)
+        return out
+
+    @contextmanager
+    def h2d(self):
+        span = self.ctx.span("h2d", parent=self.span)
+        with self.ctx.timed(span):
+            yield
+            jax.block_until_ready(self.sent)
+        span.bytes_h2d = int(sum(a.nbytes for a in self.sent))
+
+    def result_wait(self):
+        return self.ctx.timed(self.ctx.span("result_wait", parent=self.span))
+
+
+class _Untraced:
+    """The kernel-call tracer of an untraced request: sends and times
+    nothing beyond the call itself.  One shared instance, no allocation."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Untraced":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    @staticmethod
+    def put(x, dtype=None):
+        return jnp.asarray(x, dtype)
+
+    def h2d(self) -> "_Untraced":
+        return self
+
+    def result_wait(self) -> "_Untraced":
+        return self
+
+
+_UNTRACED_CALL = _Untraced()
+
+
+def _kernel_call(name: str):
+    """The tracer of one kernel call: a ``_KernelCall`` under the span the
+    request has open, or the shared no-op when it is not traced."""
+    opened = open_span()
+    return _UNTRACED_CALL if opened is None else _KernelCall(*opened, name)
+
+
 def _pad_rows(arr: jnp.ndarray, multiple: int, fill=0) -> jnp.ndarray:
     n = arr.shape[0]
     pad = (-n) % multiple
@@ -121,18 +204,22 @@ def topk_scan(
     k_eff = min(k, n)
 
     if use_pallas():
-        queries = jnp.asarray(queries, jnp.float32)
-        base = jnp.asarray(base, jnp.float32)
-        v = jnp.ones(n, jnp.int32) if valid is None else jnp.asarray(valid).astype(jnp.int32)
-        tq, tn = _choose_tiles(queries.shape[0], n)
-        qp = _pad_rows(queries, tq)
-        bp = _pad_rows(base, tn)
-        vp = _pad_rows(v, tn, fill=0)
-        vals, idx = l2_topk_pallas(
-            qp, bp, vp, k_eff, metric=metric, tq=tq, tn=tn, interpret=_interpret()
-        )
-        vals, idx = vals[: queries.shape[0]], idx[: queries.shape[0]]
-        vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
+        with _kernel_call("l2_topk") as call:
+            with call.h2d():
+                queries = call.put(queries, jnp.float32)
+                base = call.put(base, jnp.float32)
+                v = jnp.ones(n, jnp.int32) if valid is None else call.put(valid)
+            v = v.astype(jnp.int32)
+            tq, tn = _choose_tiles(queries.shape[0], n)
+            qp = _pad_rows(queries, tq)
+            bp = _pad_rows(base, tn)
+            vp = _pad_rows(v, tn, fill=0)
+            vals, idx = l2_topk_pallas(
+                qp, bp, vp, k_eff, metric=metric, tq=tq, tn=tn, interpret=_interpret()
+            )
+            vals, idx = vals[: queries.shape[0]], idx[: queries.shape[0]]
+            with call.result_wait():
+                vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
     else:
         qn = np.asarray(queries, np.float32)
         bn = np.asarray(base, np.float32)
@@ -317,22 +404,26 @@ def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.
         )
 
     if use_pallas() and (p.size == 0 or np.abs(p).max() < 2**31 - 1):
-        sp = jnp.asarray(s, jnp.float32)
-        pp = jnp.asarray(p.astype(np.int32))
-        tq = 128 if nq >= 128 else max(8, 1 << (nq - 1).bit_length())
-        pad_m = (-m) % 128
-        if pad_m:
-            sp = jnp.pad(sp, ((0, 0), (0, pad_m)), constant_values=np.float32(fill))
-            pp = jnp.pad(pp, ((0, 0), (0, pad_m)), constant_values=-1)
-        tm = next(t for t in (512, 256, 128) if (m + pad_m) % t == 0)
-        sp = _pad_rows(sp, tq)
-        pp = _pad_rows(pp, tq, fill=-1)
-        k_eff = min(k, m)
-        vals, opk = merge_topk_pallas(
-            sp, pp, k_eff, metric=metric, tq=tq, tm=tm, interpret=_interpret()
-        )
-        vals = np.asarray(vals[:nq], np.float32)
-        opk = np.asarray(opk[:nq], np.int64)
+        with _kernel_call("merge_topk") as call:
+            with call.h2d():
+                sp = call.put(s, jnp.float32)
+                pp = call.put(p.astype(np.int32))
+            tq = 128 if nq >= 128 else max(8, 1 << (nq - 1).bit_length())
+            pad_m = (-m) % 128
+            if pad_m:
+                sp = jnp.pad(sp, ((0, 0), (0, pad_m)), constant_values=np.float32(fill))
+                pp = jnp.pad(pp, ((0, 0), (0, pad_m)), constant_values=-1)
+            tm = next(t for t in (512, 256, 128) if (m + pad_m) % t == 0)
+            sp = _pad_rows(sp, tq)
+            pp = _pad_rows(pp, tq, fill=-1)
+            k_eff = min(k, m)
+            vals, opk = merge_topk_pallas(
+                sp, pp, k_eff, metric=metric, tq=tq, tm=tm, interpret=_interpret()
+            )
+            vals, opk = vals[:nq], opk[:nq]
+            with call.result_wait():
+                vals = np.asarray(vals, np.float32)
+                opk = np.asarray(opk, np.int64)
         bad = np.abs(vals) >= 1e38
         vals = np.where(bad, np.float32(fill), vals)
         opk = np.where(bad, -1, opk)
@@ -719,14 +810,18 @@ def pq_adc_topk(luts, codes, k: int, valid=None) -> tuple[np.ndarray, np.ndarray
         return np.full((nq, k), np.inf, np.float32), np.full((nq, k), -1, np.int64)
     k_eff = min(k, n)
     if use_pallas():
-        luts = jnp.asarray(luts, jnp.float32)
-        codes = jnp.asarray(codes, jnp.int32)
-        v = jnp.ones(n, jnp.int32) if valid is None else jnp.asarray(valid).astype(jnp.int32)
-        tn = 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
-        cp = _pad_rows(codes, tn)
-        vp = _pad_rows(v, tn, fill=0)
-        vals, idx = pq_adc_topk_pallas(luts, cp, vp, k_eff, tn=tn, interpret=_interpret())
-        vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
+        with _kernel_call("pq_adc_topk") as call:
+            with call.h2d():
+                luts = call.put(luts, jnp.float32)
+                codes = call.put(codes, jnp.int32)
+                v = jnp.ones(n, jnp.int32) if valid is None else call.put(valid)
+            v = v.astype(jnp.int32)
+            tn = 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
+            cp = _pad_rows(codes, tn)
+            vp = _pad_rows(v, tn, fill=0)
+            vals, idx = pq_adc_topk_pallas(luts, cp, vp, k_eff, tn=tn, interpret=_interpret())
+            with call.result_wait():
+                vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
     else:
         ln = np.asarray(luts, np.float32)
         cn = np.asarray(codes, np.int64)
@@ -757,12 +852,16 @@ def sq_scale(vmin, vmax) -> np.ndarray:
 
 def sq_encode(x, vmin, vmax) -> np.ndarray:
     if use_pallas():
-        x = jnp.asarray(x, jnp.float32)
-        n = x.shape[0]
-        tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
-        xp = _pad_rows(x, tn)
-        out = sq_encode_pallas(xp, jnp.asarray(vmin), jnp.asarray(vmax), tn=tn, interpret=_interpret())
-        return np.asarray(out[:n], np.uint8)
+        with _kernel_call("sq_encode") as call:
+            with call.h2d():
+                x = call.put(x, jnp.float32)
+                lo, hi = call.put(vmin), call.put(vmax)
+            n = x.shape[0]
+            tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
+            xp = _pad_rows(x, tn)
+            out = sq_encode_pallas(xp, lo, hi, tn=tn, interpret=_interpret())[:n]
+            with call.result_wait():
+                return np.asarray(out, np.uint8)
     xn = np.asarray(x, np.float32)
     vmin = np.asarray(vmin, np.float32)
     scale = sq_scale(vmin, vmax)
@@ -772,12 +871,16 @@ def sq_encode(x, vmin, vmax) -> np.ndarray:
 
 def sq_decode(codes, vmin, vmax) -> np.ndarray:
     if use_pallas():
-        codes = jnp.asarray(codes)
-        n = codes.shape[0]
-        tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
-        cp = _pad_rows(codes.astype(jnp.int32), tn)
-        out = sq_decode_pallas(cp, jnp.asarray(vmin), jnp.asarray(vmax), tn=tn, interpret=_interpret())
-        return np.asarray(out[:n])
+        with _kernel_call("sq_decode") as call:
+            with call.h2d():
+                codes = call.put(codes)
+                lo, hi = call.put(vmin), call.put(vmax)
+            n = codes.shape[0]
+            tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
+            cp = _pad_rows(codes.astype(jnp.int32), tn)
+            out = sq_decode_pallas(cp, lo, hi, tn=tn, interpret=_interpret())[:n]
+            with call.result_wait():
+                return np.asarray(out)
     vmin = np.asarray(vmin, np.float32)
     scale = sq_scale(vmin, vmax)
     return np.asarray(codes, np.float32) * scale[None, :] + vmin[None, :]
@@ -794,18 +897,24 @@ def sq_topk_scan(
         return np.full((nq, k), fill, np.float32), np.full((nq, k), -1, np.int64)
     k_eff = min(k, n)
     if use_pallas():
-        queries = jnp.asarray(queries, jnp.float32)
-        codes = jnp.asarray(codes)
-        v = jnp.ones(n, jnp.int32) if valid is None else jnp.asarray(valid).astype(jnp.int32)
-        tq, tn = _choose_tiles(nq, n)
-        qp = _pad_rows(queries, tq)
-        cp = _pad_rows(codes.astype(jnp.int32), tn)
-        vp = _pad_rows(v, tn, fill=0)
-        vals, idx = sq_l2_topk_pallas(
-            qp, cp, jnp.asarray(vmin), jnp.asarray(vmax), vp, k_eff,
-            metric=metric, tq=tq, tn=tn, interpret=_interpret(),
-        )
-        vals, idx = np.asarray(vals[:nq]), np.asarray(idx[:nq], np.int64)
+        with _kernel_call("sq_l2_topk") as call:
+            with call.h2d():
+                queries = call.put(queries, jnp.float32)
+                codes = call.put(codes)
+                lo, hi = call.put(vmin), call.put(vmax)
+                v = jnp.ones(n, jnp.int32) if valid is None else call.put(valid)
+            v = v.astype(jnp.int32)
+            tq, tn = _choose_tiles(nq, n)
+            qp = _pad_rows(queries, tq)
+            cp = _pad_rows(codes.astype(jnp.int32), tn)
+            vp = _pad_rows(v, tn, fill=0)
+            vals, idx = sq_l2_topk_pallas(
+                qp, cp, lo, hi, vp, k_eff,
+                metric=metric, tq=tq, tn=tn, interpret=_interpret(),
+            )
+            vals, idx = vals[:nq], idx[:nq]
+            with call.result_wait():
+                vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
     else:
         decoded = sq_decode(np.asarray(codes), vmin, vmax)
         return topk_scan(np.asarray(queries), decoded, k, metric=metric, valid=valid)
@@ -1000,17 +1109,21 @@ def kmeans_assign(x, centroids) -> tuple[np.ndarray, np.ndarray]:
     """Nearest-centroid assignment: returns (assign [n] int, sqdist [n])."""
     n, ncent = x.shape[0], centroids.shape[0]
     if use_pallas():
-        x = jnp.asarray(x, jnp.float32)
-        c = jnp.asarray(centroids, jnp.float32)
-        tn = 512 if n >= 512 else max(128, 1 << (max(n, 2) - 1).bit_length())
-        tc = 512 if ncent >= 512 else max(128, 1 << (max(ncent, 2) - 1).bit_length())
-        xp = _pad_rows(x, tn)
-        # pad centroids with far-away sentinels so they never win
-        pad = (-ncent) % tc
-        if pad:
-            c = jnp.concatenate([c, jnp.full((pad, c.shape[1]), 1e18, jnp.float32)])
-        a, d = kmeans_assign_pallas(xp, c, tn=tn, tc=tc, interpret=_interpret())
-        return np.asarray(a[:n], np.int64), np.asarray(d[:n])
+        with _kernel_call("kmeans_assign") as call:
+            with call.h2d():
+                x = call.put(x, jnp.float32)
+                c = call.put(centroids, jnp.float32)
+            tn = 512 if n >= 512 else max(128, 1 << (max(n, 2) - 1).bit_length())
+            tc = 512 if ncent >= 512 else max(128, 1 << (max(ncent, 2) - 1).bit_length())
+            xp = _pad_rows(x, tn)
+            # pad centroids with far-away sentinels so they never win
+            pad = (-ncent) % tc
+            if pad:
+                c = jnp.concatenate([c, jnp.full((pad, c.shape[1]), 1e18, jnp.float32)])
+            a, d = kmeans_assign_pallas(xp, c, tn=tn, tc=tc, interpret=_interpret())
+            a, d = a[:n], d[:n]
+            with call.result_wait():
+                return np.asarray(a, np.int64), np.asarray(d)
     xn = np.asarray(x, np.float32)
     cn = np.asarray(centroids, np.float32)
     d2 = (

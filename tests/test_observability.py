@@ -16,6 +16,7 @@ from repro.core.telemetry import (
     TraceContext,
 )
 from repro.core.timestamp import ManualClock
+from repro.kernels import ops
 
 
 # ---------------------------------------------------------------- histogram
@@ -190,3 +191,138 @@ def test_control_plane_events_on_failover(rng):
     assert "node_status_change" in kinds
     dead_events = system.events(kind="node_dead")
     assert dead_events and dead_events[-1].detail["node"] == victim_id
+
+
+# ----------------------------------------------------- spans in the program
+
+
+def _flat_system(rng, rows=600, seal_rows=300):
+    """One query node, ``rows // seal_rows`` sealed FLAT segments."""
+    system = ManuSystem(ManuConfig(num_query_nodes=1, seal_rows=seal_rows))
+    coll = system.create_collection("c", dim=8)
+    coll.insert({"vector": rng.standard_normal((rows, 8)).astype(np.float32)})
+    coll.flush()
+    return system, coll
+
+
+def _strong_search(coll, q, k=5, trace=True):
+    return coll.search(
+        SearchRequest.single(q, k=k, staleness_ms=0.0, trace=trace)
+    )
+
+
+def test_span_start_times_nest_inside_their_parents(rng):
+    system, coll = _flat_system(rng)
+    # Rows the query node has not consumed yet: the search must wait.
+    coll.insert({"vector": rng.standard_normal((7, 8)).astype(np.float32)})
+    res = _strong_search(coll, rng.standard_normal((2, 8)).astype(np.float32))
+    trace = res.trace
+    (wait,) = trace.spans_named("consistency_wait")
+    assert wait in trace.root.children
+    assert wait.detail.startswith("dml/c/")
+    assert [s.name for s in trace.root.children].index("consistency_wait") < \
+        [s.name for s in trace.root.children].index("dispatch")
+    eps = 1e-3  # microseconds of float rounding in start + duration
+    for parent in trace.walk():
+        for child in parent.children:
+            assert child.start_us >= parent.start_us - eps, (parent.name, child.name)
+            assert child.start_us + child.duration_us <= \
+                parent.start_us + parent.duration_us + eps, (parent.name, child.name)
+    # The root holds the reduce, so its self time is not negative.
+    assert trace.root.duration_us >= sum(c.duration_us for c in trace.root.children)
+    assert trace.root.duration_us >= res.waited_ms * 1e3
+
+
+def test_routed_search_records_no_wait_span(rng):
+    system, coll = _flat_system(rng)
+    _strong_search(coll, rng.standard_normal((1, 8)).astype(np.float32))
+    # Eventual reads are covered by what the node consumed: no wait call.
+    res = coll.search(SearchRequest.single(
+        rng.standard_normal((1, 8)).astype(np.float32), k=5,
+        staleness_ms=float("inf"), trace=True))
+    assert res.trace.spans_named("consistency_wait") == []
+    assert res.trace.spans_named("dispatch")
+
+
+def test_kernel_spans_split_copy_and_result_wait(rng, monkeypatch):
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    system, coll = _flat_system(rng)
+    nq, k, dim, seg_rows = 2, 5, 8, 300
+    res = _strong_search(coll, rng.standard_normal((nq, dim)).astype(np.float32), k=k)
+    trace = res.trace
+    scans = [s for s in trace.walk() if s.name.startswith("scan_")]
+    assert [s.name for s in scans] == ["scan_brute_sealed"]
+    l2 = trace.spans_named("kernel_l2_topk")
+    assert len(l2) == 2 and all(s in scans[0].children for s in l2)
+    merges = trace.spans_named("kernel_merge_topk")
+    (node_merge,) = trace.spans_named("node_merge_topk")
+    (proxy_merge,) = trace.spans_named("merge_topk")
+    assert merges == node_merge.children + proxy_merge.children
+    for span in l2 + merges:
+        assert [c.name for c in span.children] == ["h2d", "result_wait"]
+        assert sum(c.duration_us for c in span.children) <= span.duration_us
+    # Bytes by hand: f32 queries and segment rows plus the bool mask...
+    for span in l2:
+        assert span.children[0].bytes_h2d == nq * dim * 4 + seg_rows * dim * 4 + seg_rows
+    # ...and the merges' f32 scores and int32 pks: 2 segments x k on the
+    # node, 1 node x k at the proxy.
+    assert node_merge.children[0].children[0].bytes_h2d == 2 * nq * (2 * k) * 4
+    assert proxy_merge.children[0].children[0].bytes_h2d == 2 * nq * k * 4
+    assert sum(s.bytes_h2d for s in trace.walk()) == \
+        2 * (nq * dim * 4 + seg_rows * dim * 4 + seg_rows) + 2 * nq * 3 * k * 4
+
+
+def test_untraced_search_records_and_waits_for_nothing(rng, monkeypatch):
+    import jax
+
+    from repro.core import telemetry
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    system, coll = _flat_system(rng)
+    q = rng.standard_normal((2, 8)).astype(np.float32)
+    traced = _strong_search(coll, q)  # compiles the same kernels first
+    calls = {"block": 0, "span": 0}
+    real_block = jax.block_until_ready
+
+    def block(x):
+        calls["block"] += 1
+        return real_block(x)
+
+    class CountedSpan(telemetry.Span):
+        def __init__(self, *args, **kwargs):
+            calls["span"] += 1
+            super().__init__(*args, **kwargs)
+
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    monkeypatch.setattr(telemetry, "Span", CountedSpan)
+    res = _strong_search(coll, q, trace=False)
+    assert res.trace is None
+    assert calls == {"block": 0, "span": 0}
+    assert telemetry.open_span() is None
+    np.testing.assert_array_equal(res.pks, traced.pks)
+    # The same counters do count in a traced search.
+    _strong_search(coll, q)
+    assert calls["block"] > 0 and calls["span"] > 0
+
+
+def test_spans_are_profiler_annotations(rng, tmp_path):
+    import glob
+
+    import jax
+
+    system, coll = _flat_system(rng)
+    coll.insert({"vector": rng.standard_normal((7, 8)).astype(np.float32)})
+    q = rng.standard_normal((1, 8)).astype(np.float32)
+    with jax.profiler.trace(str(tmp_path)):
+        res = _strong_search(coll, q)
+    assert res.trace.spans_named("consistency_wait")
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    names = {
+        ev.name
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert {"manu.dispatch", "manu.consistency_wait", "manu.plan_search",
+            "manu.merge_topk"} <= names
+    assert any(n.startswith("manu.scan_") for n in names)
