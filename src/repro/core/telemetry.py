@@ -258,6 +258,7 @@ class Span:
     duration_us: float = 0.0
     rows_scanned: int = 0
     bytes_h2d: int = 0  # host bytes copied to the device
+    bytes_resident: int = 0  # operand bytes read where they lie on the device
     detail: str = ""
     children: list["Span"] = field(default_factory=list)
 
@@ -270,6 +271,7 @@ class Span:
             "duration_us": float(self.duration_us),
             "rows_scanned": int(self.rows_scanned),
             "bytes_h2d": int(self.bytes_h2d),
+            "bytes_resident": int(self.bytes_resident),
             "detail": self.detail,
             "children": [c.to_dict() for c in self.children],
         }
@@ -314,6 +316,8 @@ class RequestTrace:
                 bits.append(f"rows={span.rows_scanned}")
             if span.bytes_h2d:
                 bits.append(f"h2d={span.bytes_h2d}B")
+            if span.bytes_resident:
+                bits.append(f"resident={span.bytes_resident}B")
             bits.append(f"{span.duration_us:.0f}us")
             if span.detail:
                 bits.append(span.detail)

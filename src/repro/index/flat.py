@@ -19,14 +19,20 @@ class FlatIndex(VectorIndex):
     def __init__(self, metric: Metric = Metric.L2, **params):
         super().__init__(metric, **params)
         self.vectors: np.ndarray | None = None
+        # The scan's operand: ``vectors`` kept on the device from the first
+        # search on, for as long as this index lives (``ops.resident``).
+        self._operand = None
 
     def build(self, vectors: np.ndarray) -> None:
         self.vectors = normalize_if_cosine(self.metric, np.asarray(vectors, np.float32))
         self.num_rows = len(self.vectors)
+        self._operand = None
 
     def search(self, queries, k, valid=None):
         q = normalize_if_cosine(self.metric, np.asarray(queries, np.float32))
-        return ops.topk_scan(q, self.vectors, k, metric=scan_metric(self.metric), valid=valid)
+        if self._operand is None:
+            self._operand = ops.resident(self.vectors)
+        return ops.topk_scan(q, self._operand, k, metric=scan_metric(self.metric), valid=valid)
 
     def _state(self):
         return {"vectors": self.vectors}
@@ -34,6 +40,7 @@ class FlatIndex(VectorIndex):
     def _load_state(self, state):
         self.vectors = state["vectors"]
         self.num_rows = len(self.vectors)
+        self._operand = None
 
 
 class SQIndex(VectorIndex):

@@ -53,6 +53,9 @@ class IVFBase(VectorIndex):
         self.nlist = nlist
         self.nprobe = nprobe
         self.centroids: np.ndarray | None = None
+        # The probe's operand: ``centroids`` kept on the device from the
+        # first probe on, for as long as this index lives (``ops.resident``).
+        self._centroid_operand = None
         self.list_offsets: np.ndarray | None = None  # [nlist+1] CSR offsets
         self.row_ids: np.ndarray | None = None  # [n] permutation: list order -> original
 
@@ -60,6 +63,7 @@ class IVFBase(VectorIndex):
         """Cluster and build CSR layout; returns x permuted to list order."""
         self.centroids, assign = kmeans(x, min(self.nlist, max(1, len(x))), seed=0)
         self.nlist = len(self.centroids)
+        self._centroid_operand = None
         order = np.argsort(assign, kind="stable")
         counts = np.bincount(assign, minlength=self.nlist)
         self.list_offsets = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
@@ -69,9 +73,11 @@ class IVFBase(VectorIndex):
     def _probe_lists(self, q: np.ndarray, nprobe: int) -> np.ndarray:
         """[nq, nprobe] most promising list ids per query (-1 = padded)."""
         nprobe = min(nprobe, self.nlist)
+        if self._centroid_operand is None:
+            self._centroid_operand = ops.resident(self.centroids)
         # For IP, the best lists are by centroid similarity; for L2 by distance.
         vals, idx = ops.topk_scan(
-            q, self.centroids, nprobe, metric=scan_metric(self.metric)
+            q, self._centroid_operand, nprobe, metric=scan_metric(self.metric)
         )
         return idx
 
@@ -214,6 +220,7 @@ class IVFBase(VectorIndex):
 
     def _load_base_state(self, state) -> None:
         self.centroids = state["centroids"]
+        self._centroid_operand = None
         self.list_offsets = state["list_offsets"]
         self.row_ids = state["row_ids"]
         self.nlist = len(self.centroids)

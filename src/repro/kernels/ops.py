@@ -6,7 +6,10 @@ bodies are validated against the ``ref.py`` oracles in interpret mode by
 the test suite.  The platform is the only selector.
 
 All wrappers here accept un-padded shapes and handle the 128-alignment the
-kernels require (pad rows, mask padding as invalid, strip outputs).
+kernels require (pad rows, mask padding as invalid, strip outputs).  An input
+is a host array, sent on every call, or, for ``topk_scan``'s base, a
+``ResidentOperand`` (``resident``): an immutable array that stays on the
+device, already padded, and is sent once for the life of its holder.
 
 Under a traced request every kernel call records a ``kernel_<name>`` span
 (``_KernelCall``) below the span open in the caller.
@@ -50,6 +53,8 @@ __all__ = [
     "sq_topk_scan",
     "kmeans_assign",
     "use_pallas",
+    "resident",
+    "ResidentOperand",
     "ivf_probe_schedule",
     "ivf_gather_topk",
     "IVFBucket",
@@ -92,18 +97,20 @@ class _KernelCall:
 
     Two children split it: ``h2d`` runs from the first host input sent
     (``put``) until every input is on the device (``block_until_ready``,
-    so that the copy is timed by itself) and counts the bytes sent;
+    so that the copy is timed by itself) and counts the bytes sent, and
+    apart from them the bytes of the resident operands it read in place;
     ``result_wait`` runs from the kernel's return until its outputs are
     numpy arrays.  The rest is the eager pads, the slicing and the launch.
     """
 
-    __slots__ = ("ctx", "span", "timer", "sent")
+    __slots__ = ("ctx", "span", "timer", "sent", "resident")
 
     def __init__(self, ctx, parent, name: str) -> None:
         self.ctx = ctx
         self.span = ctx.span("kernel_" + name, parent=parent)
         self.timer = ctx.timed(self.span)
         self.sent: list = []
+        self.resident = 0
 
     def __enter__(self) -> "_KernelCall":
         self.timer.__enter__()
@@ -114,7 +121,11 @@ class _KernelCall:
 
     def put(self, x, dtype=None):
         """``x`` on the device; a host array counts its bytes as sent
-        (after the dtype conversion), a device array counts none."""
+        (after the dtype conversion), a device array counts none, and a
+        resident operand counts its own as resident."""
+        if isinstance(x, ResidentOperand):
+            self.resident += x.nbytes
+            return x.array
         out = jnp.asarray(x, dtype)
         if not isinstance(x, jax.Array):
             self.sent.append(out)
@@ -127,6 +138,7 @@ class _KernelCall:
             yield
             jax.block_until_ready(self.sent)
         span.bytes_h2d = int(sum(a.nbytes for a in self.sent))
+        span.bytes_resident = self.resident
 
     def result_wait(self):
         return self.ctx.timed(self.ctx.span("result_wait", parent=self.span))
@@ -146,6 +158,8 @@ class _Untraced:
 
     @staticmethod
     def put(x, dtype=None):
+        if isinstance(x, ResidentOperand):
+            return x.array
         return jnp.asarray(x, dtype)
 
     def h2d(self) -> "_Untraced":
@@ -174,10 +188,39 @@ def _pad_rows(arr: jnp.ndarray, multiple: int, fill=0) -> jnp.ndarray:
     return jnp.pad(arr, widths, constant_values=fill)
 
 
+def _row_tile(n: int) -> int:
+    return 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
+
+
 def _choose_tiles(nq: int, n: int) -> tuple[int, int]:
     tq = 128 if nq >= 128 else max(8, 1 << (nq - 1).bit_length())
-    tn = 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
-    return tq, tn
+    return tq, _row_tile(n)
+
+
+@dataclass(frozen=True, eq=False)
+class ResidentOperand:
+    """A host array held on the device for as long as this object lives:
+    f32, its rows zero-padded to the row tile ``topk_scan`` uses for
+    ``shape[0]`` rows, so that a call neither sends nor pads it."""
+
+    array: jax.Array  # [rows padded to _row_tile(rows), ...] float32
+    shape: tuple[int, ...]  # the host array's shape, before padding
+
+    @property
+    def nbytes(self) -> int:
+        """The bytes a call would otherwise send: f32, unpadded."""
+        return 4 * int(np.prod(self.shape))
+
+
+def resident(x):
+    """``x`` as a ``ResidentOperand`` for ``topk_scan``'s base where the
+    Pallas path runs; elsewhere (and for an empty array) ``x`` itself, so
+    the host paths read it as before."""
+    if not use_pallas() or len(x) == 0:
+        return x
+    return ResidentOperand(
+        _pad_rows(jnp.asarray(x, jnp.float32), _row_tile(len(x))), tuple(x.shape)
+    )
 
 
 def topk_scan(
@@ -192,6 +235,8 @@ def topk_scan(
     Returns (scores [nq,k], idx [nq,k]); ascending distance for L2,
     descending similarity for IP.  ``valid`` masks rows (MVCC visibility /
     delete bitmap).  Invalid or out-of-range results carry idx == -1.
+    ``base`` is a host array or a ``ResidentOperand``, which the kernel
+    reads where it lies (already padded: ``_pad_rows`` adds nothing).
     """
     n = base.shape[0]
     if n == 0:
