@@ -402,37 +402,39 @@ class CompactionCoordinator:
         live_rows: int,
         seal_rows: int,
     ) -> dict:
-        compact_ts = self.tso.next()
-        dd = self.tombstones.get(collection) or {}
-        doomed = None
-        if dd:
-            pks, dts = flatten_tombstones(dd)
-            doomed = ops.eff_tombstones(pks, dts, compact_ts)
-        if doomed is None:
-            doomed = (np.empty(0, np.int64), np.empty(0, np.int64))
-        n_targets = max(1, -(-live_rows // seal_rows))  # ceil
-        task_id = f"ct-{self._next_task}"
-        self._next_task += 1
-        payload = {
-            "msg": "compaction_task",
-            "task_id": task_id,
-            "collection": collection,
-            "shard": shard,
-            "partition": partition,
-            "sources": list(sources),
-            "targets": [
-                self.data_coord.allocate_segment_id() for _ in range(n_targets)
-            ],
-            "seal_rows": seal_rows,
-            "compact_ts": compact_ts,
-            "doomed_pks": doomed[0],
-            "doomed_eff": doomed[1],
-        }
-        self.pending[task_id] = payload
-        self.broker.publish(
-            COORD_CHANNEL,
-            LogEntry(ts=compact_ts, type=EntryType.COORD, payload=payload),
-        )
+        # The task's timestamp is its entry's: held in order until published.
+        with self.broker.order_lock:
+            compact_ts = self.tso.next()
+            dd = self.tombstones.get(collection) or {}
+            doomed = None
+            if dd:
+                pks, dts = flatten_tombstones(dd)
+                doomed = ops.eff_tombstones(pks, dts, compact_ts)
+            if doomed is None:
+                doomed = (np.empty(0, np.int64), np.empty(0, np.int64))
+            n_targets = max(1, -(-live_rows // seal_rows))  # ceil
+            task_id = f"ct-{self._next_task}"
+            self._next_task += 1
+            payload = {
+                "msg": "compaction_task",
+                "task_id": task_id,
+                "collection": collection,
+                "shard": shard,
+                "partition": partition,
+                "sources": list(sources),
+                "targets": [
+                    self.data_coord.allocate_segment_id() for _ in range(n_targets)
+                ],
+                "seal_rows": seal_rows,
+                "compact_ts": compact_ts,
+                "doomed_pks": doomed[0],
+                "doomed_eff": doomed[1],
+            }
+            self.pending[task_id] = payload
+            self.broker.publish(
+                COORD_CHANNEL,
+                LogEntry(ts=compact_ts, type=EntryType.COORD, payload=payload),
+            )
         if self.events is not None:
             self.events.emit(
                 "compaction_task", "compaction_coord",
@@ -453,10 +455,11 @@ class CompactionCoordinator:
         payload = {"msg": "retention_advance", "horizon_ts": horizon_ts}
         if collection is not None:
             payload["collection"] = collection
-        self.broker.publish(
-            COORD_CHANNEL,
-            LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=payload),
-        )
+        with self.broker.order_lock:
+            self.broker.publish(
+                COORD_CHANNEL,
+                LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=payload),
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -623,31 +626,32 @@ class CompactionNode:
         )
         self.metrics.inc("compactions_total")
         self.metrics.inc("compaction_rows_purged_total", rows_in - n_live)
-        self.broker.publish(
-            COORD_CHANNEL,
-            LogEntry(
-                ts=self.tso.next(),
-                type=EntryType.COORD,
-                payload={
-                    "msg": "segment_compacted",
-                    "task_id": task["task_id"],
-                    "collection": coll,
-                    "segments": out_segments,
-                    "sources": sources,
-                    "shard": metas[0]["shard"],
-                    "partition": partition,
-                    "num_rows": n_live,
-                    "rows_purged": rows_in - n_live,
-                    "compact_ts": task["compact_ts"],
-                    # only tombstones actually folded into THIS rewrite are
-                    # prunable — a doomed pk living in another segment must
-                    # keep its delta-delete entry
-                    "folded_pks": folded_pks,
-                    "attr_fields": attr_fields,
-                    "built_by": self.node_id,
-                },
-            ),
-        )
+        with self.broker.order_lock:
+            self.broker.publish(
+                COORD_CHANNEL,
+                LogEntry(
+                    ts=self.tso.next(),
+                    type=EntryType.COORD,
+                    payload={
+                        "msg": "segment_compacted",
+                        "task_id": task["task_id"],
+                        "collection": coll,
+                        "segments": out_segments,
+                        "sources": sources,
+                        "shard": metas[0]["shard"],
+                        "partition": partition,
+                        "num_rows": n_live,
+                        "rows_purged": rows_in - n_live,
+                        "compact_ts": task["compact_ts"],
+                        # only tombstones actually folded into THIS rewrite are
+                        # prunable — a doomed pk living in another segment must
+                        # keep its delta-delete entry
+                        "folded_pks": folded_pks,
+                        "attr_fields": attr_fields,
+                        "built_by": self.node_id,
+                    },
+                ),
+            )
         return True
 
 
@@ -718,18 +722,19 @@ class GCReaper:
             self.meta.delete(f"segment/{coll}/{sid}")
             for ak in list(self.meta.scan(f"attr_index/{coll}/{sid}/")):
                 self.meta.delete(ak)
-            self.broker.publish(
-                COORD_CHANNEL,
-                LogEntry(
-                    ts=self.tso.next(),
-                    type=EntryType.COORD,
-                    payload={
-                        "msg": "segment_gc",
-                        "collection": coll,
-                        "segment_id": sid,
-                    },
-                ),
-            )
+            with self.broker.order_lock:
+                self.broker.publish(
+                    COORD_CHANNEL,
+                    LogEntry(
+                        ts=self.tso.next(),
+                        type=EntryType.COORD,
+                        payload={
+                            "msg": "segment_gc",
+                            "collection": coll,
+                            "segment_id": sid,
+                        },
+                    ),
+                )
             report["segments"].append((coll, sid))
         self.segments_reclaimed += len(report["segments"])
         self.bytes_reclaimed += report["bytes"]

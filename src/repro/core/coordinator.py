@@ -11,6 +11,7 @@ the meta store (see ``QueryCoordinator.recover_state``).
 
 from __future__ import annotations
 
+import functools
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -225,8 +226,23 @@ class SegmentAlloc:
     last_alloc_ms: float = 0.0
 
 
+def _locked(method):
+    """Run ``method`` holding its object's ``_lock``."""
+
+    @functools.wraps(method)
+    def run(self, *args, **kwargs):
+        with self._lock:
+            return method(self, *args, **kwargs)
+
+    return run
+
+
 class DataCoordinator:
     def __init__(self, broker: LogBroker, meta: MetaStore, tso: TSO, clock: Clock):
+        # Loggers (pump and client threads), data nodes (the background
+        # build thread) and the coordinators (pump thread) share the maps
+        # below: every method that reads or writes them holds this lock.
+        self._lock = threading.RLock()
         self.broker = broker
         self.meta = meta
         self.tso = tso
@@ -246,6 +262,7 @@ class DataCoordinator:
     def allocate_pks(self, collection: str, n: int):
         return self.id_alloc.allocate(collection, n)
 
+    @_locked
     def _alloc_sid(self) -> int:
         """Allocate a segment id; the sequence is checkpointed to the meta
         store so a restarted coordinator never reuses one."""
@@ -258,6 +275,7 @@ class DataCoordinator:
         info = self.meta.get(f"collection/{collection}") or {}
         return int(info.get("seal_rows", DEFAULT_SEAL_ROWS))
 
+    @_locked
     def assign_segment(
         self,
         collection: str,
@@ -278,9 +296,11 @@ class DataCoordinator:
         return alloc.segment_id
 
     # --------------------------------------------------------------- sealing
+    @_locked
     def should_seal(self, collection: str, segment_id: int) -> bool:
         return (collection, segment_id) in self._to_seal
 
+    @_locked
     def on_sealed(
         self,
         collection: str,
@@ -323,6 +343,7 @@ class DataCoordinator:
         """Reserve a fresh segment id (compaction rewrite targets)."""
         return self._alloc_sid()
 
+    @_locked
     def on_compacted(
         self,
         collection: str,
@@ -371,6 +392,7 @@ class DataCoordinator:
                 collection, t["segment_id"], t["num_rows"], attr_fields
             )
 
+    @_locked
     def flush(self, collection: str) -> list[int]:
         """Force-seal every growing segment of a collection."""
         sealed = []
@@ -382,6 +404,7 @@ class DataCoordinator:
             self._growing[(coll, shard, part)] = SegmentAlloc(self._alloc_sid())
         return sealed
 
+    @_locked
     def seal_idle(self, max_idle_ms: float) -> list[int]:
         """Time-based sealing (paper: seal after a period without inserts)."""
         now = self.clock.now_ms()
@@ -393,6 +416,7 @@ class DataCoordinator:
                 self._growing[(coll, shard, part)] = SegmentAlloc(self._alloc_sid())
         return sealed
 
+    @_locked
     def sealed_segments(self, collection: str) -> list[int]:
         return sorted(sid for (c, sid) in self._sealed_rows if c == collection)
 
@@ -400,6 +424,7 @@ class DataCoordinator:
         info = self.meta.get(f"segment/{collection}/{segment_id}") or {}
         return info.get("partition", DEFAULT_PARTITION)
 
+    @_locked
     def partition_segments(self, collection: str, partition: str) -> list[int]:
         """Sealed segments currently placed under ``partition``."""
         return sorted(
@@ -409,6 +434,7 @@ class DataCoordinator:
             and self.segment_partition(collection, sid) == partition
         )
 
+    @_locked
     def drop_partition_state(self, collection: str, partition: str, ts: int) -> list[int]:
         """Forget a dropped partition's placement: clear its growing
         allocations and retire its sealed segments (marked for GC).
@@ -431,6 +457,7 @@ class DataCoordinator:
             self.segment_map.apply(collection, remove=sids, ts=ts)
         return sids
 
+    @_locked
     def record_sealed_position(self, collection: str, shard: int, pos: int) -> None:
         key = (collection, shard)
         cur = self.replay_position(collection, shard)
@@ -440,6 +467,7 @@ class DataCoordinator:
             # durable checkpoint: a restarted system replays from here
             self.meta.put(f"replay/{collection}/{shard}", {"pos": new})
 
+    @_locked
     def replay_position(self, collection: str, shard: int) -> int:
         """WAL position from which a recovering node must replay."""
         key = (collection, shard)
@@ -598,10 +626,11 @@ class IndexCoordinator:
                         continue
                     task = self._task_of(p["collection"], p["segment_id"], spec)
                     self.pending_tasks[key] = task
-                    self.broker.publish(
-                        COORD_CHANNEL,
-                        LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=task),
-                    )
+                    with self.broker.order_lock:
+                        self.broker.publish(
+                            COORD_CHANNEL,
+                            LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=task),
+                        )
                     if self.events is not None:
                         self.events.emit(
                             "index_task", "index_coord",
@@ -702,10 +731,11 @@ class IndexCoordinator:
                     continue
                 task = self._task_of(coll, sid, spec)
                 self.pending_tasks[k] = task
-                self.broker.publish(
-                    COORD_CHANNEL,
-                    LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=task),
-                )
+                with self.broker.order_lock:
+                    self.broker.publish(
+                        COORD_CHANNEL,
+                        LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=task),
+                    )
                 reissued += 1
         return {"built": adopted, "claims_cleared": cleared, "tasks_reissued": reissued}
 
@@ -724,10 +754,11 @@ class IndexCoordinator:
             )
             task = self._task_of(collection, segment_id, spec)
             self.pending_tasks[(collection, segment_id, field)] = task
-            self.broker.publish(
-                COORD_CHANNEL,
-                LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=task),
-            )
+            with self.broker.order_lock:
+                self.broker.publish(
+                    COORD_CHANNEL,
+                    LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=task),
+                )
 
 
 # ---------------------------------------------------------------------------
@@ -993,10 +1024,11 @@ class QueryCoordinator:
         return nodes
 
     def _publish(self, payload: dict) -> None:
-        self.broker.publish(
-            COORD_CHANNEL,
-            LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=payload),
-        )
+        with self.broker.order_lock:
+            self.broker.publish(
+                COORD_CHANNEL,
+                LogEntry(ts=self.tso.next(), type=EntryType.COORD, payload=payload),
+            )
 
     def step(self) -> bool:
         with self._mutex:

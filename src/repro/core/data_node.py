@@ -15,7 +15,7 @@ from .log import COORD_CHANNEL, EntryType, LogBroker, LogEntry, Subscription
 from .binlog import write_attr_satellites, write_segment_binlog
 from .object_store import ObjectStore
 from .segment import DEFAULT_PARTITION, Segment
-from .telemetry import MetricsRegistry
+from .telemetry import MetricsRegistry, background_step
 from .timestamp import TSO
 
 
@@ -59,6 +59,11 @@ class DataNode:
     def step(self) -> bool:
         if not self.alive:
             return False
+        progress = self._consume_available()
+        progress |= self._flush_sealed()
+        return progress
+
+    def _consume_available(self) -> bool:
         progress = False
         for sub in list(self.subscriptions.values()):
             watermark = self._applied_pos.get(sub.channel, -1)
@@ -70,7 +75,6 @@ class DataNode:
                 progress |= self._consume(entry, entry.position + 1)
                 watermark = entry.position
             self._applied_pos[sub.channel] = watermark
-        progress |= self._flush_sealed()
         return progress
 
     def _is_archived(self, coll: str, sid: int) -> bool:
@@ -125,32 +129,43 @@ class DataNode:
 
     def _flush_sealed(self) -> bool:
         """Seal + flush segments the data coordinator marked."""
+        due = [key for key in list(self.growing) if self.data_coord.should_seal(*key)]
+        if not due:
+            return False
+        # A logger marks a segment to seal under the broker's ordering lock,
+        # before it publishes the rows that fill it; under that lock every
+        # row of a marked segment is in the log, so take them all first.
+        with self.broker.order_lock:
+            self._consume_available()
+        for key in due:
+            seg = self.growing.pop(key, None)
+            if seg is not None:
+                with background_step(self.metrics, "seal"):
+                    self._seal(key, seg)
+        return True
+
+    def _seal(self, key: tuple[str, int], seg: Segment) -> None:
         import time as _t
 
-        progress = False
-        for key in list(self.growing):
-            coll, sid = key
-            if not self.data_coord.should_seal(coll, sid):
-                continue
-            seg = self.growing.pop(key)
-            t0 = _t.perf_counter()
-            seg.seal()
-            keys = write_segment_binlog(self.store, seg)
-            # Attribute-index satellites ride behind the binlog meta (the
-            # flush-complete proof): a crash in this window leaves a sealed
-            # binlog without satellites, which reconcile_sealed rebuilds.
-            attr_keys = write_attr_satellites(self.store, seg)
-            self.metrics.inc("data_node_attr_indexes_built_total", len(attr_keys))
-            self.metrics.observe(
-                "data_node_seal_flush_us", (_t.perf_counter() - t0) * 1e6
-            )
-            self.metrics.inc("data_node_segments_sealed_total")
-            self.metrics.inc("data_node_rows_flushed_total", seg.num_rows)
-            ts = self.tso.next()
+        coll, sid = key
+        t0 = _t.perf_counter()
+        seg.seal()
+        keys = write_segment_binlog(self.store, seg)
+        # Attribute-index satellites ride behind the binlog meta (the
+        # flush-complete proof): a crash in this window leaves a sealed
+        # binlog without satellites, which reconcile_sealed rebuilds.
+        attr_keys = write_attr_satellites(self.store, seg)
+        self.metrics.inc("data_node_attr_indexes_built_total", len(attr_keys))
+        self.metrics.observe(
+            "data_node_seal_flush_us", (_t.perf_counter() - t0) * 1e6
+        )
+        self.metrics.inc("data_node_segments_sealed_total")
+        self.metrics.inc("data_node_rows_flushed_total", seg.num_rows)
+        with self.broker.order_lock:
             self.broker.publish(
                 COORD_CHANNEL,
                 LogEntry(
-                    ts=ts,
+                    ts=self.tso.next(),
                     type=EntryType.COORD,
                     payload={
                         "msg": "segment_sealed",
@@ -167,12 +182,10 @@ class DataNode:
                     },
                 ),
             )
-            self.data_coord.on_sealed(
-                coll, sid, seg.num_rows, seg.partition, shard=seg.shard,
-                attr_fields=sorted(attr_keys),
-            )
-            progress = True
-        return progress
+        self.data_coord.on_sealed(
+            coll, sid, seg.num_rows, seg.partition, shard=seg.shard,
+            attr_fields=sorted(attr_keys),
+        )
 
     def drop_partition(self, collection: str, partition: str) -> int:
         """Discard growing segments of a dropped partition (their rows

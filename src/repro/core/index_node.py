@@ -16,7 +16,7 @@ from .collection import Metric
 from .log import COORD_CHANNEL, EntryType, LogBroker, LogEntry, Subscription
 from .meta_store import MetaStore
 from .object_store import ObjectStore
-from .telemetry import MetricsRegistry
+from .telemetry import MetricsRegistry, background_step
 from .timestamp import TSO
 
 
@@ -75,17 +75,18 @@ class IndexNode:
 
         t0 = _t.perf_counter()
         try:
-            vectors = read_binlog_column(self.store, coll, sid, column)
-            spec = IndexSpec(
-                kind=kind,
-                metric=Metric(task.get("metric", "l2")),
-                params=task.get("params") or {},
-                field=field,
-            )
-            index = create_index(spec)
-            index.build(vectors)
-            key = index_key(coll, sid, field, kind)
-            self.store.put(key, index.save())
+            with background_step(self.metrics, "index_build"):
+                vectors = read_binlog_column(self.store, coll, sid, column)
+                spec = IndexSpec(
+                    kind=kind,
+                    metric=Metric(task.get("metric", "l2")),
+                    params=task.get("params") or {},
+                    field=field,
+                )
+                index = create_index(spec)
+                index.build(vectors)
+                key = index_key(coll, sid, field, kind)
+                self.store.put(key, index.save())
         except Exception:
             # Release the claim so the task stays takeable.  (A simulated
             # Crash is a BaseException: the claim leaks, as with a real
@@ -97,23 +98,24 @@ class IndexNode:
             "index_build_us", (_t.perf_counter() - t0) * 1e6,
             labels={"kind": kind},
         )
-        self.metrics.inc("index_builds_total", labels={"kind": kind})
+        self.metrics.inc("index_builds_total")
 
-        self.broker.publish(
-            COORD_CHANNEL,
-            LogEntry(
-                ts=self.tso.next(),
-                type=EntryType.COORD,
-                payload={
-                    "msg": "index_built",
-                    "collection": coll,
-                    "segment_id": sid,
-                    "field": field,
-                    "column": column,
-                    "index_kind": kind,
-                    "index_key": key,
-                    "built_by": self.node_id,
-                },
-            ),
-        )
+        with self.broker.order_lock:
+            self.broker.publish(
+                COORD_CHANNEL,
+                LogEntry(
+                    ts=self.tso.next(),
+                    type=EntryType.COORD,
+                    payload={
+                        "msg": "index_built",
+                        "collection": coll,
+                        "segment_id": sid,
+                        "field": field,
+                        "column": column,
+                        "index_kind": kind,
+                        "index_key": key,
+                        "built_by": self.node_id,
+                    },
+                ),
+            )
         return True

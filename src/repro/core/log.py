@@ -26,6 +26,7 @@ types of requests from interfering"):
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from enum import Enum
 from typing import Any, Callable, Iterator
@@ -67,6 +68,10 @@ class _Channel:
     entries: list[LogEntry] = field(default_factory=list)
     last_tick_ts: int = 0
     bytes_published: int = 0
+    # Host time (perf_counter) of the last time-tick, and the longest gap
+    # between two ticks since the gaps were last taken.
+    last_tick_at: float | None = None
+    tick_gap_max_s: float = 0.0
 
 
 def _entry_nbytes(entry: LogEntry) -> int:
@@ -94,6 +99,11 @@ class LogBroker:
         self._channels: dict[str, _Channel] = {}
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
+        # Held by every publisher from the moment it takes a timestamp
+        # from the TSO until the entries stamped with it are published, so
+        # that publishers on different threads reach each channel in
+        # timestamp order (``publish`` refuses anything else).
+        self.order_lock = threading.RLock()
 
     # --------------------------------------------------------------- admin
     def create_channel(self, name: str) -> None:
@@ -136,6 +146,10 @@ class LogBroker:
             ch.bytes_published += _entry_nbytes(stamped)
             if entry.type is EntryType.TIME_TICK:
                 ch.last_tick_ts = entry.ts
+                now = time.perf_counter()
+                if ch.last_tick_at is not None:
+                    ch.tick_gap_max_s = max(ch.tick_gap_max_s, now - ch.last_tick_at)
+                ch.last_tick_at = now
             self._cv.notify_all()
             return position
 
@@ -164,6 +178,21 @@ class LogBroker:
             return self._cv.wait_for(
                 lambda: self._channels[channel].last_tick_ts >= min_ts, timeout=timeout_s
             )
+
+    def take_tick_gaps(self) -> dict[str, float]:
+        """Per DML channel, the longest host-clock gap (ms) between two
+        consecutive time-ticks since the last take, the gap still open
+        counted as if a tick came now; starts the next period.  A reader
+        takes once at a window's start and once at its end."""
+        now = time.perf_counter()
+        out = {}
+        with self._lock:
+            for name, ch in self._channels.items():
+                if not name.startswith("dml/") or ch.last_tick_at is None:
+                    continue
+                out[name] = 1e3 * max(ch.tick_gap_max_s, now - ch.last_tick_at)
+                ch.tick_gap_max_s = 0.0
+        return out
 
     def entries_between(
         self, channel: str, ts_lo: int, ts_hi: int

@@ -20,8 +20,6 @@ Loggers also emit the periodic time-ticks that drive delta consistency.
 
 from __future__ import annotations
 
-import threading
-
 import numpy as np
 
 from ..kernels import ops
@@ -63,7 +61,9 @@ class Logger:
         # Serializes LSN-assign + WAL publish: the broker enforces
         # monotonic per-channel timestamps, so a threaded scheduler flush
         # racing a user-thread mutation must not interleave the two steps.
-        self._lock = threading.Lock()
+        # It is the broker's ordering lock, shared with every other logger
+        # (each ticks every DML channel) and every coord-channel publisher.
+        self._lock = broker.order_lock
 
     # ----------------------------------------------------------- mutations
     def mutate(
@@ -337,13 +337,14 @@ class Logger:
         """Emit time-ticks on owned channels if the interval elapsed."""
         now = self.clock.now_ms()
         emitted = 0
-        for ch in channels:
-            last = self._last_tick_ms.get(ch, -1e18)
-            if force or (now - last) >= self.tick_interval_ms:
-                ts = self.tso.next()
-                self.broker.publish(
-                    ch, LogEntry(ts=ts, type=EntryType.TIME_TICK, payload={})
-                )
-                self._last_tick_ms[ch] = now
-                emitted += 1
+        with self._lock:  # ordered with mutations, as their LSNs are
+            for ch in channels:
+                last = self._last_tick_ms.get(ch, -1e18)
+                if force or (now - last) >= self.tick_interval_ms:
+                    ts = self.tso.next()
+                    self.broker.publish(
+                        ch, LogEntry(ts=ts, type=EntryType.TIME_TICK, payload={})
+                    )
+                    self._last_tick_ms[ch] = now
+                    emitted += 1
         return emitted

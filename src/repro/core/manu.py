@@ -24,11 +24,19 @@ Two driving modes:
   clock and emit time-ticks explicitly.  This is what the tests use.
 * **threaded** — background threads pump components and loggers emit ticks
   on the wall clock; searches block on watermarks.  Used by the latency /
-  elasticity benchmarks (Figs 9, 12).
+  elasticity benchmarks (Figs 9, 12).  The pump thread steps the loggers'
+  ticks, the coordinators, the query nodes and the ingest scheduler; the
+  build thread steps the data, index and compaction nodes, whose seals,
+  builds and rewrites take seconds (the paper's independent subscribers);
+  a loader thread reads and decodes the sealed segments and indexes query
+  nodes are told to load (a growing copy serves until its sealed copy is
+  installed); a watchdog keeps leases alive and reconciles.  With the threads stopped,
+  ``pump()`` steps every node itself.
 """
 
 from __future__ import annotations
 
+import concurrent.futures
 import threading
 import time
 from dataclasses import dataclass, field
@@ -94,6 +102,10 @@ from .segment import DEFAULT_PARTITION
 from .telemetry import Event, EventLog, MetricsRegistry
 from .time_travel import RestoredCollection, TimeTravel
 from .timestamp import INFINITE_STALENESS, TSO, Clock, ManualClock
+
+# How long a threaded consistency wait gives the time-ticks to reach its
+# target, and then the query node to consume them, before it fails.
+THREADED_WAIT_S = 10.0
 
 
 @dataclass
@@ -480,11 +492,17 @@ class ManuSystem:
             metrics=self.telemetry, event_log=self.event_log, sleep=sleep,
         )
 
-        self._build_processes()
-        self.collections: dict[str, ManuCollection] = {}
-
         self._threads: list[threading.Thread] = []
         self._stop = threading.Event()
+        # While the threads run: the build thread (data, index and
+        # compaction nodes step there, not in ``pump``), the query nodes'
+        # index loader, and the first exception a thread died of.
+        self._build_thread: threading.Thread | None = None
+        self._loader: concurrent.futures.ThreadPoolExecutor | None = None
+        self._thread_failure: tuple[str, BaseException] | None = None
+
+        self._build_processes()
+        self.collections: dict[str, ManuCollection] = {}
         if self.config.threaded:
             self.start_threads()
 
@@ -573,6 +591,7 @@ class ManuSystem:
         qn = QueryNode(node_id, self.broker, self.store, self.tso,
                        slice_rows=self.config.slice_rows,
                        metrics=self.telemetry)
+        qn.loader = self._loader
         self.query_nodes[node_id] = qn
         self.query_coord.register_node(node_id)
         return qn
@@ -721,6 +740,7 @@ class ManuSystem:
         qn = QueryNode(node_id, self.broker, self.store, self.tso,
                        slice_rows=self.config.slice_rows,
                        metrics=self.telemetry)
+        qn.loader = self._loader
         self.query_nodes[node_id] = qn
         self.query_coord.register_node(node_id)
         self.query_coord.reconciler.reconcile()
@@ -785,25 +805,26 @@ class ManuSystem:
             if self.meta.get(f"partition/{coll}/{part}") is None:
                 continue  # dropped partitions stay dropped
             attr_fields = sorted(rebuild_attr_satellites(self.store, coll, sid))
-            self.broker.publish(
-                COORD_CHANNEL,
-                LogEntry(
-                    ts=self.tso.next(),
-                    type=EntryType.COORD,
-                    payload={
-                        "msg": "segment_sealed",
-                        "collection": coll,
-                        "segment_id": sid,
-                        "shard": bm.get("shard", 0),
-                        "partition": part,
-                        "num_rows": bm["num_rows"],
-                        "binlog_keys": {},
-                        "checkpoint_pos": bm["checkpoint_pos"],
-                        "min_ts": bm.get("min_ts", 0),
-                        "max_ts": bm.get("max_ts", 0),
-                    },
-                ),
-            )
+            with self.broker.order_lock:
+                self.broker.publish(
+                    COORD_CHANNEL,
+                    LogEntry(
+                        ts=self.tso.next(),
+                        type=EntryType.COORD,
+                        payload={
+                            "msg": "segment_sealed",
+                            "collection": coll,
+                            "segment_id": sid,
+                            "shard": bm.get("shard", 0),
+                            "partition": part,
+                            "num_rows": bm["num_rows"],
+                            "binlog_keys": {},
+                            "checkpoint_pos": bm["checkpoint_pos"],
+                            "min_ts": bm.get("min_ts", 0),
+                            "max_ts": bm.get("max_ts", 0),
+                        },
+                    ),
+                )
             self.data_coord.on_sealed(
                 coll, sid, bm["num_rows"], part, shard=bm.get("shard", 0),
                 attr_fields=attr_fields,
@@ -1043,20 +1064,21 @@ class ManuSystem:
                     seg.pks()
                 )
 
-        self.broker.publish(
-            COORD_CHANNEL,
-            LogEntry(
-                ts=self.tso.next(),
-                type=EntryType.COORD,
-                payload={
-                    "msg": "partition_dropped",
-                    "collection": name,
-                    "partition": partition,
-                    "segment_ids": sids,
-                    "drop_ts": ts,
-                },
-            ),
-        )
+        with self.broker.order_lock:
+            self.broker.publish(
+                COORD_CHANNEL,
+                LogEntry(
+                    ts=self.tso.next(),
+                    type=EntryType.COORD,
+                    payload={
+                        "msg": "partition_dropped",
+                        "collection": name,
+                        "partition": partition,
+                        "segment_ids": sids,
+                        "drop_ts": ts,
+                    },
+                ),
+            )
         for dn in self.data_nodes:
             dn.drop_partition(name, partition)
 
@@ -1068,19 +1090,20 @@ class ManuSystem:
                     exclusive, np.concatenate(surviving_pks), assume_unique=False
                 )
         if exclusive.size:
-            self.broker.publish(
-                COORD_CHANNEL,
-                LogEntry(
-                    ts=self.tso.next(),
-                    type=EntryType.COORD,
-                    payload={
-                        "msg": "tombstones_folded",
-                        "collection": name,
-                        "folded_pks": exclusive,
-                        "compact_ts": ts,
-                    },
-                ),
-            )
+            with self.broker.order_lock:
+                self.broker.publish(
+                    COORD_CHANNEL,
+                    LogEntry(
+                        ts=self.tso.next(),
+                        type=EntryType.COORD,
+                        payload={
+                            "msg": "tombstones_folded",
+                            "collection": name,
+                            "folded_pks": exclusive,
+                            "compact_ts": ts,
+                        },
+                    ),
+                )
             pruned = prune_folded(
                 self.compaction_coord.tombstones.get(name) or {}, exclusive, ts
             )
@@ -1139,7 +1162,10 @@ class ManuSystem:
 
     # ---------------------------------------------------------------- pump
     def pump(self, rounds: int = 1) -> bool:
-        """One cooperative scheduling round over every component."""
+        """One cooperative scheduling round over every component (the
+        data, index and compaction nodes only while no build thread
+        steps them)."""
+        builders = self._build_thread is None
         progress = False
         for _ in range(rounds):
             # Alive nodes heartbeat every round: consistency waits advance
@@ -1154,20 +1180,36 @@ class ManuSystem:
                     lg.tick(self.broker.channels("dml/"))
                 except Crash as c:
                     self._mark_crashed("logger", lg, c)
-            for dn in self.data_nodes:
-                progress |= self._crashable_step("data", dn)
+            if builders:
+                for dn in self.data_nodes:
+                    progress |= self._crashable_step("data", dn)
             progress |= self.index_coord.step()
-            for ix in self.index_nodes:
-                progress |= self._crashable_step("index", ix)
+            if builders:
+                for ix in self.index_nodes:
+                    progress |= self._crashable_step("index", ix)
             progress |= self.compaction_coord.step()
-            for cn in self.compaction_nodes:
-                progress |= self._crashable_step("compaction", cn)
+            if builders:
+                for cn in self.compaction_nodes:
+                    progress |= self._crashable_step("compaction", cn)
             progress |= self.query_coord.step()
             for qn in self.query_nodes.values():
                 progress |= self._crashable_step("query", qn)
             # Ingest scheduler age trigger: admitted-but-unflushed writes
             # never outlive ``ingest_flush_ms`` of pump activity.
             progress |= self.scheduler.step()
+        return progress
+
+    def _step_builders(self) -> bool:
+        """One round of the build thread: data nodes (seal and archive),
+        index nodes (build) and compaction nodes (rewrite)."""
+        progress = False
+        for kind, nodes in (
+            ("data", self.data_nodes),
+            ("index", self.index_nodes),
+            ("compaction", self.compaction_nodes),
+        ):
+            for node in list(nodes):
+                progress |= self._crashable_step(kind, node)
         return progress
 
     def _crashable_step(self, kind: str, node) -> bool:
@@ -1435,20 +1477,44 @@ class ManuSystem:
     def _threaded_wait(
         self, node: QueryNode, guarantee: GuaranteeTs, channels=None
     ) -> None:
+        """Block until the time-ticks reach the guarantee's target on the
+        node's channels and the node has consumed them.  Raises
+        ``TimeoutError`` (with the diagnostic dump) when either takes
+        longer than ``THREADED_WAIT_S``, and ``RuntimeError`` once a
+        background thread has died: a read is never answered without its
+        guarantee."""
+        self._raise_thread_failure()
         if channels is None:
             channels = [ch for ch in node.subscriptions if ch.startswith("dml/")]
         else:
             channels = list(channels)
         target = guarantee.wait_target_ts()
+        deadline = time.monotonic() + THREADED_WAIT_S
         for ch in channels:
-            self.broker.wait_for_tick(ch, target, timeout_s=10.0)
-        # ensure node consumed the ticks
-        deadline = time.time() + 10.0
-        while time.time() < deadline:
-            if all(node.subscriptions[ch].last_tick_seen >= target for ch in channels
-                   if ch in node.subscriptions):
-                return
+            while not self.broker.wait_for_tick(ch, target, timeout_s=0.05):
+                self._raise_thread_failure()
+                if time.monotonic() > deadline:
+                    raise TimeoutError(self._diagnostic_dump(
+                        f"no time-tick >= {target} on {ch} "
+                        f"within {THREADED_WAIT_S}s"
+                    ))
+        deadline = time.monotonic() + THREADED_WAIT_S
+        while not all(
+            node.subscriptions[ch].last_tick_seen >= target
+            for ch in channels if ch in node.subscriptions
+        ):
+            self._raise_thread_failure()
+            if time.monotonic() > deadline:
+                raise TimeoutError(self._diagnostic_dump(
+                    f"query node {node.node_id} did not consume the tick "
+                    f">= {target} within {THREADED_WAIT_S}s"
+                ))
             time.sleep(0.001)
+
+    def _raise_thread_failure(self) -> None:
+        if self._thread_failure is not None:
+            name, exc = self._thread_failure
+            raise RuntimeError(f"background thread '{name}' died: {exc!r}") from exc
 
     # -------------------------------------------------------- time travel
     def checkpoint_collection(self, name: str) -> None:
@@ -1471,45 +1537,89 @@ class ManuSystem:
 
     # ------------------------------------------------------------- threads
     def start_threads(self) -> None:
+        """Start the background threads (module docstring); a call while
+        they run starts nothing."""
+        if self._threads:
+            return
         # Each pump round and reconcile is a profiler annotation
         # (``manu.pump``, ``manu.reconcile``), so a device trace shows
         # when the background threads ran.
         from jax.profiler import TraceAnnotation
 
         self._stop.clear()
+        self._thread_failure = None
         # The pump thread owns node stepping; the proxy's failover waits
         # must sleep instead of stepping nodes themselves.
         self.proxy.pump_fn = lambda: time.sleep(self.config.pump_sleep_s)
+        self._loader = concurrent.futures.ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="manu-loader"
+        )
+        for qn in list(self.query_nodes.values()):
+            qn.loader = self._loader
 
-        def pump_loop():
-            while not self._stop.is_set():
-                with TraceAnnotation("manu.pump"):
-                    self.pump()
+        def pump_round():
+            with TraceAnnotation("manu.pump"):
+                self.pump()
+            time.sleep(self.config.pump_sleep_s)
+
+        def build_round():
+            if not self._step_builders():
                 time.sleep(self.config.pump_sleep_s)
 
-        def watchdog_loop():
-            last_reconcile = 0.0
-            while not self._stop.is_set():
-                for node_id, qn in list(self.query_nodes.items()):
-                    if qn.alive and node_id in self.query_coord.nodes:
-                        self.query_coord.heartbeat(node_id)
-                now = time.time()
-                if now - last_reconcile >= self.config.reconcile_interval_s:
-                    last_reconcile = now
-                    with TraceAnnotation("manu.reconcile"):
-                        self.query_coord.reconciler.reconcile()
-                time.sleep(0.05)
+        last_reconcile = 0.0
 
-        for fn in (pump_loop, watchdog_loop):
-            t = threading.Thread(target=fn, daemon=True)
+        def watchdog_round():
+            nonlocal last_reconcile
+            for node_id, qn in list(self.query_nodes.items()):
+                if qn.alive and node_id in self.query_coord.nodes:
+                    self.query_coord.heartbeat(node_id)
+            now = time.time()
+            if now - last_reconcile >= self.config.reconcile_interval_s:
+                last_reconcile = now
+                with TraceAnnotation("manu.reconcile"):
+                    self.query_coord.reconciler.reconcile()
+            time.sleep(0.05)
+
+        for name, body in (
+            ("pump", pump_round),
+            ("build", build_round),
+            ("watchdog", watchdog_round),
+        ):
+            t = threading.Thread(
+                target=self._run_thread, args=(name, body),
+                name=f"manu-{name}", daemon=True,
+            )
+            if name == "build":
+                self._build_thread = t
             t.start()
             self._threads.append(t)
 
+    def _run_thread(self, name: str, body) -> None:
+        """Run ``body`` until the threads stop; an exception ends the
+        thread and is kept for the next consistency wait to raise."""
+        try:
+            while not self._stop.is_set():
+                body()
+        except BaseException as exc:
+            if self._thread_failure is None:
+                self._thread_failure = (name, exc)
+            self.event_log.emit("thread_died", "system", thread=name,
+                                error=repr(exc))
+
     def stop_threads(self) -> None:
+        """Stop the threads, each after the round it is in (a build may
+        take seconds); pending segment and index loads finish and are installed by the
+        query nodes' next step."""
         self._stop.set()
         for t in self._threads:
-            t.join(timeout=2.0)
+            t.join()
         self._threads.clear()
+        self._build_thread = None
+        for qn in list(self.query_nodes.values()):
+            qn.loader = None
+        if self._loader is not None:
+            self._loader.shutdown(wait=True)
+            self._loader = None
         self.proxy.pump_fn = None
 
     # ------------------------------------------------------------ metrics

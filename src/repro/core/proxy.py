@@ -31,7 +31,7 @@ from ..kernels import ops
 from .collection import CollectionInfo, FieldType, Metric
 from .consistency import GuaranteeTs
 from .coordinator import QueryCoordinator
-from .log import shard_of_channel, shard_of_pk
+from .log import dml_channel, shard_of_channel, shard_of_pk
 from .logger_node import Logger
 from .meta_store import MetaStore
 from .query_node import QueryNode, StalePlanError
@@ -548,6 +548,9 @@ class Proxy:
 
     # ------------------------------------------------- replica-aware dispatch
     _FAILOVER_ROUNDS = 200  # pump iterations before giving up on a unit
+    # ... and, while a live replica is still loading the unit, seconds: the
+    # threaded runtime reads and decodes a sealed copy on its loader thread.
+    _LOAD_WAIT_S = 10.0
 
     def _alive(self, node_id: str) -> bool:
         qn = self.query_nodes.get(node_id)
@@ -682,13 +685,28 @@ class Proxy:
                     "consistency_routes_total", labels={"outcome": "waited"}
                 )
         orphans: list[int] = []
-        for sid in sorted(coord.placement_for(collection)):
+        for sid, reps in sorted(coord.placement_for(collection).items()):
             pick = self._pick_replica(collection, sid, chosen=chosen)
-            if pick is None:
-                orphans.append(sid)
-            else:
+            if pick is not None:
                 chosen.setdefault(pick, set()).add(sid)
+            elif not (
+                all(self._alive(n) for n in reps)
+                and self._growing_serves(collection, sid, routed)
+            ):
+                orphans.append(sid)
         return chosen, orphans, waits, routed
+
+    def _growing_serves(
+        self, collection: str, sid: int, routed: "dict[str, set[str]]"
+    ) -> bool:
+        """Whether a node this plan routes the segment's DML channel to
+        still holds its growing copy: a freshly sealed segment whose live
+        replicas are still loading it is served from there meanwhile."""
+        for n, channels in routed.items():
+            gs = self.query_nodes[n].growing.get((collection, sid))
+            if gs is not None and dml_channel(collection, gs.segment.shard) in channels:
+                return True
+        return False
 
     def _pump(self) -> None:
         """Advance coordination-message delivery while waiting on a
@@ -713,13 +731,23 @@ class Proxy:
                 if not self._alive(n) and n in coord.nodes:
                     coord.on_node_down(n)
         out: dict[str, set[int]] = {}
-        for _ in range(self._FAILOVER_ROUNDS):
+        deadline = time.monotonic() + self._LOAD_WAIT_S
+        rounds = 0
+        while True:
             for sid in sorted(missing):
                 pick = self._pick_replica(collection, sid, chosen=out)
                 if pick is not None:
                     out.setdefault(pick, set()).add(sid)
             missing -= {s for units in out.values() for s in units}
-            if not missing:
+            rounds += 1
+            if not missing or (
+                rounds >= self._FAILOVER_ROUNDS
+                and (time.monotonic() > deadline or not any(
+                    self._alive(n)
+                    for sid in missing
+                    for n in coord.replica_sets.get((collection, sid), ())
+                ))
+            ):
                 break
             coord.reconciler.reconcile()
             self._pump()

@@ -33,9 +33,27 @@ from .log import EntryType, LogBroker, LogEntry, Subscription, shard_of_channel
 from .object_store import ObjectStore
 from .request import PRIMARY_VECTOR_COLUMN, AnnsQuery, NodeSearchRequest
 from .segment import DEFAULT_PARTITION, Segment, add_tombstone, flatten_tombstones
-from .telemetry import UNTRACED, MetricsRegistry
+from .telemetry import UNTRACED, MetricsRegistry, background_step
 
 TEMP_INDEX_SLICE_ROWS = 2_048  # scaled-down default of the paper's 10k
+
+# Coordination messages that change a node's sealed copies.
+_AFTER_LOADS = frozenset(
+    {"partition_dropped", "retention_advance", "release_segment", "retire_segment"}
+)
+
+
+class SliceIndex(IVFFlatIndex):
+    """The temporary index of one full slice of a growing segment.  A
+    search probes every slice of every growing segment (tens at a
+    deployment's seal size), each over 16 centroids: the probes run in
+    numpy, where each costs less than one kernel call."""
+
+    def _probe_lists(self, q: np.ndarray, nprobe: int) -> np.ndarray:
+        # Slice indexes are built L2 (``_build_slice_indexes``).
+        d = ((q[:, None, :] - self.centroids[None, :, :]) ** 2).sum(-1)
+        return np.argsort(d, axis=1, kind="stable")[:, : min(nprobe, self.nlist)]
+
 
 # Selectivity-adaptive filtered-search thresholds.  ``n_comb`` is the
 # surviving row count (visible AND matching), ``n_vis`` the visible count.
@@ -104,6 +122,10 @@ class SealedHandle:
     # keeps reading the old version until the retention horizon releases it.
     visible_from_ts: int = 0
     retired_at_ts: int | None = None
+    # TSO timestamp taken as the node loaded this copy.  A request pinned
+    # before it was planned before the assignment, and may leave the
+    # segment out of every node's scope while its growing copy is gone.
+    loaded_ts: int = 0
     # Indexes on additional vector columns (multi-vector schemas), keyed by
     # segment column name.
     extra_indexes: dict[str, VectorIndex] = field(default_factory=dict)
@@ -177,6 +199,7 @@ class SearchPlan:
     # Per-unit planning report for observability: dicts with segment_id,
     # strategy, estimated and actual selectivity.
     filter_info: list = field(default_factory=list)
+    growing_rows: int = 0  # rows of the growing segments planned over
 
     def units(self) -> "list[ScanUnit]":
         return (
@@ -245,6 +268,14 @@ class QueryNode:
         self.inflight = 0  # concurrent search_request count (any kind)
         self.inflight_primary = 0  # dispatch-load key used by the picker
         self.inject_delay_s = 0.0  # straggler fault injection (tests/benches)
+        # Set while the threaded runtime runs: an executor that fetches and
+        # decodes the sealed segments and indexes this node is told to
+        # load, off the thread that steps it; ``step`` installs them in the
+        # order their messages came.
+        self.loader = None
+        # ("segment", (collection, segment_id, visible_from_ts), Future) or
+        # ("index", load_index args, Future)
+        self._fetches: list = []
 
     # --------------------------------------------------------- subscriptions
     def subscribe(self, channel: str, from_position: int = 0) -> None:
@@ -271,7 +302,9 @@ class QueryNode:
     def step(self) -> bool:
         if not self.alive:
             return False
-        progress = False
+        # Without a loader (threads stopped) a fetch still under way is
+        # waited for, so a cooperative round ends with it installed.
+        progress = self._install_fetched(wait=self.loader is None)
         if self.coord_sub is not None:
             watermark = self._applied_pos.get("coord", -1)
             for entry in self.coord_sub.poll():
@@ -301,6 +334,10 @@ class QueryNode:
             return False
         p = entry.payload
         msg = p.get("msg")
+        if msg in _AFTER_LOADS and self._fetches:
+            # It changes sealed copies: the loads whose messages came
+            # before it are installed first.
+            self._install_fetched(wait=True)
         if msg == "segment_loaded":
             # Another node owns the sealed copy now: hand off our growing rows.
             if p.get("node_id") != self.node_id:
@@ -347,30 +384,32 @@ class QueryNode:
         if p.get("node_id") != self.node_id:
             return False
         if msg == "load_segment":
-            self.load_sealed(
-                p["collection"], p["segment_id"],
-                visible_from_ts=p.get("visible_from_ts", 0),
-            )
-            if self.tso is not None:
-                self.broker.publish(
-                    "coord",
-                    LogEntry(
-                        ts=self.tso.next(),
-                        type=EntryType.COORD,
-                        payload={
-                            "msg": "segment_loaded",
-                            "node_id": self.node_id,
-                            "collection": p["collection"],
-                            "segment_id": p["segment_id"],
-                        },
-                    ),
-                )
+            args = (p["collection"], p["segment_id"], p.get("visible_from_ts", 0))
+            if self.loader is not None:
+                # The growing copy serves until the sealed one is installed.
+                self._fetches.append((
+                    "segment", args,
+                    self.loader.submit(self._fetch_segment, *args[:2]),
+                ))
+            else:
+                self.load_sealed(*args)
+                self._announce_loaded(*args[:2])
             return True
         if msg == "load_index":
-            self.load_index(
+            args = (
                 p["collection"], p["segment_id"], p["index_kind"], p["index_key"],
-                column=p.get("column", PRIMARY_VECTOR_COLUMN),
+                p.get("column", PRIMARY_VECTOR_COLUMN),
             )
+            key = args[:2]
+            if self.loader is not None and (key in self.sealed or any(
+                kind == "segment" and a[:2] == key for kind, a, _f in self._fetches
+            )):
+                # The sealed segment serves by brute force meanwhile.
+                self._fetches.append(
+                    ("index", args, self.loader.submit(self._fetch_index, args[3]))
+                )
+            else:
+                self.load_index(*args)
             return True
         if msg == "release_segment":
             self.release_segment(p["collection"], p["segment_id"])
@@ -442,29 +481,88 @@ class QueryNode:
         mismatched slices to the brute tail, so IP/cosine growing reads
         stay exact."""
         progress = False
-        for gs in self.growing.values():
+        for gs in list(self.growing.values()):
             for s in gs.segment.full_slices():
                 if s in gs.slice_index_built:
                     continue
                 lo, hi = gs.segment.slice_bounds(s)
-                idx = IVFFlatIndex(metric=Metric.L2, nlist=16, nprobe=4)
-                idx.build(gs.segment.vectors()[lo:hi])
+                with background_step(self.metrics, "slice_build"):
+                    idx = SliceIndex(metric=Metric.L2, nlist=16, nprobe=4)
+                    idx.build(gs.segment.vectors()[lo:hi])
                 gs.slice_index_built[s] = idx
                 progress = True
         return progress
+
+    def _fetch_segment(self, collection: str, segment_id: int):
+        """Read and decode a sealed segment and its attribute indexes (on
+        the loader while the threads run)."""
+        with background_step(self.metrics, "handoff"):
+            seg = load_segment(self.store, collection, segment_id)
+            return seg, self._attr_indexes_for(seg)
+
+    def _fetch_index(self, index_key: str) -> VectorIndex:
+        """Read and decode one index (on the loader while the threads run)."""
+        with background_step(self.metrics, "handoff"):
+            return VectorIndex.load(self.store.get(index_key))
+
+    def _install_fetched(self, wait: bool) -> bool:
+        """Install fetched segments and indexes in the order their messages
+        came; stop at the first still being fetched unless ``wait``."""
+        progress = False
+        while self._fetches:
+            kind, args, fetch = self._fetches[0]
+            if not (wait or fetch.done()):
+                break
+            self._fetches.pop(0)
+            if kind == "segment":
+                self._install_segment(*args, *fetch.result())
+                self._announce_loaded(*args[:2])
+            else:
+                self._install_index(args, fetch.result())
+            progress = True
+        return progress
+
+    def _announce_loaded(self, collection: str, segment_id: int) -> None:
+        """Tell the other nodes to hand off their growing copies."""
+        if self.tso is None:
+            return
+        with self.broker.order_lock:
+            self.broker.publish(
+                "coord",
+                LogEntry(
+                    ts=self.tso.next(),
+                    type=EntryType.COORD,
+                    payload={
+                        "msg": "segment_loaded",
+                        "node_id": self.node_id,
+                        "collection": collection,
+                        "segment_id": segment_id,
+                    },
+                ),
+            )
 
     # ---------------------------------------------------------- assignments
     def load_sealed(
         self, collection: str, segment_id: int, visible_from_ts: int = 0
     ) -> None:
+        if (collection, segment_id) not in self.sealed:
+            self._install_segment(
+                collection, segment_id, visible_from_ts,
+                *self._fetch_segment(collection, segment_id),
+            )
+
+    def _install_segment(
+        self, collection: str, segment_id: int, visible_from_ts: int,
+        seg: Segment, attr_indexes: dict,
+    ) -> None:
         key = (collection, segment_id)
         if key in self.sealed:
             return
-        seg = load_segment(self.store, collection, segment_id)
         self.sealed[key] = SealedHandle(
             seg,
             visible_from_ts=visible_from_ts,
-            attr_indexes=self._attr_indexes_for(seg),
+            attr_indexes=attr_indexes,
+            loaded_ts=self.tso.next() if self.tso is not None else 0,
         )
         # Hand-off: drop our growing copy of the same segment.
         self.growing.pop(key, None)
@@ -509,12 +607,15 @@ class QueryNode:
         index_key: str,
         column: str = PRIMARY_VECTOR_COLUMN,
     ) -> None:
+        self.load_sealed(collection, segment_id)
+        args = (collection, segment_id, kind, index_key, column)
+        self._install_index(args, self._fetch_index(index_key))
+
+    def _install_index(self, args: tuple, index: VectorIndex) -> None:
+        collection, segment_id, kind, _key, column = args
         handle = self.sealed.get((collection, segment_id))
-        if handle is None:
-            self.load_sealed(collection, segment_id)
-            handle = self.sealed[(collection, segment_id)]
-        index = VectorIndex.load(self.store.get(index_key))
-        handle.set_index(column, index, kind)
+        if handle is not None:  # else released while it was fetched
+            handle.set_index(column, index, kind)
 
     def release_segment(self, collection: str, segment_id: int) -> None:
         self.sealed.pop((collection, segment_id), None)
@@ -576,12 +677,12 @@ class QueryNode:
     def memory_rows(self, collection: str | None = None) -> int:
         rows = sum(
             h.segment.num_rows
-            for (c, _sid), h in self.sealed.items()
+            for (c, _sid), h in list(self.sealed.items())
             if collection is None or c == collection
         )
         rows += sum(
             g.segment.num_rows
-            for (c, _sid), g in self.growing.items()
+            for (c, _sid), g in list(self.growing.items())
             if collection is None or c == collection
         )
         return rows
@@ -590,10 +691,11 @@ class QueryNode:
         """(collection, segment_id, is_sealed) -> live row count; used by
         the per-collection entity count (replicated segments dedup upstream)."""
         out: dict[tuple[str, int, bool], int] = {}
-        for (c, sid), h in self.sealed.items():
+        growing = list(self.growing.items())  # before sealed: see plan_search
+        for (c, sid), h in list(self.sealed.items()):
             if c == collection and h.retired_at_ts is None:
                 out[(c, sid, True)] = h.segment.num_rows
-        for (c, sid), g in self.growing.items():
+        for (c, sid), g in growing:
             if c == collection:
                 out[(c, sid, False)] = g.segment.num_rows
         return out
@@ -616,7 +718,7 @@ class QueryNode:
             return None
         from ..kernels import ops
 
-        pks, dts = flatten_tombstones(dd)
+        pks, dts = flatten_tombstones(dict(dd))  # the pump thread adds to it
         return ops.eff_tombstones(pks, dts, ts)
 
     _DOOMED_UNSET = object()  # sentinel: standalone call, derive the set here
@@ -685,6 +787,12 @@ class QueryNode:
         plan = SearchPlan()
         if doomed is QueryNode._DOOMED_UNSET:
             doomed = self._request_doomed_pks(collection, ts)
+        # The thread that steps this node may hand a growing segment off
+        # (load its sealed copy, then drop the growing one) while this
+        # plans: copy the growing map first, so that a segment missing
+        # from it is already in the sealed copy.
+        growing = list(self.growing.items())
+        sealed = list(self.sealed.items())
         prune = set(partitions) if partitions is not None else None
         scope = set(segments) if segments is not None else None
         unit_cols = metric is Metric.COSINE
@@ -697,7 +805,7 @@ class QueryNode:
 
         # ---- sealed segments: indexed or brute ----
         served: set[int] = set()
-        for (coll, sid), handle in self.sealed.items():
+        for (coll, sid), handle in sealed:
             if coll != collection:
                 continue
             if scope is not None and sid in scope:
@@ -713,6 +821,7 @@ class QueryNode:
                 scope is not None
                 and handle.retired_at_ts is None
                 and sid not in scope
+                and handle.loaded_ts <= ts
             ):
                 continue  # another replica owns this plan unit
             seg = handle.segment
@@ -751,17 +860,23 @@ class QueryNode:
             )
 
         # ---- growing segments: temp slice indexes + brute tail ----
+        # Each is read through one view (``Segment.snapshot``): rows the
+        # pump thread appends later carry timestamps after any this plan
+        # was pinned at once its wait is over, so the cut loses nothing.
         shard_scope = set(shards) if shards is not None else None
-        for (coll, sid), gs in self.growing.items():
-            if coll != collection:
+        handed_off = {key for key, _h in sealed}
+        for (coll, sid), gs in growing:
+            if coll != collection or (coll, sid) in handed_off:
                 continue
             seg = gs.segment
             if shard_scope is not None and seg.shard not in shard_scope:
                 continue  # another replica serves this channel's rows
             if prune is not None and seg.partition not in prune:
                 continue
+            seg = seg.snapshot()
             if seg.num_rows == 0:
                 continue
+            plan.growing_rows += seg.num_rows
             mask = self._visible(collection, seg, ts, doomed)
             if filter_masks and sid in filter_masks:
                 mask = mask & filter_masks[sid]
@@ -785,7 +900,9 @@ class QueryNode:
                 continue
             covered = np.zeros(seg.num_rows, dtype=bool)
             if column == PRIMARY_VECTOR_COLUMN:
-                for s_idx, temp in gs.slice_index_built.items():
+                for s_idx, temp in list(gs.slice_index_built.items()):
+                    if (s_idx + 1) * gs.segment.slice_rows > seg.num_rows:
+                        continue  # its slice filled after the view was taken
                     if metric is not None and temp.metric is not metric:
                         # metric-mismatched temp index (built L2 off the
                         # WAL): leave the slice in the brute tail so the
@@ -798,11 +915,15 @@ class QueryNode:
                     plan.growing_slice.append(
                         ScanUnit(sid, pks[lo:hi], mask[lo:hi], index=temp)
                     )
-            # tail = rows not covered by any temp index yet
+            # tail = rows not covered by any temp index yet, from its first
+            # row on: the slices before it are covered, and a scan of the
+            # whole segment would copy it for every search and compile for
+            # every row count it grows through
             tail_mask = mask & ~covered
             if tail_mask.any():
+                lo = int(np.argmax(tail_mask))
                 plan.brute_tail.append(
-                    ScanUnit(sid, pks, tail_mask, vectors=vectors)
+                    ScanUnit(sid, pks[lo:], tail_mask[lo:], vectors=vectors[lo:])
                 )
         return plan
 
@@ -1127,6 +1248,7 @@ class QueryNode:
                 pspan.segment_ids = tuple(
                     sorted({u.segment_id for u in plan.units()})
                 )
+                pspan.growing_rows = plan.growing_rows
                 if request.filter is not None and plan.filter_info:
                     fspan = ctx.span(
                         "filter_plan", parent=parent, node_id=self.node_id,
@@ -1231,13 +1353,18 @@ class QueryNode:
         out: dict[str, list] = {c: [] for c in columns}
         if want.size:
             doomed = self._request_doomed_pks(collection, ts)
-            sources: list[Segment] = [
+            growing = list(self.growing.items())  # before sealed: see plan_search
+            sealed = list(self.sealed.items())
+            handed_off = {key for key, _h in sealed}
+            sources: list = [
                 h.segment
-                for (c, _sid), h in self.sealed.items()
+                for (c, _sid), h in sealed
                 if c == collection and h.covers_ts(ts)
             ]
             sources += [
-                g.segment for (c, _sid), g in self.growing.items() if c == collection
+                g.segment.snapshot()
+                for key, g in growing
+                if key[0] == collection and key not in handed_off
             ]
             for seg in sources:
                 if seg.num_rows == 0:

@@ -16,6 +16,11 @@ touching the new one, and visibility flips atomically at one timestamp.
 see — this one primitive yields delta consistency, repeatable reads, and
 time travel.
 
+Reads while a writer appends: columns grow in buffers that double when
+full, so ``snapshot()`` hands out a ``SegmentView`` — one row count and
+every column, tombstone set and slice bound cut to it — taken under the
+segment's lock, which later appends never change.
+
 Partitions: each segment carries the partition tag it was placed under
 (paper §3.1: collection → shard → partition → segment); the query-node
 planner prunes non-matching segments before any scan.
@@ -49,8 +54,9 @@ def add_tombstone(dd: dict, pk, ts: int) -> bool:
     if isinstance(cur, list):
         if ts in cur:
             return False
-        cur.append(int(ts))
-        cur.sort()
+        # A new list, not one grown in place: a reader on another thread
+        # (``flatten_tombstones``) keeps the one it took.
+        dd[pk] = sorted(cur + [int(ts)])
         return True
     if cur == ts:
         return False
@@ -88,6 +94,101 @@ class SegmentStats:
     max_ts: int
 
 
+class _Column:
+    """An append-only column: rows land in a buffer that doubles when it
+    fills, so an append copies only its own rows and the first ``n`` rows
+    read as a view that later appends never write into."""
+
+    __slots__ = ("buf", "n")
+
+    def __init__(self, empty: np.ndarray):
+        self.buf = empty  # zero rows: the dtype and shape of an empty read
+        self.n = 0
+
+    def append(self, rows: np.ndarray) -> None:
+        m = len(rows)
+        dtype = np.promote_types(self.buf.dtype, rows.dtype) if self.n else rows.dtype
+        if self.n + m > len(self.buf) or dtype != self.buf.dtype:
+            grown = np.empty(
+                (max(self.n + m, 2 * len(self.buf)),) + rows.shape[1:], dtype
+            )
+            if self.n:
+                grown[: self.n] = self.buf[: self.n]
+            self.buf = grown
+        self.buf[self.n : self.n + m] = rows
+        self.n += m
+
+    def view(self) -> np.ndarray:
+        return self.buf[: self.n]
+
+
+@dataclass(frozen=True, eq=False)
+class SegmentView:
+    """A segment as one read sees it: its first ``num_rows`` rows, with
+    the tombstones recorded when the view was taken.  It answers the read
+    calls a ``Segment`` answers, so a planner reads a view where it would
+    read the segment and every array it gets agrees in length."""
+
+    segment: "Segment"
+    num_rows: int
+    columns: dict  # "pk", "vector", "ts" and each extra field, cut to num_rows
+    tombstones: "tuple[np.ndarray, np.ndarray] | None"
+
+    @property
+    def segment_id(self) -> int:
+        return self.segment.segment_id
+
+    @property
+    def shard(self) -> int:
+        return self.segment.shard
+
+    @property
+    def partition(self) -> str:
+        return self.segment.partition
+
+    @property
+    def extra_fields(self) -> tuple[str, ...]:
+        return self.segment.extra_fields
+
+    def pks(self) -> np.ndarray:
+        return self.columns["pk"]
+
+    def vectors(self) -> np.ndarray:
+        return self.columns["vector"]
+
+    def timestamps(self) -> np.ndarray:
+        return self.columns["ts"]
+
+    def extra(self, name: str) -> np.ndarray:
+        return self.columns[name]
+
+    def unit_column(self, name: str = "vector") -> np.ndarray:
+        # Rows normalize one by one: the segment's cached column cut to
+        # this view holds exactly its rows.
+        return self.segment.unit_column(name)[: self.num_rows]
+
+    def visible_mask(self, ts: int) -> np.ndarray:
+        """MVCC visibility at query timestamp ``ts``: rows written at or
+        before ``ts`` and not killed by any tombstone in ``(row_ts, ts]``."""
+        from ..kernels import ops
+
+        mask = self.columns["ts"] <= ts
+        if self.tombstones is not None:
+            eff = ops.eff_tombstones(self.tombstones[0], self.tombstones[1], ts)
+            if eff is not None:
+                mask &= ~ops.tombstone_mask(
+                    self.columns["pk"], self.columns["ts"], eff[0], eff[1]
+                )
+        return mask
+
+    def full_slices(self) -> list[int]:
+        return list(range(self.num_rows // self.segment.slice_rows))
+
+    def slice_bounds(self, slice_idx: int) -> tuple[int, int]:
+        lo = slice_idx * self.segment.slice_rows
+        return lo, min(lo + self.segment.slice_rows, self.num_rows)
+
+
 class Segment:
     """Columnar in-memory segment with MVCC visibility.
 
@@ -114,13 +215,15 @@ class Segment:
         self.state = SegmentState.GROWING
         self.extra_fields = tuple(extra_fields)
 
-        self._pks: list[np.ndarray] = []
-        self._vectors: list[np.ndarray] = []
-        self._timestamps: list[np.ndarray] = []
-        self._extras: dict[str, list[np.ndarray]] = {f: [] for f in self.extra_fields}
+        self._cols: dict[str, _Column] = {
+            "pk": _Column(np.empty(0, np.int64)),
+            "vector": _Column(np.empty((0, dim), np.float32)),
+            "ts": _Column(np.empty(0, np.int64)),
+            **{f: _Column(np.empty(0)) for f in self.extra_fields},
+        }
         self._num_rows = 0
 
-        # Materialized (concatenated) columns; invalidated on append.
+        # The columns' views cut to ``num_rows``; invalidated on append.
         self._mat: dict[str, np.ndarray] | None = None
         # Row-normalized vector columns (cosine scans), cached per column
         # name; invalidated on append alongside ``_mat``.
@@ -155,14 +258,14 @@ class Segment:
             n = len(pks)
             if not (len(vectors) == len(timestamps) == n):
                 raise ValueError("pks/vectors/timestamps length mismatch")
-            self._pks.append(np.asarray(pks))
-            self._vectors.append(np.asarray(vectors, dtype=np.float32))
-            self._timestamps.append(np.asarray(timestamps, dtype=np.int64))
+            missing = [f for f in self.extra_fields if (extras or {}).get(f) is None]
+            if missing:
+                raise ValueError(f"missing extra field '{missing[0]}'")
+            self._cols["pk"].append(np.asarray(pks))
+            self._cols["vector"].append(np.asarray(vectors, dtype=np.float32))
+            self._cols["ts"].append(np.asarray(timestamps, dtype=np.int64))
             for name in self.extra_fields:
-                src = (extras or {}).get(name)
-                if src is None:
-                    raise ValueError(f"missing extra field '{name}'")
-                self._extras[name].append(np.asarray(src))
+                self._cols[name].append(np.asarray(extras[name]))
             self._num_rows += n
             self._mat = None
             self._unit.clear()
@@ -174,10 +277,10 @@ class Segment:
         insert half of an upsert at the same LSN) are untouched.
         Returns the number of tombstones recorded."""
         with self._lock:
-            existing = set(np.asarray(self.pks()).tolist())
+            pks = np.asarray(pks)
             hits = 0
-            for pk in np.asarray(pks).tolist():
-                if pk in existing and add_tombstone(self._deleted, pk, ts):
+            for pk in pks[np.isin(pks, self.pks())].tolist():
+                if add_tombstone(self._deleted, pk, ts):
                     hits += 1
             if hits:
                 self._del_flat = None
@@ -191,27 +294,17 @@ class Segment:
     def _materialize(self) -> dict[str, np.ndarray]:
         with self._lock:
             if self._mat is None:
-                cols: dict[str, np.ndarray] = {}
-                cols["pk"] = (
-                    np.concatenate(self._pks) if self._pks else np.empty(0, np.int64)
-                )
-                cols["vector"] = (
-                    np.concatenate(self._vectors)
-                    if self._vectors
-                    else np.empty((0, self.dim), np.float32)
-                )
-                cols["ts"] = (
-                    np.concatenate(self._timestamps)
-                    if self._timestamps
-                    else np.empty(0, np.int64)
-                )
-                for name in self.extra_fields:
-                    chunks = self._extras[name]
-                    cols[name] = (
-                        np.concatenate(chunks) if chunks else np.empty(0)
-                    )
-                self._mat = cols
+                self._mat = {name: c.view() for name, c in self._cols.items()}
             return self._mat
+
+    def snapshot(self) -> SegmentView:
+        """The segment as it stands now: its row count with every column,
+        the tombstones and the slice bounds cut to it, read together under
+        the lock (see ``SegmentView``)."""
+        with self._lock:
+            return SegmentView(
+                self, self._num_rows, self._materialize(), self._tombstones_flat()
+            )
 
     @property
     def num_rows(self) -> int:
@@ -256,18 +349,8 @@ class Segment:
         return ~self.visible_mask(np.iinfo(np.int64).max)
 
     def visible_mask(self, ts: int) -> np.ndarray:
-        """MVCC visibility at query timestamp ``ts``: rows written at or
-        before ``ts`` and not killed by any tombstone in ``(row_ts, ts]``."""
-        from ..kernels import ops
-
-        cols = self._materialize()
-        mask = cols["ts"] <= ts
-        flat = self._tombstones_flat()
-        if flat is not None:
-            eff = ops.eff_tombstones(flat[0], flat[1], ts)
-            if eff is not None:
-                mask &= ~ops.tombstone_mask(cols["pk"], cols["ts"], eff[0], eff[1])
-        return mask
+        """MVCC visibility at query timestamp ``ts`` (``SegmentView``)."""
+        return self.snapshot().visible_mask(ts)
 
     def min_ts(self) -> int:
         ts = self.timestamps()

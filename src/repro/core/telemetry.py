@@ -32,8 +32,10 @@ Three cooperating primitives, all process-local and allocation-light:
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import math
+import threading
 import time
 from contextlib import nullcontext
 from dataclasses import dataclass, field
@@ -48,6 +50,7 @@ __all__ = [
     "RequestTrace",
     "open_span",
     "UNTRACED",
+    "background_step",
     "Event",
     "EventLog",
 ]
@@ -143,6 +146,8 @@ class MetricsRegistry:
         self._counters: dict[str, float] = {}
         self._gauges: dict[str, float] = {}
         self._histograms: dict[str, Histogram] = {}
+        # Recording threads (pump, background builds, client) share series.
+        self._lock = threading.Lock()
 
     # -- series key ----------------------------------------------------
     @staticmethod
@@ -155,24 +160,24 @@ class MetricsRegistry:
     # -- recording -----------------------------------------------------
     def inc(self, name: str, value: float = 1.0, labels: dict | None = None) -> None:
         key = self._key(name, labels)
-        self._counters[key] = self._counters.get(key, 0.0) + value
+        with self._lock:
+            self._counters[key] = self._counters.get(key, 0.0) + value
 
     def set_gauge(self, name: str, value: float, labels: dict | None = None) -> None:
         self._gauges[self._key(name, labels)] = float(value)
 
     def observe(self, name: str, value: float, labels: dict | None = None) -> None:
-        key = self._key(name, labels)
-        h = self._histograms.get(key)
-        if h is None:
-            h = self._histograms[key] = Histogram(key)
-        h.record(value)
+        h = self.histogram(name, labels)
+        with self._lock:
+            h.record(value)
 
     def histogram(self, name: str, labels: dict | None = None) -> Histogram:
         key = self._key(name, labels)
-        h = self._histograms.get(key)
-        if h is None:
-            h = self._histograms[key] = Histogram(key)
-        return h
+        with self._lock:
+            h = self._histograms.get(key)
+            if h is None:
+                h = self._histograms[key] = Histogram(key)
+            return h
 
     # -- read-out ------------------------------------------------------
     def counter_value(self, name: str, labels: dict | None = None) -> float:
@@ -187,11 +192,13 @@ class MetricsRegistry:
         Histogram rows are tuples ``(name, count, mean, p50, p95, p99)``.
         The typed wrapper lives in ``core.request.MetricsSnapshot``.
         """
-        counters = dict(sorted(self._counters.items()))
-        gauges = dict(sorted(self._gauges.items()))
+        with self._lock:
+            counters = dict(sorted(self._counters.items()))
+            gauges = dict(sorted(self._gauges.items()))
+            histograms = dict(self._histograms)
         hists = []
-        for key in sorted(self._histograms):
-            h = self._histograms[key]
+        for key in sorted(histograms):
+            h = histograms[key]
             hists.append(
                 (
                     key,
@@ -259,6 +266,7 @@ class Span:
     rows_scanned: int = 0
     bytes_h2d: int = 0  # host bytes copied to the device
     bytes_resident: int = 0  # operand bytes read where they lie on the device
+    growing_rows: int = 0  # growing-segment rows a plan covered
     detail: str = ""
     children: list["Span"] = field(default_factory=list)
 
@@ -272,6 +280,7 @@ class Span:
             "rows_scanned": int(self.rows_scanned),
             "bytes_h2d": int(self.bytes_h2d),
             "bytes_resident": int(self.bytes_resident),
+            "growing_rows": int(self.growing_rows),
             "detail": self.detail,
             "children": [c.to_dict() for c in self.children],
         }
@@ -318,6 +327,8 @@ class RequestTrace:
                 bits.append(f"h2d={span.bytes_h2d}B")
             if span.bytes_resident:
                 bits.append(f"resident={span.bytes_resident}B")
+            if span.growing_rows:
+                bits.append(f"growing={span.growing_rows}")
             bits.append(f"{span.duration_us:.0f}us")
             if span.detail:
                 bits.append(span.detail)
@@ -425,6 +436,32 @@ class _SpanTimer:
         self.span.duration_us = (self.ctx.perf_counter() - self.t0) * 1e6
         _OPEN_SPAN.reset(self.token)
         self.annotation.__exit__(*exc)
+
+
+# --------------------------------------------------------------------------
+# background work
+# --------------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def background_step(metrics: MetricsRegistry, step: str):
+    """One step of background work (``seal``, ``index_build``,
+    ``slice_build``, ``handoff``): a profiler annotation ``manu.<step>``
+    around it, and its count and host time in the counters
+    ``background_steps_total`` and ``background_us_total`` (label
+    ``step``), which a reader takes over a window."""
+    from jax.profiler import TraceAnnotation
+
+    labels = {"step": step}
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation("manu." + step):
+            yield
+    finally:
+        metrics.inc("background_steps_total", labels=labels)
+        metrics.inc(
+            "background_us_total", (time.perf_counter() - t0) * 1e6, labels=labels
+        )
 
 
 # --------------------------------------------------------------------------

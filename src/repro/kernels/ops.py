@@ -9,7 +9,10 @@ All wrappers here accept un-padded shapes and handle the 128-alignment the
 kernels require (pad rows, mask padding as invalid, strip outputs).  An input
 is a host array, sent on every call, or, for ``topk_scan``'s base, a
 ``ResidentOperand`` (``resident``): an immutable array that stays on the
-device, already padded, and is sent once for the life of its holder.
+device, already padded, and is sent once for the life of its holder.  A
+host array whose length follows the data (a growing tail's rows and mask,
+a candidate pool's width) is padded on the host before it is sent: an
+eager pad on the device compiles a program for every length it meets.
 
 Under a traced request every kernel call records a ``kernel_<name>`` span
 (``_KernelCall``) below the span open in the caller.
@@ -188,6 +191,23 @@ def _pad_rows(arr: jnp.ndarray, multiple: int, fill=0) -> jnp.ndarray:
     return jnp.pad(arr, widths, constant_values=fill)
 
 
+def _host_padded(x, multiple: int, dtype, fill=0, axis: int = 0):
+    """A host array as ``dtype``, padded with ``fill`` along ``axis`` to a
+    multiple of ``multiple``; a device array or ``ResidentOperand`` as it
+    is (``_pad_rows`` pads a device array after it is put)."""
+    if isinstance(x, (ResidentOperand, jax.Array)):
+        return x
+    x = np.asarray(x, dtype)
+    pad = (-x.shape[axis]) % multiple
+    if pad == 0:
+        return x
+    shape = list(x.shape)
+    shape[axis] += pad
+    out = np.full(shape, fill, dtype)
+    out[(slice(None),) * axis + (slice(0, x.shape[axis]),)] = x
+    return out
+
+
 def _row_tile(n: int) -> int:
     return 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
 
@@ -249,13 +269,17 @@ def topk_scan(
     k_eff = min(k, n)
 
     if use_pallas():
+        tq, tn = _choose_tiles(len(queries), n)
         with _kernel_call("l2_topk") as call:
             with call.h2d():
                 queries = call.put(queries, jnp.float32)
-                base = call.put(base, jnp.float32)
-                v = jnp.ones(n, jnp.int32) if valid is None else call.put(valid)
+                base = call.put(_host_padded(base, tn, np.float32), jnp.float32)
+                v = (
+                    jnp.ones(n, jnp.int32)
+                    if valid is None
+                    else call.put(_host_padded(valid, tn, bool, fill=False))
+                )
             v = v.astype(jnp.int32)
-            tq, tn = _choose_tiles(queries.shape[0], n)
             qp = _pad_rows(queries, tq)
             bp = _pad_rows(base, tn)
             vp = _pad_rows(v, tn, fill=0)
@@ -449,16 +473,16 @@ def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.
         )
 
     if use_pallas() and (p.size == 0 or np.abs(p).max() < 2**31 - 1):
+        # Pools wider than 512 round up to a power of two: a node's pool
+        # grows with every temporary slice index, and each width compiles
+        # the kernel anew.
+        width = 128 if m <= 512 else 1 << (m - 1).bit_length()
         with _kernel_call("merge_topk") as call:
             with call.h2d():
-                sp = call.put(s, jnp.float32)
-                pp = call.put(p.astype(np.int32))
+                sp = call.put(_host_padded(s, width, np.float32, fill, axis=1), jnp.float32)
+                pp = call.put(_host_padded(p, width, np.int32, -1, axis=1), jnp.int32)
             tq = 128 if nq >= 128 else max(8, 1 << (nq - 1).bit_length())
-            pad_m = (-m) % 128
-            if pad_m:
-                sp = jnp.pad(sp, ((0, 0), (0, pad_m)), constant_values=np.float32(fill))
-                pp = jnp.pad(pp, ((0, 0), (0, pad_m)), constant_values=-1)
-            tm = next(t for t in (512, 256, 128) if (m + pad_m) % t == 0)
+            tm = next(t for t in (512, 256, 128) if sp.shape[1] % t == 0)
             sp = _pad_rows(sp, tq)
             pp = _pad_rows(pp, tq, fill=-1)
             k_eff = min(k, m)

@@ -261,15 +261,18 @@ def test_kernel_spans_split_copy_and_result_wait(rng, monkeypatch):
     for span in l2 + merges:
         assert [c.name for c in span.children] == ["h2d", "result_wait"]
         assert sum(c.duration_us for c in span.children) <= span.duration_us
-    # Bytes by hand: f32 queries and segment rows plus the bool mask...
+    # Bytes by hand: f32 queries, and the segment's rows and bool mask
+    # padded on the host to the 512-row tile...
+    tile = 512
     for span in l2:
-        assert span.children[0].bytes_h2d == nq * dim * 4 + seg_rows * dim * 4 + seg_rows
-    # ...and the merges' f32 scores and int32 pks: 2 segments x k on the
-    # node, 1 node x k at the proxy.
-    assert node_merge.children[0].children[0].bytes_h2d == 2 * nq * (2 * k) * 4
-    assert proxy_merge.children[0].children[0].bytes_h2d == 2 * nq * k * 4
+        assert span.children[0].bytes_h2d == nq * dim * 4 + tile * dim * 4 + tile
+    # ...and the merges' f32 scores and int32 pks, padded on the host to 128
+    # columns: 2 segments x k on the node, 1 node x k at the proxy.
+    assert 2 * k <= 128
+    assert node_merge.children[0].children[0].bytes_h2d == 2 * nq * 128 * 4
+    assert proxy_merge.children[0].children[0].bytes_h2d == 2 * nq * 128 * 4
     assert sum(s.bytes_h2d for s in trace.walk()) == \
-        2 * (nq * dim * 4 + seg_rows * dim * 4 + seg_rows) + 2 * nq * 3 * k * 4
+        2 * (nq * dim * 4 + tile * dim * 4 + tile) + 2 * (2 * nq * 128 * 4)
 
 
 def test_untraced_search_records_and_waits_for_nothing(rng, monkeypatch):

@@ -71,9 +71,9 @@ def test_flat_search_reads_its_rows_on_the_device(rng, kernel_path, monkeypatch)
         assert len(l2) == 2
         for span in l2:
             h2d = span.children[0]
-            # Only the f32 queries and the bool mask cross; the rows are read
-            # where they lie.
-            assert h2d.bytes_h2d == NQ * DIM * 4 + seg_rows
+            # Only the f32 queries and the bool mask (padded on the host to
+            # the 512-row tile) cross; the rows are read where they lie.
+            assert h2d.bytes_h2d == NQ * DIM * 4 + 512
             assert h2d.bytes_resident == seg_rows * DIM * 4
     for handle in _node(system).sealed.values():
         assert isinstance(handle.index._operand, ops.ResidentOperand)
