@@ -15,9 +15,9 @@ Three cooperating primitives, all process-local and allocation-light:
     when ``SearchRequest(trace=True)`` — every hot-path call site guards
     with ``if trace is not None`` so the disabled cost is one branch.
     Start times and durations use an injectable ``perf_counter``; tracing
-    carries node ids, segment ids, rows scanned and host-to-device bytes
-    so chaos tests can assert the tree bit-for-bit matches what was
-    executed.  A span opened with ``TraceContext.timed`` is the ambient
+    carries node ids, segment ids, rows scanned, host-to-device bytes and
+    each kernel call's device programs so chaos tests can assert the tree
+    bit-for-bit matches what was executed.  A span opened with ``TraceContext.timed`` is the ambient
     parent (``open_span()``) of the code it wraps, so the kernel layer
     hangs its spans under it without a trace parameter, and it is a
     ``jax.profiler.TraceAnnotation`` named ``manu.<span>`` on the
@@ -266,6 +266,7 @@ class Span:
     rows_scanned: int = 0
     bytes_h2d: int = 0  # host bytes copied to the device
     bytes_resident: int = 0  # operand bytes read where they lie on the device
+    programs: int = 0  # device programs a kernel call dispatched
     growing_rows: int = 0  # growing-segment rows a plan covered
     detail: str = ""
     children: list["Span"] = field(default_factory=list)
@@ -280,6 +281,7 @@ class Span:
             "rows_scanned": int(self.rows_scanned),
             "bytes_h2d": int(self.bytes_h2d),
             "bytes_resident": int(self.bytes_resident),
+            "programs": int(self.programs),
             "growing_rows": int(self.growing_rows),
             "detail": self.detail,
             "children": [c.to_dict() for c in self.children],
@@ -327,6 +329,8 @@ class RequestTrace:
                 bits.append(f"h2d={span.bytes_h2d}B")
             if span.bytes_resident:
                 bits.append(f"resident={span.bytes_resident}B")
+            if span.programs:
+                bits.append(f"programs={span.programs}")
             if span.growing_rows:
                 bits.append(f"growing={span.growing_rows}")
             bits.append(f"{span.duration_us:.0f}us")

@@ -14,8 +14,10 @@ buffer lives in VMEM scratch and is merged once per tile (see
 the scratch accumulates sequentially — the canonical Pallas reduction
 pattern.
 
-Alignment: TQ, TN, D should be multiples of the 128-lane VREG / MXU tile;
-``ops.py`` pads inputs accordingly and strips padding from outputs.
+Alignment: TQ, TN, D should be multiples of the 128-lane VREG / MXU tile.
+The jitted program pads what is short of its tile (the query rows, and
+the base and mask rows unless the caller padded them) and slices the
+outputs back to the query rows, so that a call is one device program.
 """
 
 from __future__ import annotations
@@ -27,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .topk_util import BIG_F32, NEG_I32, merge_topk, tile_base_indices
+from .topk_util import BIG_F32, NEG_I32, merge_topk, pad_rows_to, tile_base_indices
 
 DEFAULT_TQ = 128
 DEFAULT_TN = 512
@@ -91,9 +93,9 @@ def _scan_kernel(
     jax.jit, static_argnames=("k", "metric", "tq", "tn", "interpret")
 )
 def l2_topk_pallas(
-    queries: jnp.ndarray,  # [NQ, D] padded to TQ multiple
-    base: jnp.ndarray,  # [N, D] padded to TN multiple
-    valid: jnp.ndarray,  # [N] int32
+    queries: jnp.ndarray,  # [NQ, D], any NQ
+    base: jnp.ndarray,  # [N, D]
+    valid: jnp.ndarray,  # [N] or [N padded to TN] bool / int32, 1 = live
     k: int,
     metric: str = "l2",
     tq: int = DEFAULT_TQ,
@@ -101,10 +103,17 @@ def l2_topk_pallas(
     *,
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k of each query row over ``base``: ([NQ, K] scores, [NQ, K]
+    int32 row indices).  Rows past ``base``'s and ``valid``'s length up to
+    the tile are dead."""
     nq, d = queries.shape
-    n, _ = base.shape
-    assert nq % tq == 0 and n % tn == 0, (nq, n, tq, tn)
-    n_q_tiles, n_b_tiles = nq // tq, n // tn
+    nq_pad = -(-nq // tq) * tq
+    n = -(-base.shape[0] // tn) * tn
+    assert valid.shape[0] <= n, (valid.shape, n)
+    queries = pad_rows_to(queries, nq_pad)
+    base = pad_rows_to(base, n)
+    valid = pad_rows_to(valid.astype(jnp.int32), n)
+    n_q_tiles, n_b_tiles = nq_pad // tq, n // tn
 
     grid = (n_q_tiles, n_b_tiles)
     kernel = functools.partial(
@@ -123,13 +132,13 @@ def l2_topk_pallas(
             pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
+            jax.ShapeDtypeStruct((nq_pad, k), jnp.float32),
+            jax.ShapeDtypeStruct((nq_pad, k), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((tq, k), jnp.float32),
             pltpu.VMEM((tq, k), jnp.int32),
         ],
         interpret=interpret,
-    )(queries, base, valid[None, :].astype(jnp.int32))
-    return out_v, out_i
+    )(queries, base, valid[None, :])
+    return out_v[:nq], out_i[:nq]

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .topk_util import BIG_F32, NEG_I32
+from .topk_util import BIG_F32, NEG_I32, pad_rows_to
 
 DEFAULT_TQ = 128
 DEFAULT_TM = 512
@@ -101,8 +101,8 @@ def _merge_kernel(
     jax.jit, static_argnames=("k", "metric", "tq", "tm", "interpret")
 )
 def merge_topk_pallas(
-    scores: jnp.ndarray,  # [NQ, M] padded to TQ multiple, M to a TM multiple
-    pks: jnp.ndarray,  # [NQ, M] int32
+    scores: jnp.ndarray,  # [NQ, M], any NQ, M a TM multiple (or below TM)
+    pks: jnp.ndarray,  # [NQ, M] integer pks, -1 = empty slot
     k: int,
     metric: str = "l2",
     tq: int = DEFAULT_TQ,
@@ -110,14 +110,20 @@ def merge_topk_pallas(
     *,
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The merged top-k of each pool row: ([NQ, K] scores, [NQ, K] int32
+    pks).  The rows are padded to a TQ multiple inside the program (empty
+    slots) and the outputs sliced back, so that a call is one program."""
     nq, m = scores.shape
+    nq_pad = -(-nq // tq) * tq
     tm = min(tm, m)
-    assert nq % tq == 0 and m % tm == 0, (nq, m, tq, tm)
+    assert m % tm == 0, (m, tm)
+    scores = pad_rows_to(scores, nq_pad)
+    pks = pad_rows_to(pks.astype(jnp.int32), nq_pad, fill=-1)
     n_m_tiles = m // tm
     kernel = functools.partial(_merge_kernel, k=k, metric=metric, n_m_tiles=n_m_tiles)
     out_v, out_p = pl.pallas_call(
         kernel,
-        grid=(nq // tq, n_m_tiles),
+        grid=(nq_pad // tq, n_m_tiles),
         in_specs=[
             pl.BlockSpec((tq, tm), lambda i, j: (i, j)),
             pl.BlockSpec((tq, tm), lambda i, j: (i, j)),
@@ -127,13 +133,13 @@ def merge_topk_pallas(
             pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
+            jax.ShapeDtypeStruct((nq_pad, k), jnp.float32),
+            jax.ShapeDtypeStruct((nq_pad, k), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((tq, k), jnp.float32),
             pltpu.VMEM((tq, k), jnp.int32),
         ],
         interpret=interpret,
-    )(scores, pks.astype(jnp.int32))
-    return out_v, out_p
+    )(scores, pks)
+    return out_v[:nq], out_p[:nq]

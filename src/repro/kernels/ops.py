@@ -13,9 +13,13 @@ device, already padded, and is sent once for the life of its holder.  A
 host array whose length follows the data (a growing tail's rows and mask,
 a candidate pool's width) is padded on the host before it is sent: an
 eager pad on the device compiles a program for every length it meets.
+``topk_scan`` and ``merge_topk`` dispatch one device program a call: their
+jitted programs pad the query rows and slice the outputs themselves, and
+both outputs come back in one fetch.
 
 Under a traced request every kernel call records a ``kernel_<name>`` span
-(``_KernelCall``) below the span open in the caller.
+(``_KernelCall``) below the span open in the caller, with the number of
+device programs the call dispatched (``programs``).
 """
 
 from __future__ import annotations
@@ -103,7 +107,8 @@ class _KernelCall:
     so that the copy is timed by itself) and counts the bytes sent, and
     apart from them the bytes of the resident operands it read in place;
     ``result_wait`` runs from the kernel's return until its outputs are
-    numpy arrays.  The rest is the eager pads, the slicing and the launch.
+    numpy arrays.  The rest is the launch of the call's device programs,
+    each counted in the span's ``programs`` (``launch``).
     """
 
     __slots__ = ("ctx", "span", "timer", "sent", "resident")
@@ -121,6 +126,12 @@ class _KernelCall:
 
     def __exit__(self, *exc) -> None:
         self.timer.__exit__(*exc)
+
+    def launch(self, program, *args, **kwargs):
+        """``program(*args, **kwargs)``, counted as one device program: a
+        jitted program or an eager op on device arrays."""
+        self.span.programs += 1
+        return program(*args, **kwargs)
 
     def put(self, x, dtype=None):
         """``x`` on the device; a host array counts its bytes as sent
@@ -160,6 +171,10 @@ class _Untraced:
         return None
 
     @staticmethod
+    def launch(program, *args, **kwargs):
+        return program(*args, **kwargs)
+
+    @staticmethod
     def put(x, dtype=None):
         if isinstance(x, ResidentOperand):
             return x.array
@@ -182,19 +197,33 @@ def _kernel_call(name: str):
     return _UNTRACED_CALL if opened is None else _KernelCall(*opened, name)
 
 
-def _pad_rows(arr: jnp.ndarray, multiple: int, fill=0) -> jnp.ndarray:
+def _pad_rows(arr: jnp.ndarray, multiple: int, fill=0, call=_UNTRACED_CALL) -> jnp.ndarray:
+    """A device array padded with ``fill`` to a multiple of ``multiple``
+    rows: an eager op, counted by ``call``, where it pads at all."""
     n = arr.shape[0]
     pad = (-n) % multiple
     if pad == 0:
         return arr
     widths = [(0, pad)] + [(0, 0)] * (arr.ndim - 1)
-    return jnp.pad(arr, widths, constant_values=fill)
+    return call.launch(jnp.pad, arr, widths, constant_values=fill)
+
+
+def _as_int32(arr: jnp.ndarray, call) -> jnp.ndarray:
+    """A device array as int32: an eager op, counted by ``call``, unless
+    it is int32 already."""
+    return arr if arr.dtype == jnp.int32 else call.launch(arr.astype, jnp.int32)
+
+
+def _head(arr: jnp.ndarray, n: int, call) -> jnp.ndarray:
+    """The first ``n`` rows of a device array: an eager op, counted by
+    ``call``, unless that is all of it."""
+    return arr if arr.shape[0] == n else call.launch(arr.__getitem__, slice(0, n))
 
 
 def _host_padded(x, multiple: int, dtype, fill=0, axis: int = 0):
     """A host array as ``dtype``, padded with ``fill`` along ``axis`` to a
     multiple of ``multiple``; a device array or ``ResidentOperand`` as it
-    is (``_pad_rows`` pads a device array after it is put)."""
+    is (padded on the device where it is short of its tile)."""
     if isinstance(x, (ResidentOperand, jax.Array)):
         return x
     x = np.asarray(x, dtype)
@@ -219,17 +248,18 @@ def _choose_tiles(nq: int, n: int) -> tuple[int, int]:
 
 @dataclass(frozen=True, eq=False)
 class ResidentOperand:
-    """A host array held on the device for as long as this object lives:
-    f32, its rows zero-padded to the row tile ``topk_scan`` uses for
-    ``shape[0]`` rows, so that a call neither sends nor pads it."""
+    """A host array held on the device for as long as this object lives,
+    its rows padded to the row tile ``topk_scan`` uses for ``shape[0]``
+    rows, so that a call neither sends nor pads it: a base's f32 rows
+    (``resident``) or an all-live mask (``_all_live``)."""
 
-    array: jax.Array  # [rows padded to _row_tile(rows), ...] float32
-    shape: tuple[int, ...]  # the host array's shape, before padding
+    array: jax.Array  # [rows padded to _row_tile(rows), ...]
+    shape: tuple[int, ...]  # the host array's shape: unpadded rows, a padded mask
 
     @property
     def nbytes(self) -> int:
-        """The bytes a call would otherwise send: f32, unpadded."""
-        return 4 * int(np.prod(self.shape))
+        """The bytes a call would otherwise send."""
+        return self.array.dtype.itemsize * int(np.prod(self.shape))
 
 
 def resident(x):
@@ -238,9 +268,16 @@ def resident(x):
     the host paths read it as before."""
     if not use_pallas() or len(x) == 0:
         return x
-    return ResidentOperand(
-        _pad_rows(jnp.asarray(x, jnp.float32), _row_tile(len(x))), tuple(x.shape)
-    )
+    x = np.asarray(x, np.float32)
+    return ResidentOperand(jnp.asarray(_host_padded(x, _row_tile(len(x)), np.float32)), x.shape)
+
+
+@lru_cache(maxsize=64)
+def _all_live(n: int) -> ResidentOperand:
+    """The mask of ``n`` live rows, dead to their row tile, made on the
+    device once per row count: ``topk_scan``'s mask where it is given none."""
+    mask = _host_padded(np.ones(n, bool), _row_tile(n), bool, fill=False)
+    return ResidentOperand(jnp.asarray(mask), mask.shape)
 
 
 def topk_scan(
@@ -256,7 +293,7 @@ def topk_scan(
     descending similarity for IP.  ``valid`` masks rows (MVCC visibility /
     delete bitmap).  Invalid or out-of-range results carry idx == -1.
     ``base`` is a host array or a ``ResidentOperand``, which the kernel
-    reads where it lies (already padded: ``_pad_rows`` adds nothing).
+    reads where it lies.
     """
     n = base.shape[0]
     if n == 0:
@@ -270,25 +307,21 @@ def topk_scan(
 
     if use_pallas():
         tq, tn = _choose_tiles(len(queries), n)
+        if valid is None:
+            valid = _all_live(n)
         with _kernel_call("l2_topk") as call:
             with call.h2d():
                 queries = call.put(queries, jnp.float32)
                 base = call.put(_host_padded(base, tn, np.float32), jnp.float32)
-                v = (
-                    jnp.ones(n, jnp.int32)
-                    if valid is None
-                    else call.put(_host_padded(valid, tn, bool, fill=False))
-                )
-            v = v.astype(jnp.int32)
-            qp = _pad_rows(queries, tq)
-            bp = _pad_rows(base, tn)
-            vp = _pad_rows(v, tn, fill=0)
-            vals, idx = l2_topk_pallas(
-                qp, bp, vp, k_eff, metric=metric, tq=tq, tn=tn, interpret=_interpret()
+                valid = call.put(_host_padded(valid, tn, bool, fill=False))
+            # Looked up at call time: ``l2_topk_pallas`` is the module's.
+            vals, idx = call.launch(
+                l2_topk_pallas, queries, base, valid, k_eff,
+                metric=metric, tq=tq, tn=tn, interpret=_interpret(),
             )
-            vals, idx = vals[: queries.shape[0]], idx[: queries.shape[0]]
             with call.result_wait():
-                vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
+                vals, idx = jax.device_get((vals, idx))
+                idx = idx.astype(np.int64)
     else:
         qn = np.asarray(queries, np.float32)
         bn = np.asarray(base, np.float32)
@@ -483,16 +516,14 @@ def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.
                 pp = call.put(_host_padded(p, width, np.int32, -1, axis=1), jnp.int32)
             tq = 128 if nq >= 128 else max(8, 1 << (nq - 1).bit_length())
             tm = next(t for t in (512, 256, 128) if sp.shape[1] % t == 0)
-            sp = _pad_rows(sp, tq)
-            pp = _pad_rows(pp, tq, fill=-1)
             k_eff = min(k, m)
-            vals, opk = merge_topk_pallas(
-                sp, pp, k_eff, metric=metric, tq=tq, tm=tm, interpret=_interpret()
+            vals, opk = call.launch(
+                merge_topk_pallas, sp, pp, k_eff,
+                metric=metric, tq=tq, tm=tm, interpret=_interpret(),
             )
-            vals, opk = vals[:nq], opk[:nq]
             with call.result_wait():
-                vals = np.asarray(vals, np.float32)
-                opk = np.asarray(opk, np.int64)
+                vals, opk = jax.device_get((vals, opk))
+                opk = opk.astype(np.int64)
         bad = np.abs(vals) >= 1e38
         vals = np.where(bad, np.float32(fill), vals)
         opk = np.where(bad, -1, opk)
@@ -883,12 +914,18 @@ def pq_adc_topk(luts, codes, k: int, valid=None) -> tuple[np.ndarray, np.ndarray
             with call.h2d():
                 luts = call.put(luts, jnp.float32)
                 codes = call.put(codes, jnp.int32)
-                v = jnp.ones(n, jnp.int32) if valid is None else call.put(valid)
-            v = v.astype(jnp.int32)
+                v = (
+                    call.launch(jnp.ones, n, jnp.int32)
+                    if valid is None
+                    else call.put(valid)
+                )
+            v = _as_int32(v, call)
             tn = 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
-            cp = _pad_rows(codes, tn)
-            vp = _pad_rows(v, tn, fill=0)
-            vals, idx = pq_adc_topk_pallas(luts, cp, vp, k_eff, tn=tn, interpret=_interpret())
+            cp = _pad_rows(codes, tn, call=call)
+            vp = _pad_rows(v, tn, fill=0, call=call)
+            vals, idx = call.launch(
+                pq_adc_topk_pallas, luts, cp, vp, k_eff, tn=tn, interpret=_interpret()
+            )
             with call.result_wait():
                 vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
     else:
@@ -927,8 +964,9 @@ def sq_encode(x, vmin, vmax) -> np.ndarray:
                 lo, hi = call.put(vmin), call.put(vmax)
             n = x.shape[0]
             tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
-            xp = _pad_rows(x, tn)
-            out = sq_encode_pallas(xp, lo, hi, tn=tn, interpret=_interpret())[:n]
+            xp = _pad_rows(x, tn, call=call)
+            out = call.launch(sq_encode_pallas, xp, lo, hi, tn=tn, interpret=_interpret())
+            out = _head(out, n, call)
             with call.result_wait():
                 return np.asarray(out, np.uint8)
     xn = np.asarray(x, np.float32)
@@ -946,8 +984,9 @@ def sq_decode(codes, vmin, vmax) -> np.ndarray:
                 lo, hi = call.put(vmin), call.put(vmax)
             n = codes.shape[0]
             tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
-            cp = _pad_rows(codes.astype(jnp.int32), tn)
-            out = sq_decode_pallas(cp, lo, hi, tn=tn, interpret=_interpret())[:n]
+            cp = _pad_rows(_as_int32(codes, call), tn, call=call)
+            out = call.launch(sq_decode_pallas, cp, lo, hi, tn=tn, interpret=_interpret())
+            out = _head(out, n, call)
             with call.result_wait():
                 return np.asarray(out)
     vmin = np.asarray(vmin, np.float32)
@@ -971,17 +1010,21 @@ def sq_topk_scan(
                 queries = call.put(queries, jnp.float32)
                 codes = call.put(codes)
                 lo, hi = call.put(vmin), call.put(vmax)
-                v = jnp.ones(n, jnp.int32) if valid is None else call.put(valid)
-            v = v.astype(jnp.int32)
+                v = (
+                    call.launch(jnp.ones, n, jnp.int32)
+                    if valid is None
+                    else call.put(valid)
+                )
+            v = _as_int32(v, call)
             tq, tn = _choose_tiles(nq, n)
-            qp = _pad_rows(queries, tq)
-            cp = _pad_rows(codes.astype(jnp.int32), tn)
-            vp = _pad_rows(v, tn, fill=0)
-            vals, idx = sq_l2_topk_pallas(
-                qp, cp, lo, hi, vp, k_eff,
+            qp = _pad_rows(queries, tq, call=call)
+            cp = _pad_rows(_as_int32(codes, call), tn, call=call)
+            vp = _pad_rows(v, tn, fill=0, call=call)
+            vals, idx = call.launch(
+                sq_l2_topk_pallas, qp, cp, lo, hi, vp, k_eff,
                 metric=metric, tq=tq, tn=tn, interpret=_interpret(),
             )
-            vals, idx = vals[:nq], idx[:nq]
+            vals, idx = _head(vals, nq, call), _head(idx, nq, call)
             with call.result_wait():
                 vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
     else:
@@ -1184,13 +1227,14 @@ def kmeans_assign(x, centroids) -> tuple[np.ndarray, np.ndarray]:
                 c = call.put(centroids, jnp.float32)
             tn = 512 if n >= 512 else max(128, 1 << (max(n, 2) - 1).bit_length())
             tc = 512 if ncent >= 512 else max(128, 1 << (max(ncent, 2) - 1).bit_length())
-            xp = _pad_rows(x, tn)
+            xp = _pad_rows(x, tn, call=call)
             # pad centroids with far-away sentinels so they never win
             pad = (-ncent) % tc
             if pad:
-                c = jnp.concatenate([c, jnp.full((pad, c.shape[1]), 1e18, jnp.float32)])
-            a, d = kmeans_assign_pallas(xp, c, tn=tn, tc=tc, interpret=_interpret())
-            a, d = a[:n], d[:n]
+                far = call.launch(jnp.full, (pad, c.shape[1]), 1e18, jnp.float32)
+                c = call.launch(jnp.concatenate, [c, far])
+            a, d = call.launch(kmeans_assign_pallas, xp, c, tn=tn, tc=tc, interpret=_interpret())
+            a, d = _head(a, n, call), _head(d, n, call)
             with call.result_wait():
                 return np.asarray(a, np.int64), np.asarray(d)
     xn = np.asarray(x, np.float32)

@@ -6,6 +6,8 @@ using only reductions, broadcasted iota, and one-hot arithmetic — all of
 which lower cleanly to the VPU.  ``select_topk_small`` extracts the K
 smallest entries of a [TQ, M] candidate tile; ``merge_topk`` folds a new
 candidate tile into the running per-query buffer kept in VMEM scratch.
+``pad_rows_to`` pads a jitted program's operand to its tile before the
+``pallas_call``, so that the pad runs inside that program.
 """
 
 from __future__ import annotations
@@ -70,3 +72,11 @@ def tile_base_indices(tile_rows: int, tile_idx: jnp.ndarray, tq: int) -> jnp.nda
     """Global base-row indices for the current [TQ, TN] tile."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (tq, tile_rows), 1)
     return iota + (tile_idx * tile_rows).astype(jnp.int32)
+
+
+def pad_rows_to(x: jnp.ndarray, rows: int, fill=0) -> jnp.ndarray:
+    """``x`` with its leading axis padded with ``fill`` to ``rows``."""
+    pad = rows - x.shape[0]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1), constant_values=fill)

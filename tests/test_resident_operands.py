@@ -96,7 +96,8 @@ def test_ivf_probe_reads_its_centroids_on_the_device(rng, kernel_path, monkeypat
     for span in probes:
         h2d = span.children[0]
         assert h2d.bytes_h2d == NQ * DIM * 4  # the queries; no mask
-        assert h2d.bytes_resident == nlist * DIM * 4
+        # The centroids, and their all-live mask padded to the 128-row tile.
+        assert h2d.bytes_resident == nlist * DIM * 4 + 128
     for handle in _node(system).sealed.values():
         assert isinstance(handle.index._centroid_operand, ops.ResidentOperand)
         assert handle.index._centroid_operand.shape == (nlist, DIM)

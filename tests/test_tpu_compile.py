@@ -50,14 +50,17 @@ def one_chip():
             compilation_cache.reset_cache()
 
 
-def _l2(d, k, tq=128, metric="l2"):
+def _l2(d, k, tq=128, metric="l2", nq=None, mask=I32):
     fn = functools.partial(l2_topk_pallas, k=k, metric=metric, tq=tq, tn=512)
-    return fn, [((tq, d), F32), ((N, d), F32), ((N,), I32)]
+    return fn, [((nq or tq, d), F32), ((N, d), F32), ((N,), mask)]
 
 
 CASES = {
     **{f"l2_topk-d{d}-k{k}": _l2(d, k) for d in (128, 768, 1536) for k in (10, 100)},
     "l2_topk-ip-tq8": _l2(128, 10, tq=8, metric="ip"),
+    # an online search's call: one query row and a bool mask, padded and
+    # cast inside the program
+    "l2_topk-nq1-bool": _l2(128, 10, tq=8, nq=1, mask=jnp.bool_),
     "sq_l2_topk-d128-k10": (
         functools.partial(sq_l2_topk_pallas, k=10, tq=128, tn=512),
         [((128, 128), F32), ((N, 128), I32), ((128,), F32), ((128,), F32),
@@ -67,6 +70,10 @@ CASES = {
     "merge_topk-m384-k10": (
         functools.partial(merge_topk_pallas, k=10, tq=128, tm=128),
         [((128, 384), F32), ((128, 384), I32)],
+    ),
+    "merge_topk-nq1-m128-k10": (
+        functools.partial(merge_topk_pallas, k=10, tq=8, tm=128),
+        [((1, 128), F32), ((1, 128), I32)],
     ),
     # a query node's pool over 4 IVF segments x nprobe=32 x k=100
     "merge_topk-m12800-k100": (
