@@ -39,31 +39,6 @@ def merge_rows(rng) -> list[tuple[str, float, str]]:
     ]
 
 
-def fused_scan_rows(rng) -> list[tuple[str, float, str]]:
-    """topk_scan_segmented vs one topk_scan dispatch per segment: the
-    batched-scan stage over many small segments."""
-    nq, n_seg, rows, dim, k = (8, 4, 256, 32, 10) if SMOKE else (64, 64, 256, 128, 50)
-    q = rng.standard_normal((nq, dim)).astype(np.float32)
-    bases = [rng.standard_normal((rows, dim)).astype(np.float32) for _ in range(n_seg)]
-    valids = [rng.random(rows) < 0.9 for _ in range(n_seg)]
-
-    def per_segment():
-        for b, v in zip(bases, valids):
-            ops.topk_scan(q, b, k, metric="l2", valid=v)
-
-    t_seg = timeit_us(per_segment, best_of=5)
-    t_fused = timeit_us(
-        lambda: ops.topk_scan_segmented(q, bases, k, metric="l2", valids=valids),
-        best_of=5,
-    )
-    speedup = t_seg / max(t_fused, 1e-9)
-    return [
-        ("kern-scan-per-segment", t_seg, f"nq={nq},segs={n_seg}x{rows}x{dim},k={k}"),
-        ("kern-scan-fused", t_fused,
-         f"nq={nq},segs={n_seg}x{rows}x{dim},k={k};speedup={speedup:.1f}x"),
-    ]
-
-
 def delta_mask_rows(rng) -> list[tuple[str, float, str]]:
     """Per-request doomed-pk materialization + sorted probe vs the old
     per-segment np.array rebuild + np.isin (QueryNode delta-delete masks)."""
@@ -545,7 +520,6 @@ def main() -> list[tuple[str, float, str]]:
     rows.append(("kern-kmeans_assign-host",
                  timeit_us(lambda: ops.kmeans_assign(x, cents)), f"{n}rows-256cents"))
     rows += merge_rows(rng)
-    rows += fused_scan_rows(rng)
     rows += delta_mask_rows(rng)
     rows += hybrid_fuse_rows(rng)
     rows += filtered_range_rows(rng)

@@ -157,8 +157,10 @@ class SealedHandle:
 class ScanUnit:
     """One plannable piece of search work: rows, masks, and how to run them.
 
-    ``index`` set -> the unit executes through that index; otherwise
-    ``vectors`` holds the rows for a brute-force scan.  ``pks`` maps the
+    ``index`` set -> the unit executes through that index (the classes
+    ``indexed``, ``growing_slice``, ``post_indexed``); otherwise
+    ``vectors`` holds the rows for a brute-force scan (``brute_sealed``,
+    ``brute_tail``, ``post_brute``, ``brute_filtered``).  ``pks`` maps the
     unit's local row indices back to primary keys.
     """
 
@@ -176,18 +178,27 @@ class ScanUnit:
     k_extra: int = 0
 
 
+# The execution classes in execution order.  The order is that of the
+# node's candidate-pool columns, where ``merge_topk`` breaks score ties.
+EXECUTION_ORDER = (
+    "indexed", "growing_slice", "brute_sealed", "brute_tail",
+    "post_indexed", "post_brute", "brute_filtered",
+)
+
+
 @dataclass
 class SearchPlan:
     """Planner output: candidate units grouped by execution class.
 
-    The two brute classes run as ONE fused scan each
-    (``ops.topk_scan_segmented``); index-backed classes dispatch per
-    unit since every index owns its own structure.
+    ``classes`` gives the order the executor runs them in: the
+    index-backed classes once per index spec (``batch_spec``, one
+    ``search_batched`` call each), a brute class as one
+    ``ops.topk_scan_segmented`` call.
     """
 
     indexed: list[ScanUnit] = field(default_factory=list)  # sealed, index loaded
-    brute_sealed: list[ScanUnit] = field(default_factory=list)  # sealed, no index
     growing_slice: list[ScanUnit] = field(default_factory=list)  # temp slice index
+    brute_sealed: list[ScanUnit] = field(default_factory=list)  # sealed, no index
     brute_tail: list[ScanUnit] = field(default_factory=list)  # growing tail rows
     # Filtered-search strategy classes (empty without a filter):
     # post-filter units scan with visibility-only masks at an inflated k
@@ -202,11 +213,22 @@ class SearchPlan:
     growing_rows: int = 0  # rows of the growing segments planned over
 
     def units(self) -> "list[ScanUnit]":
-        return (
-            self.indexed + self.brute_sealed + self.growing_slice
-            + self.brute_tail + self.post_indexed + self.post_brute
-            + self.brute_filtered
-        )
+        return [u for _cls, units in self.classes() for u in units]
+
+    def classes(self):
+        """``(class name, units)`` of every non-empty class, in
+        ``EXECUTION_ORDER``; an index-backed class once per index spec,
+        in first-seen order."""
+        for cls in EXECUTION_ORDER:
+            units = getattr(self, cls)
+            if units and units[0].index is not None:
+                groups: dict = {}
+                for unit in units:
+                    groups.setdefault(unit.index.batch_spec(), []).append(unit)
+                for group in groups.values():
+                    yield cls, group
+            elif units:
+                yield cls, units
 
 
 def _map_pks(idx: np.ndarray, pks: np.ndarray) -> np.ndarray:
@@ -1020,153 +1042,72 @@ class QueryNode:
         metric: Metric,
         trace: tuple | None = None,
     ) -> tuple["list[np.ndarray]", "list[np.ndarray]"]:
-        """Run a plan's units and return per-unit top-k candidate pools.
+        """Run a plan's classes (``SearchPlan.classes``) and return per-unit
+        top-k candidate pools, in the classes' order.
 
         ``trace`` is the optional ``(TraceContext, parent Span)`` pair: one
-        child span per execution-class dispatch, carrying the segment ids
-        and live-row count it actually scanned.
+        ``scan_<class>`` span per class run, carrying the segment ids and
+        live-row count it actually scanned.
         """
         import time as _t
 
         from ..kernels import ops
 
         metric_str = "l2" if metric is Metric.L2 else "ip"
-        pool_s: list[np.ndarray] = []
-        pool_p: list[np.ndarray] = []
-
-        def open_class(cls: str, units):
-            """The class's ``scan_<cls>`` span timer, open while the class
-            runs so that kernel spans nest under it (a no-op untraced)."""
-            if trace is None:
-                return UNTRACED
-            ctx, parent = trace
-            return ctx.timed(ctx.span(
-                f"scan_{cls}", parent=parent, node_id=self.node_id,
-                segment_ids=sorted({u.segment_id for u in units}),
-            ))
-
-        def record_class(cls: str, units, t0: float, span) -> None:
-            elapsed_us = (_t.perf_counter() - t0) * 1e6
-            rows = int(sum(int(u.mask.sum()) for u in units))
-            self.metrics.observe(
-                "query_node_scan_us", elapsed_us, labels={"class": cls}
-            )
-            self.metrics.inc(
-                "query_node_rows_scanned_total", rows, labels={"class": cls}
-            )
-            if span is not None:
-                span.rows_scanned = rows
-
-        # Index-backed units group by spec: all co-located segments sharing
-        # an index configuration execute as ONE batched candidate-pool
-        # dispatch (IVF runs its vectorized probe-gather-scan across the
-        # group; other kinds fall back to per-index search inside).
-        indexed_ids = {id(u) for u in plan.indexed}
-        index_groups: dict = {}
-        for unit in plan.indexed + plan.growing_slice:
-            index_groups.setdefault(unit.index.batch_spec(), []).append(unit)
-        for units in index_groups.values():
-            cls = "indexed" if id(units[0]) in indexed_ids else "growing_slice"
-            t0 = _t.perf_counter()
-            with open_class(cls, units) as span:
-                s, i, splits = type(units[0].index).search_batched(
-                    [u.index for u in units],
-                    queries,
-                    k,
-                    valids=[u.mask for u in units],
-                )
-                for j, unit in enumerate(units):
-                    blk = slice(splits[j], splits[j + 1])
-                    pool_s.append(s[:, blk])
-                    pool_p.append(_map_pks(i[:, blk], unit.pks))
-            record_class(cls, units, t0, span)
-        # Brute classes run as one fused scan per class: a single shared
-        # distance contraction, per-segment top-k extracted from it.
-        # Cosine scans normalize both sides: the planner handed us the
-        # segments' cached unit columns, only the queries normalize here
+        # Cosine scans normalize both sides: the planner handed brute units
+        # the segments' cached unit columns, only the queries normalize here
         # (indexes normalize at build and take raw queries).
         q_brute = normalize_if_cosine(metric, np.asarray(queries, np.float32))
-        for cls, units in (
-            ("brute_sealed", plan.brute_sealed),
-            ("brute_tail", plan.brute_tail),
-        ):
-            if not units:
-                continue
+        pool_s: list[np.ndarray] = []
+        pool_p: list[np.ndarray] = []
+        for cls, units in plan.classes():
+            # A post-filter unit scans with a visibility-only mask at the
+            # class width k + max(k_extra): its k_extra is its worst-case
+            # count of visible rows failing the filter, so the widened top
+            # provably holds the filtered top-k once the cut below drops
+            # them (to (fill, -1); merge_topk ignores those).
+            width = k + max(u.k_extra for u in units)
             t0 = _t.perf_counter()
-            with open_class(cls, units) as span:
-                s, i = ops.topk_scan_segmented(
-                    q_brute,
-                    [u.vectors for u in units],
-                    k,
-                    metric=metric_str,
-                    valids=[u.mask for u in units],
-                )
-                for j, unit in enumerate(units):
-                    blk = slice(j * k, (j + 1) * k)
-                    pool_s.append(s[:, blk])
-                    pool_p.append(_map_pks(i[:, blk], unit.pks))
-            record_class(cls, units, t0, span)
-        # Post-filter classes scan with VISIBILITY-only valids at the
-        # inflated class width k' = k + max(k_extra): each unit's k_extra is
-        # its worst-case interloper count (visible rows failing the filter),
-        # so the widened top-k' provably contains the filtered top-k. The
-        # cut zeroes interlopers to (fill, -1); merge_topk drops them.
-        if plan.post_indexed:
-            post_groups: dict = {}
-            for unit in plan.post_indexed:
-                post_groups.setdefault(unit.index.batch_spec(), []).append(unit)
-            for units in post_groups.values():
-                t0 = _t.perf_counter()
-                with open_class("post_indexed", units) as span:
-                    k_class = k + max(u.k_extra for u in units)
+            with self._timed(
+                trace, f"scan_{cls}", segment_ids=sorted({u.segment_id for u in units})
+            ) as span:
+                if units[0].index is not None:
                     s, i, splits = type(units[0].index).search_batched(
-                        [u.index for u in units],
-                        queries,
-                        k_class,
+                        [u.index for u in units], queries, width,
                         valids=[u.mask for u in units],
                     )
-                    for j, unit in enumerate(units):
-                        blk = slice(splits[j], splits[j + 1])
-                        cs, ci = ops.post_filter_cut(
-                            s[:, blk], i[:, blk], unit.post_mask, metric=metric_str
-                        )
-                        pool_s.append(cs)
-                        pool_p.append(_map_pks(ci, unit.pks))
-                record_class("post_indexed", units, t0, span)
-        if plan.post_brute:
-            units = plan.post_brute
-            t0 = _t.perf_counter()
-            with open_class("post_brute", units) as span:
-                k_class = k + max(u.k_extra for u in units)
-                s, i = ops.topk_scan_segmented(
-                    q_brute,
-                    [u.vectors for u in units],
-                    k_class,
-                    metric=metric_str,
-                    valids=[u.mask for u in units],
-                )
-                for j, unit in enumerate(units):
-                    blk = slice(j * k_class, (j + 1) * k_class)
-                    cs, ci = ops.post_filter_cut(
-                        s[:, blk], i[:, blk], unit.post_mask, metric=metric_str
+                else:
+                    # a brute_filtered unit holds only its surviving rows
+                    masks = None if cls == "brute_filtered" else [u.mask for u in units]
+                    s, i = ops.topk_scan_segmented(
+                        q_brute, [u.vectors for u in units], width,
+                        metric=metric_str, valids=masks,
                     )
+                    splits = range(0, (len(units) + 1) * width, width)
+                for j, unit in enumerate(units):
+                    blk = slice(splits[j], splits[j + 1])
+                    cs, ci = s[:, blk], i[:, blk]
+                    if unit.post_mask is not None:
+                        cs, ci = ops.post_filter_cut(cs, ci, unit.post_mask, metric=metric_str)
                     pool_s.append(cs)
                     pool_p.append(_map_pks(ci, unit.pks))
-            record_class("post_brute", units, t0, span)
-        # Brute-filtered units already gathered their surviving rows: each
-        # scans its own tiny vector block unfused (the gathers are ragged,
-        # so a shared contraction buys nothing at these sizes).
-        if plan.brute_filtered:
-            t0 = _t.perf_counter()
-            with open_class("brute_filtered", plan.brute_filtered) as span:
-                for unit in plan.brute_filtered:
-                    s, i = ops.topk_scan(
-                        q_brute, unit.vectors, k, metric=metric_str
-                    )
-                    pool_s.append(s)
-                    pool_p.append(_map_pks(i, unit.pks))
-            record_class("brute_filtered", plan.brute_filtered, t0, span)
+            rows = int(sum(int(u.mask.sum()) for u in units))
+            self.metrics.observe(
+                "query_node_scan_us", (_t.perf_counter() - t0) * 1e6, labels={"class": cls}
+            )
+            self.metrics.inc("query_node_rows_scanned_total", rows, labels={"class": cls})
+            if span is not None:
+                span.rows_scanned = rows
         return pool_s, pool_p
+
+    def _timed(self, trace: tuple | None, name: str, **attrs):
+        """The timer of a ``name`` span of this node under the request's
+        ``(TraceContext, parent Span)``, which ``with`` gives as the span;
+        the shared no-op ``UNTRACED`` (giving None) when not traced."""
+        if trace is None:
+            return UNTRACED
+        ctx, parent = trace
+        return ctx.timed(ctx.span(name, parent=parent, node_id=self.node_id, **attrs))
 
     def search_request(
         self, request: NodeSearchRequest
@@ -1226,30 +1167,24 @@ class QueryNode:
         trace = request.trace  # (TraceContext, parent Span) | None
         results: list[tuple[np.ndarray, np.ndarray]] = []
         for a in request.anns:
-            queries = a.queries
-            nq = len(queries)
-            if trace is not None:
-                ctx, parent = trace
-                pspan = ctx.span(
-                    "plan_search", parent=parent, node_id=self.node_id,
-                    detail=f"column={a.field}",
+            with self._timed(trace, "plan_search", detail=f"column={a.field}") as pspan:
+                plan = self.plan_search(
+                    request.collection, ts, request.filter_masks,
+                    column=a.field, metric=metric, doomed=doomed,
+                    partitions=request.partitions,
+                    segments=request.segments,
+                    shards=shards,
+                    filter=request.filter,
+                    filter_strategy=request.filter_strategy,
+                    k=request.k,
                 )
-                with ctx.timed(pspan):
-                    plan = self.plan_search(
-                        request.collection, ts, request.filter_masks,
-                        column=a.field, metric=metric, doomed=doomed,
-                        partitions=request.partitions,
-                        segments=request.segments,
-                        shards=shards,
-                        filter=request.filter,
-                        filter_strategy=request.filter_strategy,
-                        k=request.k,
-                    )
+            if pspan is not None:
                 pspan.segment_ids = tuple(
                     sorted({u.segment_id for u in plan.units()})
                 )
                 pspan.growing_rows = plan.growing_rows
                 if request.filter is not None and plan.filter_info:
+                    ctx, parent = trace
                     fspan = ctx.span(
                         "filter_plan", parent=parent, node_id=self.node_id,
                         detail=",".join(
@@ -1261,38 +1196,17 @@ class QueryNode:
                     fspan.segment_ids = tuple(
                         fi["segment_id"] for fi in plan.filter_info
                     )
-            else:
-                plan = self.plan_search(
-                    request.collection, ts, request.filter_masks,
-                    column=a.field, metric=metric, doomed=doomed,
-                    partitions=request.partitions, segments=request.segments,
-                    shards=shards,
-                    filter=request.filter,
-                    filter_strategy=request.filter_strategy,
-                    k=request.k,
-                )
             pool_s, pool_p = self._execute_plan(
-                plan, queries, request.k, metric, trace=trace
+                plan, a.queries, request.k, metric, trace=trace
             )
             if not pool_s:
+                nq = len(a.queries)
                 out = (
                     np.full((nq, request.k), fill, np.float32),
                     np.full((nq, request.k), -1, np.int64),
                 )
             else:
-                if trace is not None:
-                    ctx, parent = trace
-                    mspan = ctx.span(
-                        "node_merge_topk", parent=parent, node_id=self.node_id
-                    )
-                    with ctx.timed(mspan):
-                        out = ops.merge_topk(
-                            np.concatenate(pool_s, axis=1),
-                            np.concatenate(pool_p, axis=1),
-                            request.k,
-                            metric=metric_str,
-                        )
-                else:
+                with self._timed(trace, "node_merge_topk"):
                     out = ops.merge_topk(
                         np.concatenate(pool_s, axis=1),
                         np.concatenate(pool_p, axis=1),

@@ -20,16 +20,14 @@ Python loops):
 
 ``search_batched`` extends the same pipeline across co-located segments
 sharing an index spec: one dispatch returns every unit's candidate pool so
-the query node merges exactly once.  Set ``REPRO_IVF_REFERENCE=1`` to run
-the scalar per-list reference path instead (the equivalence oracle).
+the query node merges exactly once.  ``_search_reference`` keeps the scalar
+per-list loop as the oracle the tests compare the pipeline against.
 
 IVF-PQ encodes residuals (x - centroid) which materially improves recall at
 the same code budget.
 """
 
 from __future__ import annotations
-
-import os
 
 import numpy as np
 
@@ -38,11 +36,6 @@ from ..kernels import ops
 from .base import VectorIndex, normalize_if_cosine, scan_metric, worst_score
 from .kmeans import kmeans
 from .pq import adc_tables, pq_encode, train_pq_codebooks
-
-
-def use_reference() -> bool:
-    """True when the scalar per-list oracle path is forced via env."""
-    return os.environ.get("REPRO_IVF_REFERENCE") == "1"
 
 
 class IVFBase(VectorIndex):
@@ -135,8 +128,6 @@ class IVFBase(VectorIndex):
         return pool_s, ids
 
     def search(self, queries, k, valid=None):
-        if use_reference():
-            return self._search_reference(queries, k, valid)
         q = normalize_if_cosine(self.metric, np.asarray(queries, np.float32))
         valid_perm = None if valid is None else np.asarray(valid)[self.row_ids]
         pool_s, ids = self._pool_candidates(q, k, valid_perm)
@@ -147,7 +138,7 @@ class IVFBase(VectorIndex):
         """All co-located IVF units of one spec in one dispatch: shared
         query prep, per-unit probe + bucketed gather-scan, raw candidate
         pools returned unreduced (the caller merges once)."""
-        if use_reference() or not indexes:
+        if not indexes:
             return super().search_batched(indexes, queries, k, valids)
         if valids is None:
             valids = [None] * len(indexes)
@@ -172,7 +163,7 @@ class IVFBase(VectorIndex):
 
     def _search_reference(self, queries, k, valid=None):
         """The pre-vectorization per-list loop, kept as the equivalence
-        oracle for the batched pipeline (``REPRO_IVF_REFERENCE=1``)."""
+        oracle for the batched pipeline; the tests call it directly."""
         q = normalize_if_cosine(self.metric, np.asarray(queries, np.float32))
         nq = len(q)
         probes = self._probe_lists(q, self._effective_nprobe())  # [nq, nprobe]

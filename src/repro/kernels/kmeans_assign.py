@@ -17,10 +17,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .topk_util import BIG_F32
+from .topk_util import BIG_F32, pad_rows_to
 
 DEFAULT_TN = 512
 DEFAULT_TC = 512
+FAR = 1e18  # a padding centroid's coordinates: it never wins a row
 
 
 def _assign_kernel(
@@ -66,16 +67,22 @@ def _assign_kernel(
 
 @functools.partial(jax.jit, static_argnames=("tn", "tc", "interpret"))
 def kmeans_assign_pallas(
-    x: jnp.ndarray,  # [N, D] padded to TN
-    centroids: jnp.ndarray,  # [C, D] padded to TC (pad rows = +inf-ish far away)
+    x: jnp.ndarray,  # [N, D], any N
+    centroids: jnp.ndarray,  # [C, D], any C
     tn: int = DEFAULT_TN,
     tc: int = DEFAULT_TC,
     *,
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    n, d = x.shape
-    c, _ = centroids.shape
-    assert n % tn == 0 and c % tc == 0
+    """Nearest centroid of each row of ``x``: ([N] int32 centroid, [N] f32
+    squared distance).  The rows are padded to a TN multiple and the
+    centroids to a TC multiple with far rows inside the program, and the
+    outputs sliced back to N, so that a call is one program."""
+    rows, d = x.shape
+    n = -(-rows // tn) * tn
+    c = -(-centroids.shape[0] // tc) * tc
+    x = pad_rows_to(x.astype(jnp.float32), n)
+    centroids = pad_rows_to(centroids.astype(jnp.float32), c, fill=FAR)
     kernel = functools.partial(_assign_kernel, n_c_tiles=c // tc, tc=tc)
     out_a, out_d = pl.pallas_call(
         kernel,
@@ -97,5 +104,5 @@ def kmeans_assign_pallas(
             pltpu.VMEM((tn, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(x.astype(jnp.float32), centroids.astype(jnp.float32))
-    return out_a[:, 0], out_d[:, 0]
+    )(x, centroids)
+    return out_a[:rows, 0], out_d[:rows, 0]

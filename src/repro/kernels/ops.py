@@ -5,17 +5,23 @@ other backend the vectorized numpy host paths below run, and the Pallas
 bodies are validated against the ``ref.py`` oracles in interpret mode by
 the test suite.  The platform is the only selector.
 
-All wrappers here accept un-padded shapes and handle the 128-alignment the
-kernels require (pad rows, mask padding as invalid, strip outputs).  An input
-is a host array, sent on every call, or, for ``topk_scan``'s base, a
-``ResidentOperand`` (``resident``): an immutable array that stays on the
-device, already padded, and is sent once for the life of its holder.  A
-host array whose length follows the data (a growing tail's rows and mask,
-a candidate pool's width) is padded on the host before it is sent: an
-eager pad on the device compiles a program for every length it meets.
-``topk_scan`` and ``merge_topk`` dispatch one device program a call: their
-jitted programs pad the query rows and slice the outputs themselves, and
-both outputs come back in one fetch.
+All wrappers here accept un-padded shapes, and every kernel call follows
+one convention: one device program, one fetch.
+
+- An input is a host array, sent on every call, or, for ``topk_scan``'s
+  base, a ``ResidentOperand`` (``resident``): an immutable array that
+  stays on the device, already padded, and is sent once for the life of
+  its holder.
+- A host array whose length follows the data (rows, codes, a mask, a
+  candidate pool's width) is padded on the host to its tile
+  (``_host_padded``, ``_row_tile``) before it is sent: an eager pad on the
+  device compiles a program for every length it meets.  A call given no
+  mask reads the all-live mask held on the device (``_all_live``).
+- The jitted program does the rest: it pads the query rows (and the
+  centroids, with far rows), casts a bool mask and integer codes to
+  int32, and slices its outputs to the rows it was given.
+- One ``jax.device_get`` fetches every output, and ``_topk_answer`` turns
+  a top-k into the answer the host paths give.
 
 Under a traced request every kernel call records a ``kernel_<name>`` span
 (``_KernelCall``) below the span open in the caller, with the number of
@@ -128,8 +134,8 @@ class _KernelCall:
         self.timer.__exit__(*exc)
 
     def launch(self, program, *args, **kwargs):
-        """``program(*args, **kwargs)``, counted as one device program: a
-        jitted program or an eager op on device arrays."""
+        """``program(*args, **kwargs)``, a jitted program, counted as one
+        device program."""
         self.span.programs += 1
         return program(*args, **kwargs)
 
@@ -197,29 +203,6 @@ def _kernel_call(name: str):
     return _UNTRACED_CALL if opened is None else _KernelCall(*opened, name)
 
 
-def _pad_rows(arr: jnp.ndarray, multiple: int, fill=0, call=_UNTRACED_CALL) -> jnp.ndarray:
-    """A device array padded with ``fill`` to a multiple of ``multiple``
-    rows: an eager op, counted by ``call``, where it pads at all."""
-    n = arr.shape[0]
-    pad = (-n) % multiple
-    if pad == 0:
-        return arr
-    widths = [(0, pad)] + [(0, 0)] * (arr.ndim - 1)
-    return call.launch(jnp.pad, arr, widths, constant_values=fill)
-
-
-def _as_int32(arr: jnp.ndarray, call) -> jnp.ndarray:
-    """A device array as int32: an eager op, counted by ``call``, unless
-    it is int32 already."""
-    return arr if arr.dtype == jnp.int32 else call.launch(arr.astype, jnp.int32)
-
-
-def _head(arr: jnp.ndarray, n: int, call) -> jnp.ndarray:
-    """The first ``n`` rows of a device array: an eager op, counted by
-    ``call``, unless that is all of it."""
-    return arr if arr.shape[0] == n else call.launch(arr.__getitem__, slice(0, n))
-
-
 def _host_padded(x, multiple: int, dtype, fill=0, axis: int = 0):
     """A host array as ``dtype``, padded with ``fill`` along ``axis`` to a
     multiple of ``multiple``; a device array or ``ResidentOperand`` as it
@@ -238,6 +221,7 @@ def _host_padded(x, multiple: int, dtype, fill=0, axis: int = 0):
 
 
 def _row_tile(n: int) -> int:
+    """The row tile of a kernel's base, codes or rows for ``n`` rows."""
     return 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
 
 
@@ -249,8 +233,8 @@ def _choose_tiles(nq: int, n: int) -> tuple[int, int]:
 @dataclass(frozen=True, eq=False)
 class ResidentOperand:
     """A host array held on the device for as long as this object lives,
-    its rows padded to the row tile ``topk_scan`` uses for ``shape[0]``
-    rows, so that a call neither sends nor pads it: a base's f32 rows
+    its rows padded to the row tile (``_row_tile``) of ``shape[0]`` rows,
+    so that a call neither sends nor pads it: a base's f32 rows
     (``resident``) or an all-live mask (``_all_live``)."""
 
     array: jax.Array  # [rows padded to _row_tile(rows), ...]
@@ -275,9 +259,23 @@ def resident(x):
 @lru_cache(maxsize=64)
 def _all_live(n: int) -> ResidentOperand:
     """The mask of ``n`` live rows, dead to their row tile, made on the
-    device once per row count: ``topk_scan``'s mask where it is given none."""
+    device once per row count: a kernel call's mask where it is given none."""
     mask = _host_padded(np.ones(n, bool), _row_tile(n), bool, fill=False)
     return ResidentOperand(jnp.asarray(mask), mask.shape)
+
+
+def _topk_answer(vals, idx, k: int, fill) -> tuple[np.ndarray, np.ndarray]:
+    """A top-k as the entry points return it: f32 scores and int64 rows
+    (or pks), a dead slot (``|score| >= 1e38``: the kernels' sentinel, the
+    host paths' infinities) at -1, and the columns past ``k_eff`` filled
+    with ``(fill, -1)`` out to ``k``.  Zero columns answer an empty input."""
+    vals = np.asarray(vals, np.float32)
+    idx = np.where(np.abs(vals) >= 1e38, -1, np.asarray(idx, np.int64))
+    nq, pad = vals.shape[0], k - vals.shape[1]
+    if pad > 0:
+        vals = np.concatenate([vals, np.full((nq, pad), fill, np.float32)], axis=1)
+        idx = np.concatenate([idx, np.full((nq, pad), -1, np.int64)], axis=1)
+    return vals, idx
 
 
 def topk_scan(
@@ -296,13 +294,10 @@ def topk_scan(
     reads where it lies.
     """
     n = base.shape[0]
+    fill = np.inf if metric == "l2" else -np.inf
     if n == 0:
-        nq = len(queries)
-        fill = np.inf if metric == "l2" else -np.inf
-        return (
-            np.full((nq, k), fill, np.float32),
-            np.full((nq, k), -1, np.int64),
-        )
+        empty = np.empty((len(queries), 0), np.float32)
+        return _topk_answer(empty, empty, k, fill)
     k_eff = min(k, n)
 
     if use_pallas():
@@ -321,7 +316,6 @@ def topk_scan(
             )
             with call.result_wait():
                 vals, idx = jax.device_get((vals, idx))
-                idx = idx.astype(np.int64)
     else:
         qn = np.asarray(queries, np.float32)
         bn = np.asarray(base, np.float32)
@@ -338,20 +332,7 @@ def topk_scan(
         vals, idx = _np_topk_min(scores, k_eff)
         if metric == "ip":
             vals = -vals
-        idx = idx.astype(np.int64)
-
-    vals = np.asarray(vals, np.float32)
-    bad = np.abs(vals) >= 1e38
-    idx = np.where(bad, -1, idx)
-    if k_eff < k:  # pad out to requested k
-        fill = np.inf if metric == "l2" else -np.inf
-        vals = np.concatenate(
-            [vals, np.full((vals.shape[0], k - k_eff), fill, np.float32)], axis=1
-        )
-        idx = np.concatenate(
-            [idx, np.full((idx.shape[0], k - k_eff), -1, np.int64)], axis=1
-        )
-    return vals, idx
+    return _topk_answer(vals, idx, k, fill)
 
 
 def topk_scan_segmented(
@@ -361,127 +342,27 @@ def topk_scan_segmented(
     metric: str = "l2",
     valids: "list | None" = None,
 ) -> tuple[np.ndarray, np.ndarray]:
-    """Fused brute-force scan over one execution class of segments.
-
-    Computes a single distance evaluation over the row-concatenation of
-    ``bases`` and extracts the per-segment top-k from column slices of
-    the shared score matrix — replacing S separate ``topk_scan``
-    dispatches (S small gemms + S top-k passes) with one large gemm.
-    This is the batched-scan half of the fused search engine; the merge
-    half is :func:`merge_topk`.
+    """Brute-force scan over one execution class of segments: the top-k of
+    each base, ``topk_scan(queries, bases[s], k, metric, valids[s])``, side
+    by side.
 
     Returns (scores [nq, S*k], idx [nq, S*k]) where block
-    ``idx[:, s*k:(s+1)*k]`` holds row indices LOCAL to ``bases[s]``
-    (-1 = invalid slot), each block BIT-IDENTICAL to
-    ``topk_scan(queries, bases[s], k, metric, valid=valids[s])``: the
-    per-segment gemm shapes are preserved (cache-blocked execution), and
-    the score combine runs as in-place passes whose float semantics match
-    the expression in ``topk_scan`` exactly.  The fusion removes the
-    per-dispatch overheads instead: query norms are computed once, the
-    masking/combine passes allocate no broadcast temporaries, and the
-    outputs land directly in the pooled candidate arrays.
+    ``idx[:, s*k:(s+1)*k]`` holds row indices LOCAL to ``bases[s]`` (-1 =
+    invalid slot).  It is the one entry point for "many bases, one class":
+    today one ``topk_scan`` per base, on every platform.
     """
-    n_seg = len(bases)
-    nq = len(queries)
-    fill = np.inf if metric == "l2" else -np.inf
-    if n_seg == 0:
-        return (
-            np.full((nq, 0), fill, np.float32),
-            np.full((nq, 0), -1, np.int64),
-        )
     if valids is None:
-        valids = [None] * n_seg
-    if use_pallas():
-        # TPU path: the scan kernel already streams base tiles through
-        # VMEM; per-segment kernel launches keep the same semantics.
-        parts = [
-            topk_scan(queries, b, k, metric=metric, valid=v)
-            for b, v in zip(bases, valids)
-        ]
-        return (
-            np.concatenate([p[0] for p in parts], axis=1),
-            np.concatenate([p[1] for p in parts], axis=1),
-        )
-
-    qn = np.ascontiguousarray(np.asarray(queries, np.float32))
-    q_norm = np.sum(qn * qn, axis=1, keepdims=True) if metric == "l2" else None
-    out_v = np.full((nq, n_seg * k), fill, np.float32)
-    out_i = np.full((nq, n_seg * k), -1, np.int64)
-
-    def emit_block(s_idx: int, vals: np.ndarray, idx: np.ndarray) -> None:
-        if metric == "ip":
-            vals = -vals
-        vals = np.asarray(vals, np.float32)
-        idx = np.where(np.abs(vals) >= 1e38, -1, idx.astype(np.int64))
-        lo = s_idx * k
-        out_v[:, lo : lo + vals.shape[-1]] = vals
-        out_i[:, lo : lo + vals.shape[-1]] = idx
-
-    # Group segments with equal row counts (the common case: slices and
-    # seal-sized segments are uniform) so each group runs as ONE batched
-    # gemm + ONE batched top-k instead of a per-segment dispatch chain.
-    # Per-gemm shapes are preserved, so results stay bit-identical to the
-    # per-segment scan.
-    groups: dict[int, list[int]] = {}
-    for s_idx, b in enumerate(bases):
-        groups.setdefault(b.shape[0], []).append(s_idx)
-
-    for n_s, members in groups.items():
-        if n_s == 0:
-            continue
-        k_eff = min(k, n_s)
-        # The batched cube pays off while it stays cache-resident (many
-        # tiny segments, where per-dispatch overhead dominates); past
-        # that, streaming the whole [S,nq,n_s] cube through each pass is
-        # DRAM-bound and cache-blocked per-segment execution wins.
-        if len(members) == 1 or len(members) * nq * n_s > 1 << 17:
-            for s_idx in members:
-                bn = np.asarray(bases[s_idx], np.float32)
-                scores = qn @ bn.T
-                if metric == "l2":
-                    # In-place equivalent of q_norm - 2*q@b.T + b_norm (the
-                    # float ops commute bitwise with topk_scan's expression).
-                    scores *= -2.0
-                    scores += q_norm
-                    scores += np.sum(bn * bn, axis=1)[None, :]
-                else:
-                    np.negative(scores, out=scores)
-                v = valids[s_idx]
-                if v is not None:
-                    scores[:, ~np.asarray(v, bool)] = np.float32(np.inf)
-                vals, idx = _np_topk_min(scores, k_eff)
-                emit_block(s_idx, vals, idx)
-            continue
-        # Per-slice BLAS gemms into one preallocated cube (numpy's stacked
-        # matmul bypasses BLAS); all later passes are batched over [S,nq,n_s].
-        n_grp = len(members)
-        scores = np.empty((n_grp, nq, n_s), np.float32)
-        b_norm = np.empty((n_grp, n_s), np.float32) if metric == "l2" else None
-        for g, i in enumerate(members):
-            bn = np.asarray(bases[i], np.float32)
-            np.matmul(qn, bn.T, out=scores[g])
-            if metric == "l2":
-                b_norm[g] = np.sum(bn * bn, axis=1)
-        if metric == "l2":
-            scores *= -2.0
-            scores += q_norm[None, :, :]
-            scores += b_norm[:, None, :]
-        else:
-            np.negative(scores, out=scores)
-        if any(valids[i] is not None for i in members):
-            vstack = np.stack(
-                [
-                    np.ones(n_s, bool)
-                    if valids[i] is None
-                    else np.asarray(valids[i], bool)
-                    for i in members
-                ]
-            )
-            np.copyto(scores, np.float32(np.inf), where=~vstack[:, None, :])
-        vals, idx = _np_topk_min(scores, k_eff)  # [S, nq, k_eff]
-        for g, s_idx in enumerate(members):
-            emit_block(s_idx, vals[g], idx[g])
-    return out_v, out_i
+        valids = [None] * len(bases)
+    parts = [
+        topk_scan(queries, b, k, metric=metric, valid=v) for b, v in zip(bases, valids)
+    ]
+    if not parts:
+        empty = np.empty((len(queries), 0), np.float32)
+        return empty, empty.astype(np.int64)
+    return (
+        np.concatenate([p[0] for p in parts], axis=1),
+        np.concatenate([p[1] for p in parts], axis=1),
+    )
 
 
 def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.ndarray]:
@@ -500,10 +381,8 @@ def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.
     nq, m = s.shape
     fill = np.inf if metric == "l2" else -np.inf
     if m == 0 or nq == 0:
-        return (
-            np.full((nq, k), fill, np.float32),
-            np.full((nq, k), -1, np.int64),
-        )
+        empty = np.empty((nq, 0), np.float32)
+        return _topk_answer(empty, empty, k, fill)
 
     if use_pallas() and (p.size == 0 or np.abs(p).max() < 2**31 - 1):
         # Pools wider than 512 round up to a power of two: a node's pool
@@ -514,7 +393,7 @@ def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.
             with call.h2d():
                 sp = call.put(_host_padded(s, width, np.float32, fill, axis=1), jnp.float32)
                 pp = call.put(_host_padded(p, width, np.int32, -1, axis=1), jnp.int32)
-            tq = 128 if nq >= 128 else max(8, 1 << (nq - 1).bit_length())
+            tq, _ = _choose_tiles(nq, m)
             tm = next(t for t in (512, 256, 128) if sp.shape[1] % t == 0)
             k_eff = min(k, m)
             vals, opk = call.launch(
@@ -523,16 +402,9 @@ def merge_topk(scores, pks, k: int, metric: str = "l2") -> tuple[np.ndarray, np.
             )
             with call.result_wait():
                 vals, opk = jax.device_get((vals, opk))
-                opk = opk.astype(np.int64)
-        bad = np.abs(vals) >= 1e38
-        vals = np.where(bad, np.float32(fill), vals)
-        opk = np.where(bad, -1, opk)
-        if k_eff < k:
-            vals = np.concatenate(
-                [vals, np.full((nq, k - k_eff), fill, np.float32)], axis=1
-            )
-            opk = np.concatenate([opk, np.full((nq, k - k_eff), -1, np.int64)], axis=1)
-        return vals, opk
+        # A merge's dead slot carries the fill score, as the host path's does.
+        vals = np.where(np.abs(vals) >= 1e38, np.float32(fill), vals)
+        return _topk_answer(vals, opk, k, fill)
 
     # Host fast path: optimistic top-k by packed integer key, then full
     # dedup only for the rows whose top-k actually contains a duplicate
@@ -907,27 +779,23 @@ def pq_adc_topk(luts, codes, k: int, valid=None) -> tuple[np.ndarray, np.ndarray
     n = codes.shape[0]
     nq = luts.shape[0]
     if n == 0:
-        return np.full((nq, k), np.inf, np.float32), np.full((nq, k), -1, np.int64)
+        empty = np.empty((nq, 0), np.float32)
+        return _topk_answer(empty, empty, k, np.inf)
     k_eff = min(k, n)
     if use_pallas():
+        tn = _row_tile(n)
+        if valid is None:
+            valid = _all_live(n)
         with _kernel_call("pq_adc_topk") as call:
             with call.h2d():
                 luts = call.put(luts, jnp.float32)
-                codes = call.put(codes, jnp.int32)
-                v = (
-                    call.launch(jnp.ones, n, jnp.int32)
-                    if valid is None
-                    else call.put(valid)
-                )
-            v = _as_int32(v, call)
-            tn = 512 if n >= 512 else max(128, 1 << (n - 1).bit_length())
-            cp = _pad_rows(codes, tn, call=call)
-            vp = _pad_rows(v, tn, fill=0, call=call)
+                codes = call.put(_host_padded(codes, tn, codes.dtype))
+                valid = call.put(_host_padded(valid, tn, bool, fill=False))
             vals, idx = call.launch(
-                pq_adc_topk_pallas, luts, cp, vp, k_eff, tn=tn, interpret=_interpret()
+                pq_adc_topk_pallas, luts, codes, valid, k_eff, tn=tn, interpret=_interpret()
             )
             with call.result_wait():
-                vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
+                vals, idx = jax.device_get((vals, idx))
     else:
         ln = np.asarray(luts, np.float32)
         cn = np.asarray(codes, np.int64)
@@ -938,14 +806,7 @@ def pq_adc_topk(luts, codes, k: int, valid=None) -> tuple[np.ndarray, np.ndarray
         if valid is not None:
             scores = np.where(np.asarray(valid, bool)[None, :], scores, np.float32(np.inf))
         vals, idx = _np_topk_min(scores, k_eff)
-        idx = idx.astype(np.int64)
-    idx = np.where(np.abs(vals) >= 1e38, -1, idx)
-    if k_eff < k:
-        vals = np.concatenate(
-            [vals, np.full((nq, k - k_eff), np.inf, np.float32)], axis=1
-        )
-        idx = np.concatenate([idx, np.full((nq, k - k_eff), -1, np.int64)], axis=1)
-    return vals, idx
+    return _topk_answer(vals, idx, k, np.inf)
 
 
 def sq_scale(vmin, vmax) -> np.ndarray:
@@ -958,17 +819,15 @@ def sq_scale(vmin, vmax) -> np.ndarray:
 
 def sq_encode(x, vmin, vmax) -> np.ndarray:
     if use_pallas():
+        n = x.shape[0]
+        tn = _row_tile(n)
         with _kernel_call("sq_encode") as call:
             with call.h2d():
-                x = call.put(x, jnp.float32)
+                x = call.put(_host_padded(x, tn, np.float32), jnp.float32)
                 lo, hi = call.put(vmin), call.put(vmax)
-            n = x.shape[0]
-            tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
-            xp = _pad_rows(x, tn, call=call)
-            out = call.launch(sq_encode_pallas, xp, lo, hi, tn=tn, interpret=_interpret())
-            out = _head(out, n, call)
+            out = call.launch(sq_encode_pallas, x, lo, hi, tn=tn, interpret=_interpret())
             with call.result_wait():
-                return np.asarray(out, np.uint8)
+                return jax.device_get(out)[:n].astype(np.uint8)
     xn = np.asarray(x, np.float32)
     vmin = np.asarray(vmin, np.float32)
     scale = sq_scale(vmin, vmax)
@@ -978,17 +837,15 @@ def sq_encode(x, vmin, vmax) -> np.ndarray:
 
 def sq_decode(codes, vmin, vmax) -> np.ndarray:
     if use_pallas():
+        n = codes.shape[0]
+        tn = _row_tile(n)
         with _kernel_call("sq_decode") as call:
             with call.h2d():
-                codes = call.put(codes)
+                codes = call.put(_host_padded(codes, tn, codes.dtype))
                 lo, hi = call.put(vmin), call.put(vmax)
-            n = codes.shape[0]
-            tn = 512 if n >= 512 else max(128, 1 << max(0, (n - 1)).bit_length())
-            cp = _pad_rows(_as_int32(codes, call), tn, call=call)
-            out = call.launch(sq_decode_pallas, cp, lo, hi, tn=tn, interpret=_interpret())
-            out = _head(out, n, call)
+            out = call.launch(sq_decode_pallas, codes, lo, hi, tn=tn, interpret=_interpret())
             with call.result_wait():
-                return np.asarray(out)
+                return jax.device_get(out)[:n]
     vmin = np.asarray(vmin, np.float32)
     scale = sq_scale(vmin, vmax)
     return np.asarray(codes, np.float32) * scale[None, :] + vmin[None, :]
@@ -998,45 +855,31 @@ def sq_topk_scan(
     queries, codes, vmin, vmax, k: int, metric: str = "l2", valid=None
 ) -> tuple[np.ndarray, np.ndarray]:
     """Top-k against SQ-compressed base with fused dequantization."""
-    n = codes.shape[0]
-    nq = len(queries)
-    if n == 0:
-        fill = np.inf if metric == "l2" else -np.inf
-        return np.full((nq, k), fill, np.float32), np.full((nq, k), -1, np.int64)
-    k_eff = min(k, n)
-    if use_pallas():
-        with _kernel_call("sq_l2_topk") as call:
-            with call.h2d():
-                queries = call.put(queries, jnp.float32)
-                codes = call.put(codes)
-                lo, hi = call.put(vmin), call.put(vmax)
-                v = (
-                    call.launch(jnp.ones, n, jnp.int32)
-                    if valid is None
-                    else call.put(valid)
-                )
-            v = _as_int32(v, call)
-            tq, tn = _choose_tiles(nq, n)
-            qp = _pad_rows(queries, tq, call=call)
-            cp = _pad_rows(_as_int32(codes, call), tn, call=call)
-            vp = _pad_rows(v, tn, fill=0, call=call)
-            vals, idx = call.launch(
-                sq_l2_topk_pallas, qp, cp, lo, hi, vp, k_eff,
-                metric=metric, tq=tq, tn=tn, interpret=_interpret(),
-            )
-            vals, idx = _head(vals, nq, call), _head(idx, nq, call)
-            with call.result_wait():
-                vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
-    else:
+    if not use_pallas():
         decoded = sq_decode(np.asarray(codes), vmin, vmax)
         return topk_scan(np.asarray(queries), decoded, k, metric=metric, valid=valid)
-    vals, idx = np.asarray(vals), np.asarray(idx, np.int64)
-    idx = np.where(np.abs(vals) >= 1e38, -1, idx)
-    if k_eff < k:
-        fill = np.inf if metric == "l2" else -np.inf
-        vals = np.concatenate([vals, np.full((nq, k - k_eff), fill, np.float32)], axis=1)
-        idx = np.concatenate([idx, np.full((nq, k - k_eff), -1, np.int64)], axis=1)
-    return vals, idx
+    n = codes.shape[0]
+    nq = len(queries)
+    fill = np.inf if metric == "l2" else -np.inf
+    if n == 0:
+        empty = np.empty((nq, 0), np.float32)
+        return _topk_answer(empty, empty, k, fill)
+    tq, tn = _choose_tiles(nq, n)
+    if valid is None:
+        valid = _all_live(n)
+    with _kernel_call("sq_l2_topk") as call:
+        with call.h2d():
+            queries = call.put(queries, jnp.float32)
+            codes = call.put(_host_padded(codes, tn, codes.dtype))
+            lo, hi = call.put(vmin), call.put(vmax)
+            valid = call.put(_host_padded(valid, tn, bool, fill=False))
+        vals, idx = call.launch(
+            sq_l2_topk_pallas, queries, codes, lo, hi, valid, min(k, n),
+            metric=metric, tq=tq, tn=tn, interpret=_interpret(),
+        )
+        with call.result_wait():
+            vals, idx = jax.device_get((vals, idx))
+    return _topk_answer(vals, idx, k, fill)
 
 
 # ---------------------------------------------------------------------------
@@ -1219,24 +1062,20 @@ def ivf_gather_topk(
 
 def kmeans_assign(x, centroids) -> tuple[np.ndarray, np.ndarray]:
     """Nearest-centroid assignment: returns (assign [n] int, sqdist [n])."""
-    n, ncent = x.shape[0], centroids.shape[0]
     if use_pallas():
+        n = x.shape[0]
+        tn = _row_tile(n)
         with _kernel_call("kmeans_assign") as call:
             with call.h2d():
-                x = call.put(x, jnp.float32)
+                x = call.put(_host_padded(x, tn, np.float32), jnp.float32)
                 c = call.put(centroids, jnp.float32)
-            tn = 512 if n >= 512 else max(128, 1 << (max(n, 2) - 1).bit_length())
-            tc = 512 if ncent >= 512 else max(128, 1 << (max(ncent, 2) - 1).bit_length())
-            xp = _pad_rows(x, tn, call=call)
-            # pad centroids with far-away sentinels so they never win
-            pad = (-ncent) % tc
-            if pad:
-                far = call.launch(jnp.full, (pad, c.shape[1]), 1e18, jnp.float32)
-                c = call.launch(jnp.concatenate, [c, far])
-            a, d = call.launch(kmeans_assign_pallas, xp, c, tn=tn, tc=tc, interpret=_interpret())
-            a, d = _head(a, n, call), _head(d, n, call)
+            out = call.launch(
+                kmeans_assign_pallas, x, c,
+                tn=tn, tc=_row_tile(c.shape[0]), interpret=_interpret(),
+            )
             with call.result_wait():
-                return np.asarray(a, np.int64), np.asarray(d)
+                a, d = jax.device_get(out)
+        return a[:n].astype(np.int64), d[:n]
     xn = np.asarray(x, np.float32)
     cn = np.asarray(centroids, np.float32)
     d2 = (

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .topk_util import BIG_F32, NEG_I32, merge_topk, tile_base_indices
+from .topk_util import BIG_F32, NEG_I32, merge_topk, pad_rows_to, tile_base_indices
 
 DEFAULT_TN = 512
 # Bytes one LUT block may take in VMEM (it is double-buffered by the grid).
@@ -92,16 +92,20 @@ def _adc_kernel(
 @functools.partial(jax.jit, static_argnames=("k", "tn", "interpret"))
 def pq_adc_topk_pallas(
     luts: jnp.ndarray,  # [NQ, M, KSUB] f32
-    codes: jnp.ndarray,  # [N, M] int32, N padded to TN multiple
-    valid: jnp.ndarray,  # [N] int32
+    codes: jnp.ndarray,  # [N, M] integer codes
+    valid: jnp.ndarray,  # [N] or [N padded to TN] bool / int32, 1 = live
     k: int,
     tn: int = DEFAULT_TN,
     *,
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """ADC top-k of each query over ``codes``: ([NQ, K] distances, [NQ, K]
+    int32 rows).  The query rows, codes and mask are padded to their tiles
+    inside the program (padded rows are dead), so that a call is one
+    program."""
     nq, m, ksub = luts.shape
-    n = codes.shape[0]
-    assert n % tn == 0, (n, tn)
+    n = -(-codes.shape[0] // tn) * tn
+    assert valid.shape[0] <= n, (valid.shape, n)
     tq = query_tile(nq, m, ksub)
     nq_pad = -(-nq // tq) * tq
     n_b_tiles = n // tn
@@ -133,7 +137,7 @@ def pq_adc_topk_pallas(
             jnp.transpose(luts.astype(jnp.float32), (1, 0, 2)),
             ((0, 0), (0, nq_pad - nq), (0, 0)),
         ),
-        codes.astype(jnp.int32).T,
-        valid[None, :].astype(jnp.int32),
+        pad_rows_to(codes.astype(jnp.int32), n).T,
+        pad_rows_to(valid.astype(jnp.int32), n)[None, :],
     )
     return out_v[:nq], out_i[:nq]

@@ -11,6 +11,10 @@ Kernels:
   * ``sq_decode_pallas``  — codes -> f32
   * ``sq_l2_topk_pallas`` — fused dequant + L2/IP scan + running top-k
     (structure identical to ``l2_topk``; base tiles are int codes).
+
+Each jitted program pads what is short of its tile (rows, codes, the
+mask, the query rows), casts codes and the mask to int32, and slices its
+outputs back to the rows it was given, so that a call is one program.
 """
 
 from __future__ import annotations
@@ -22,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .topk_util import BIG_F32, NEG_I32, merge_topk, tile_base_indices
+from .topk_util import BIG_F32, NEG_I32, merge_topk, pad_rows_to, tile_base_indices
 
 DEFAULT_TN = 512
 DEFAULT_TQ = 128
@@ -50,8 +54,8 @@ def sq_encode_pallas(
     x: jnp.ndarray, vmin: jnp.ndarray, vmax: jnp.ndarray,
     tn: int = DEFAULT_TN, *, interpret: bool,
 ) -> jnp.ndarray:
-    n, d = x.shape
-    assert n % tn == 0
+    rows, d = x.shape
+    n = -(-rows // tn) * tn
     return pl.pallas_call(
         _encode_kernel,
         grid=(n // tn,),
@@ -63,7 +67,7 @@ def sq_encode_pallas(
         out_specs=pl.BlockSpec((tn, d), lambda j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.int32),
         interpret=interpret,
-    )(x.astype(jnp.float32), vmin[None, :], vmax[None, :])
+    )(pad_rows_to(x.astype(jnp.float32), n), vmin[None, :], vmax[None, :])[:rows]
 
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
@@ -71,8 +75,8 @@ def sq_decode_pallas(
     codes: jnp.ndarray, vmin: jnp.ndarray, vmax: jnp.ndarray,
     tn: int = DEFAULT_TN, *, interpret: bool,
 ) -> jnp.ndarray:
-    n, d = codes.shape
-    assert n % tn == 0
+    rows, d = codes.shape
+    n = -(-rows // tn) * tn
     return pl.pallas_call(
         _decode_kernel,
         grid=(n // tn,),
@@ -84,7 +88,7 @@ def sq_decode_pallas(
         out_specs=pl.BlockSpec((tn, d), lambda j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         interpret=interpret,
-    )(codes.astype(jnp.int32), vmin[None, :], vmax[None, :])
+    )(pad_rows_to(codes.astype(jnp.int32), n), vmin[None, :], vmax[None, :])[:rows]
 
 
 def _sq_scan_kernel(
@@ -144,11 +148,11 @@ def _sq_scan_kernel(
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "tq", "tn", "interpret"))
 def sq_l2_topk_pallas(
-    queries: jnp.ndarray,  # [NQ, D]
-    codes: jnp.ndarray,  # [N, D] int32
+    queries: jnp.ndarray,  # [NQ, D], any NQ
+    codes: jnp.ndarray,  # [N, D] integer codes
     vmin: jnp.ndarray,
     vmax: jnp.ndarray,
-    valid: jnp.ndarray,
+    valid: jnp.ndarray,  # [N] or [N padded to TN] bool / int32, 1 = live
     k: int,
     metric: str = "l2",
     tq: int = DEFAULT_TQ,
@@ -157,14 +161,15 @@ def sq_l2_topk_pallas(
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     nq, d = queries.shape
-    n = codes.shape[0]
-    assert nq % tq == 0 and n % tn == 0
+    nq_pad = -(-nq // tq) * tq
+    n = -(-codes.shape[0] // tn) * tn
+    assert valid.shape[0] <= n, (valid.shape, n)
     kernel = functools.partial(
         _sq_scan_kernel, k=k, metric=metric, n_base_tiles=n // tn
     )
-    return pl.pallas_call(
+    out_v, out_i = pl.pallas_call(
         kernel,
-        grid=(nq // tq, n // tn),
+        grid=(nq_pad // tq, n // tn),
         in_specs=[
             pl.BlockSpec((tq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
@@ -177,8 +182,8 @@ def sq_l2_topk_pallas(
             pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
+            jax.ShapeDtypeStruct((nq_pad, k), jnp.float32),
+            jax.ShapeDtypeStruct((nq_pad, k), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((tq, k), jnp.float32),
@@ -186,9 +191,10 @@ def sq_l2_topk_pallas(
         ],
         interpret=interpret,
     )(
-        queries.astype(jnp.float32),
-        codes.astype(jnp.int32),
+        pad_rows_to(queries.astype(jnp.float32), nq_pad),
+        pad_rows_to(codes.astype(jnp.int32), n),
         vmin[None, :].astype(jnp.float32),
         vmax[None, :].astype(jnp.float32),
-        valid[None, :].astype(jnp.int32),
+        pad_rows_to(valid.astype(jnp.int32), n)[None, :],
     )
+    return out_v[:nq], out_i[:nq]

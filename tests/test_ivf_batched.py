@@ -1,15 +1,14 @@
 """Batched IVF execution engine: fuzz equivalence against the scalar
-per-list reference oracle (``REPRO_IVF_REFERENCE=1``), padded-probe edge
-cases (tiny collections), and the ``search_batched`` candidate-pool
+per-list reference oracle (``IVFBase._search_reference``), padded-probe
+edge cases (tiny collections), and the ``search_batched`` candidate-pool
 surface (including through ``QueryNode``)."""
-
-import os
 
 import numpy as np
 import pytest
 
 from repro.core.collection import Metric
 from repro.index import IndexSpec, create_index
+from repro.index.base import VectorIndex
 from repro.index.ivf import IVFFlatIndex
 from repro.kernels import ops
 
@@ -34,11 +33,14 @@ def make_data(seed=7, n=800, d=32, nq=9):
 
 
 def reference(idx, q, k, valid=None):
-    os.environ["REPRO_IVF_REFERENCE"] = "1"
-    try:
-        return idx.search(q, k, valid=valid)
-    finally:
-        del os.environ["REPRO_IVF_REFERENCE"]
+    return idx._search_reference(q, k, valid=valid)
+
+
+def _search_by_reference(monkeypatch):
+    """IVF_FLAT searches run the oracle, a group of indexes one search
+    each through the base per-index ``search_batched`` (as HNSW's do)."""
+    monkeypatch.setattr(IVFFlatIndex, "search", IVFFlatIndex._search_reference)
+    monkeypatch.setattr(IVFFlatIndex, "search_batched", VectorIndex.search_batched)
 
 
 def assert_topk_equiv(batched, ref, metric, atol=3e-3):
@@ -172,9 +174,9 @@ def test_search_batched_pools_match_per_index_search():
         assert_topk_equiv((ms, mi), (ss, si), Metric.L2, atol=1e-4)
 
 
-def test_search_batched_reference_flag_falls_back():
-    """REPRO_IVF_REFERENCE=1 routes search_batched through per-index
-    scalar searches (blocks of width k)."""
+def test_search_batched_base_fallback_runs_per_index_searches(monkeypatch):
+    """The base ``search_batched``, which the kinds without a batched
+    engine use, runs each index's own search: blocks of width k."""
     rng = np.random.default_rng(12)
     base = rng.standard_normal((200, 16)).astype(np.float32)
     ix = create_index(
@@ -182,18 +184,15 @@ def test_search_batched_reference_flag_falls_back():
     )
     ix.build(base)
     q = rng.standard_normal((4, 16)).astype(np.float32)
-    os.environ["REPRO_IVF_REFERENCE"] = "1"
-    try:
-        s, i, splits = IVFFlatIndex.search_batched([ix, ix], q, 5)
-    finally:
-        del os.environ["REPRO_IVF_REFERENCE"]
+    _search_by_reference(monkeypatch)
+    s, i, splits = IVFFlatIndex.search_batched([ix, ix], q, 5)
     assert splits == [0, 5, 10]
     ss, si = reference(ix, q, 5)
     np.testing.assert_array_equal(i[:, :5], si)
     np.testing.assert_array_equal(i[:, 5:], si)
 
 
-def test_query_node_indexed_equivalence_with_deletes():
+def test_query_node_indexed_equivalence_with_deletes(monkeypatch):
     """Node-level search over sealed+indexed segments (grouped
     search_batched dispatch) matches the reference oracle path, with
     delta-deletes in play."""
@@ -228,10 +227,7 @@ def test_query_node_indexed_equivalence_with_deletes():
     g = GuaranteeTs(query_ts=10_000, staleness_ms=INFINITE_STALENESS)
 
     got = node.search("c", q, 10, Metric.L2, g)
-    os.environ["REPRO_IVF_REFERENCE"] = "1"
-    try:
-        want = node.search("c", q, 10, Metric.L2, g)
-    finally:
-        del os.environ["REPRO_IVF_REFERENCE"]
+    _search_by_reference(monkeypatch)
+    want = node.search("c", q, 10, Metric.L2, g)
     assert_topk_equiv(got, want, Metric.L2)
     assert not set(got[1][got[1] >= 0].ravel().tolist()) & set(doomed.tolist())

@@ -1,6 +1,7 @@
-"""One device program per kernel call: ``topk_scan`` and ``merge_topk``
-launch their jitted program once (it pads the query rows and slices the
-outputs itself) and fetch both outputs in one transfer.
+"""One device program per kernel call: every entry point of
+``kernels/ops.py`` launches its jitted program once (it pads the query
+rows, casts masks and codes, and slices the outputs itself) and fetches
+its outputs in one transfer.
 
 The kernel path runs on the CPU in interpret mode (``ops.use_pallas``
 patched to True).  Vectors hold small integers, so every distance is
@@ -134,11 +135,11 @@ def test_a_warm_search_dispatches_no_eager_op(rng, kernel_path, monkeypatch, kin
     search()  # compiles every program the search runs
 
     def refuse(*args, **kwargs):
-        raise AssertionError("an eager pad on a warm search")
+        raise AssertionError("an eager op on a warm search")
 
     with monkeypatch.context() as m:
-        m.setattr(jnp, "pad", refuse)
-        m.setattr(ops, "_pad_rows", refuse)
+        for eager in ("pad", "ones", "full", "concatenate"):
+            m.setattr(jnp, eager, refuse)
         trace = search().trace
     scans = trace.spans_named("kernel_l2_topk")
     merges = trace.spans_named("kernel_merge_topk")
@@ -155,15 +156,57 @@ def test_a_warm_search_dispatches_no_eager_op(rng, kernel_path, monkeypatch, kin
         assert span.children[0].bytes_h2d == 2 * nq * 128 * 4
 
 
-def test_other_kernels_count_their_eager_ops(rng, kernel_path):
-    """``kmeans_assign`` keeps its eager ops, each counted: the row pad,
-    the far centroids and their concatenation, the program, two slices."""
-    x = rng.standard_normal((ROWS, DIM)).astype(np.float32)
-    cents = rng.standard_normal((16, DIM)).astype(np.float32)
-    ctx, timer = _traced("build")
+VMIN, VMAX = -3.0, 252.0  # an SQ step of exactly 1: a code is its value + 3
+
+
+def _other_kernel_case(case, rng):
+    """(kernel name, the entry point's call, ``ref.py``'s answer) of one
+    case: small integers, 300 rows (short of the 512-row tile), 3 queries."""
+    x = _ints(rng, (ROWS, DIM))
+    codes = (x - VMIN).astype(np.uint8)
+    lo, hi = np.full(DIM, VMIN, np.float32), np.full(DIM, VMAX, np.float32)
+    q = _ints(rng, (3, DIM))
+    valid = rng.random(ROWS) < 0.7 if case.endswith("-masked") else None
+    jvalid = None if valid is None else jnp.asarray(valid)
+    jlo, jhi = jnp.asarray(lo), jnp.asarray(hi)
+    if case == "kmeans_assign":
+        cents = _ints(rng, (16, DIM))
+        want = ref.kmeans_assign_ref(jnp.asarray(x), jnp.asarray(cents))
+        return "kmeans_assign", lambda: ops.kmeans_assign(x, cents), want
+    if case == "sq_encode":
+        want = ref.sq_encode_ref(jnp.asarray(x), jlo, jhi)
+        return "sq_encode", lambda: ops.sq_encode(x, lo, hi), want
+    if case == "sq_decode":
+        want = ref.sq_decode_ref(jnp.asarray(codes), jlo, jhi)
+        return "sq_decode", lambda: ops.sq_decode(codes, lo, hi), want
+    if case.startswith("sq_topk_scan"):
+        want = ref.sq_l2_topk_ref(jnp.asarray(q), jnp.asarray(codes), jlo, jhi, K,
+                                  valid=jvalid)
+        return "sq_l2_topk", lambda: ops.sq_topk_scan(q, codes, lo, hi, K, valid=valid), want
+    m, ksub = 4, 16
+    luts = _ints(rng, (3, m, ksub))
+    pq_codes = rng.integers(0, ksub, (ROWS, m)).astype(np.uint8)
+    want = ref.pq_adc_topk_ref(jnp.asarray(luts), jnp.asarray(pq_codes), K, valid=jvalid)
+    return "pq_adc_topk", lambda: ops.pq_adc_topk(luts, pq_codes, K, valid=valid), want
+
+
+@pytest.mark.parametrize("case", [
+    "kmeans_assign", "sq_encode", "sq_decode", "sq_topk_scan-masked",
+    "sq_topk_scan-all-live", "pq_adc_topk-masked", "pq_adc_topk-all-live",
+])
+def test_kernel_is_one_program_equal_to_ref(rng, kernel_path, case):
+    name, call, want = _other_kernel_case(case, rng)
+    ctx, timer = _traced(name)
     with timer:
-        got = ops.kmeans_assign(x, cents)
+        got = call()
     (span,) = ctx.root.children[0].children
-    assert span.name == "kernel_kmeans_assign" and span.programs == 6
-    want = ref.kmeans_assign_ref(jnp.asarray(x), jnp.asarray(cents))
-    np.testing.assert_array_equal(got[0], np.asarray(want[0]))
+    assert span.name == "kernel_" + name and span.programs == 1
+    if name in ("sq_l2_topk", "pq_adc_topk"):
+        assert got[0].shape == got[1].shape == (3, K)
+        _assert_scan_equals_ref(got, want)
+    elif name == "kmeans_assign":
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, np.asarray(w))
+    else:
+        assert got.dtype == np.asarray(want).dtype
+        np.testing.assert_array_equal(got, np.asarray(want))

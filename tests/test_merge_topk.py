@@ -250,3 +250,78 @@ def test_query_node_plan_classes():
     assert len(plan.units()) == 5
     # queries pinned before any insert see an empty plan
     assert not node.plan_search(coll, 50).units()
+
+
+def _tied_node(dim=4, slice_rows=16):
+    """A query node whose segments each hold one row at distance 1 from the
+    origin (pk = 100 * segment id, or 450 in the growing tail) among rows
+    at distance >= 36: a FLAT-indexed and an IVF_FLAT-indexed sealed
+    segment (two index specs), an unindexed one, and a growing one with a
+    full slice (temporary index) and a tail."""
+    from repro.core.query_node import GrowingState
+    from repro.index import IndexSpec, create_index
+
+    rng = np.random.default_rng(5)
+    node = QueryNode("qn-order", LogBroker(), MemoryObjectStore(), slice_rows=slice_rows)
+    tie = np.eye(dim, dtype=np.float32)[0]
+
+    def rows(n, pk0, tie_at):
+        x = rng.integers(3, 30, (n, dim)).astype(np.float32)
+        x[tie_at] = tie
+        pks = np.arange(pk0 + 1, pk0 + 1 + n)
+        pks[tie_at] = pk0
+        return pks, x
+
+    for sid, kind in ((1, "flat"), (2, "ivf_flat"), (3, None)):
+        pks, x = rows(12, 100 * sid, 5)
+        seg = Segment(sid, "c", 0, dim, slice_rows=slice_rows)
+        seg.append(pks, x, np.full(12, 100, np.int64))
+        index = None
+        if kind is not None:
+            params = {"nlist": 4, "nprobe": 4} if kind == "ivf_flat" else {}
+            index = create_index(IndexSpec(kind=kind, metric=Metric.L2, params=params))
+            index.build(x)
+        node.sealed[("c", sid)] = SealedHandle(seg, index=index, index_kind=kind)
+    seg = Segment(4, "c", 0, dim, slice_rows=slice_rows)
+    pks, x = rows(20, 400, 3)
+    x[18], pks[18] = tie, 450  # in the tail, after the full slice
+    seg.append(pks, x, np.full(20, 100, np.int64))
+    node.growing[("c", 4)] = GrowingState(seg)
+    node._build_slice_indexes()
+    return node
+
+
+@pytest.mark.parametrize("strategy,classes,pks", [
+    (None,
+     ["indexed", "indexed", "growing_slice", "brute_sealed", "brute_tail"],
+     [100, 200, 400, 300, 450]),
+    ("post",
+     ["growing_slice", "brute_tail", "post_indexed", "post_indexed", "post_brute"],
+     [400, 450, 100, 200, 300]),
+    ("brute",
+     ["growing_slice", "brute_tail", "brute_filtered"],
+     [400, 450, 100, 200, 300]),
+])
+def test_exact_ties_keep_the_pool_column_order_of_the_classes(strategy, classes, pks):
+    """Equal scores across execution classes come back in the node's
+    candidate-pool column order (``merge_topk`` breaks ties by column),
+    which is the order the classes run in."""
+    from repro.core.request import AnnsQuery, NodeSearchRequest
+    from repro.core.telemetry import TraceContext
+    from repro.index.attribute import FilterExpr
+
+    node = _tied_node()
+    ctx = TraceContext("search")
+    request = NodeSearchRequest(
+        collection="c", k=5, metric=Metric.L2,
+        guarantee=GuaranteeTs(query_ts=10_000, staleness_ms=INFINITE_STALENESS),
+        anns=[AnnsQuery("vector", np.zeros((2, 4), np.float32))],
+        filter=None if strategy is None else FilterExpr("pk != 301"),
+        filter_strategy=strategy,
+        trace=(ctx, ctx.root),
+    )
+    (scores, got), = node.search_request(request)
+    assert got.tolist() == [pks, pks]
+    assert (scores == 1.0).all()
+    scans = [s.name for s in ctx.root.children if s.name.startswith("scan_")]
+    assert scans == ["scan_" + c for c in classes]

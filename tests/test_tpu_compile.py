@@ -21,8 +21,9 @@ from repro.kernels.merge_topk import merge_topk_pallas
 from repro.kernels.pq_adc import pq_adc_topk_pallas
 from repro.kernels.sq_codec import sq_decode_pallas, sq_encode_pallas, sq_l2_topk_pallas
 
-F32, I32 = jnp.float32, jnp.int32
+F32, I32, U8 = jnp.float32, jnp.int32, jnp.uint8
 N = 4096  # rows per compile: 8 base tiles of 512
+SHORT = 300  # rows short of the 512-row tile, padded inside the program
 
 
 @pytest.fixture(scope="module")
@@ -95,6 +96,30 @@ CASES = {
     "pq_adc-m96-nq128": (
         functools.partial(pq_adc_topk_pallas, k=10, tn=512),
         [((128, 96, 256), F32), ((N, 96), I32), ((N,), I32)],
+    ),
+    # the entry points' calls: one query row, uint8 codes and a bool mask
+    # short of the tile, and unpadded centroids, each padded and cast
+    # inside the program
+    "sq_l2_topk-nq1-uint8-short": (
+        functools.partial(sq_l2_topk_pallas, k=10, tq=8, tn=512),
+        [((1, 128), F32), ((SHORT, 128), U8), ((128,), F32), ((128,), F32),
+         ((SHORT,), jnp.bool_)],
+    ),
+    "pq_adc-nq1-uint8-short": (
+        functools.partial(pq_adc_topk_pallas, k=10, tn=512),
+        [((1, 96, 256), F32), ((SHORT, 96), U8), ((SHORT,), jnp.bool_)],
+    ),
+    "kmeans_assign-short-c1000": (
+        functools.partial(kmeans_assign_pallas, tn=512, tc=512),
+        [((SHORT, 128), F32), ((1000, 128), F32)],
+    ),
+    "sq_encode-short": (
+        functools.partial(sq_encode_pallas, tn=512),
+        [((SHORT, 128), F32), ((128,), F32), ((128,), F32)],
+    ),
+    "sq_decode-uint8-short": (
+        functools.partial(sq_decode_pallas, tn=512),
+        [((SHORT, 128), U8), ((128,), F32), ((128,), F32)],
     ),
 }
 
